@@ -25,6 +25,11 @@ Usage::
     PYTHONPATH=src python benchmarks/kernel_tuning.py --smoke
     PYTHONPATH=src python benchmarks/kernel_tuning.py --smoke \
         --record BENCH_tuning.json   # + schema-versioned trajectory
+
+The timings are those of XLA's CPU backend and the Pallas interpreter,
+so the script runs on the CPU only: it sets ``jax_platforms="cpu"``
+before any backend starts (and exports ``JAX_PLATFORMS=cpu`` to any
+child), so it never opens a chip.
 """
 
 from __future__ import annotations
@@ -38,6 +43,7 @@ import tempfile
 # repo root not) and as `python -m benchmarks.kernel_tuning`
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.kernels import ops  # noqa: E402
@@ -135,6 +141,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     scale = "smoke" if args.smoke else "full"
     cases = CASES[scale]
+
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
 
     from benchmarks.recorder import Recorder
     rec = Recorder("tuning", path=args.record)

@@ -69,6 +69,12 @@ what ``scripts/verify.sh --smoke`` runs so serving regressions fail CI
 fast.  ``--record [PATH]`` writes a
 schema-versioned ``BENCH_serving.json`` (rows + per-stage histogram
 snapshots + counters; validated by ``python -m benchmarks.recorder``).
+
+Every gate here counts, compares answers, or times XLA's CPU backend, so
+the script runs on the CPU only: it sets ``jax_platforms="cpu"`` before
+any backend starts, and its child processes inherit
+``JAX_PLATFORMS=cpu``.  No process it starts ever opens a chip; device
+numbers come from ``chip_smoke.py`` and the chip benchmark.
 """
 
 from __future__ import annotations
@@ -1005,6 +1011,9 @@ def _spawn_restart_child(cache_dir: str, scale: int, seed: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
+    # the cold process must start with no compiled programs: give the
+    # pair an XLA cache of their own, not the checkout's shared one
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(cache_dir, "xla")
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--restart-child",
          cache_dir, "--scale", str(scale), "--seed", str(seed)],
@@ -1235,7 +1244,10 @@ def main(argv=None):
     scale = args.scale or (50 if tiny else 1000)
     warm_iters = args.warm_iters or (8 if tiny else 25)
 
-    jax.config.update("jax_platform_name", "cpu")
+    # a CPU counter gate: neither this process nor the children it starts
+    # (they inherit the variable) ever opens an accelerator
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
 
     if args.restart_child is not None:
         print(json.dumps(run_restart_child(args.restart_child, scale,

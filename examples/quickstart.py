@@ -416,8 +416,9 @@ def warm_restart_example():
     """Restart with a warm cache: plans & executables outlive the process.
 
     ``QueryService(db, schema, cache_dir=...)`` persists every shareable
-    plan into a content-addressed store under ``cache_dir`` and points
-    JAX's persistent compilation cache at ``cache_dir/xla`` — so a
+    plan into a content-addressed store under ``cache_dir`` and turns on
+    JAX's persistent compilation cache (``.jax_cache`` in the checkout,
+    unless ``JAX_COMPILATION_CACHE_DIR`` names another) — so a
     RESTARTED service over the same schema re-plans nothing
     (``plan_builds == 0``, the disk level answers with ``persist_hits``)
     and loads previously compiled XLA binaries from disk instead of
@@ -593,7 +594,6 @@ def sql_example():
 
 
 if __name__ == "__main__":
-    jax.config.update("jax_platform_name", "cpu")
     main()
     sql_example()
     serving_example()

@@ -81,10 +81,14 @@ def scalar_aggregate(ag: Agg, cols: dict[str, jax.Array], freq: jax.Array,
                              else jnp.float32) * w)
         n = jnp.sum(w).astype(s.dtype)
         return s / jnp.maximum(n, 1)
+    # no live rows — or none at all, after an eager join that matched
+    # nothing — gives the identity, as a dead row would
     if ag.func == "min":
-        return jnp.min(jnp.where(live, a, _big(a.dtype)))
+        return jnp.min(jnp.where(live, a, _big(a.dtype)),
+                       initial=_big(a.dtype))
     if ag.func == "max":
-        return jnp.max(jnp.where(live, a, _small(a.dtype)))
+        return jnp.max(jnp.where(live, a, _small(a.dtype)),
+                       initial=_small(a.dtype))
     if ag.func == "median":
         return ops.weighted_percentile(a, w, 0.5)
     raise NotImplementedError(ag.func)
@@ -107,8 +111,9 @@ def grouped_aggregate(group_by: tuple[str, ...], aggregates: tuple[Agg, ...],
     order = jnp.argsort(key)
     ks = key[order]
     n = ks.shape[0]
-    is_last = jnp.concatenate([ks[1:] != ks[:-1], jnp.ones((1,), bool)])
-    is_first = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
+    # [:n] keeps an empty root state empty
+    is_last = jnp.concatenate([ks[1:] != ks[:-1], jnp.ones((1,), bool)])[:n]
+    is_first = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])[:n]
     run_id = jnp.cumsum(is_first.astype(jnp.int32)) - 1
     live_s = (freq > 0)[order]
     w_s = w[order]
@@ -164,7 +169,7 @@ def _grouped_weighted_median(sorted_keys, values, weights, live):
     vs = v[order]
     ws = jnp.where(live[order], weights[order], 0).astype(
         jnp.float64 if jax.config.jax_enable_x64 else jnp.float32)
-    is_first = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
+    is_first = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])[:n]
     run_id = jnp.cumsum(is_first.astype(jnp.int32)) - 1
     cw = jnp.cumsum(ws)
     run_start_cw = jnp.take(

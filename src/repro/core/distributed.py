@@ -50,15 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# jax.shard_map graduated from jax.experimental in 0.5.x; support both
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# lax.pvary arrived with the 0.5.x varying-axes checker; under the older
-# shard_map every value is already device-varying, so it's the identity
-_pvary = getattr(lax, "pvary", lambda x, axes: x)
-
 from repro.core.executor import Executor, _State
 from repro.core.plan import (
     FreqJoinOp,
@@ -100,7 +91,8 @@ def ring_freq_join(pk, pf, ck, cf, *, ring_axes: Sequence[str],
         then two searchsorteds + a gather.  Saves (P−1) sorts per join —
         see EXPERIMENTS.md §Perf (engine cell).
     """
-    mult = _pvary(jnp.zeros(pk.shape, pf.dtype), tuple(ring_axes))
+    mult = lax.pcast(jnp.zeros(pk.shape, pf.dtype), tuple(ring_axes),
+                     to="varying")
 
     def rotate(x, axis):
         size = lax.psum(1, axis)
@@ -353,16 +345,20 @@ class DistributedExecutor(Executor):
 
         def run(db: dict[str, Table]):
             specs = jax.tree.map(lambda _: spec, db)
-            outs = _shard_map(sweep, mesh=self.mesh, in_specs=(specs,),
-                              out_specs=spec)(db)
+            outs = jax.shard_map(sweep, mesh=self.mesh, in_specs=(specs,),
+                                 out_specs=spec)(db)
             results = []
             for plan, (cols, freq) in zip(plans, outs):
                 # replicate the (exact, order-independent) sweep output so
                 # the aggregate program is the single-device one on every
-                # device — bitwise parity with the local executor
-                cols = {v: jax.lax.with_sharding_constraint(c, rep)
+                # device — bitwise parity with the local executor.
+                # ``reshard`` (not a sharding constraint) also changes the
+                # array's sharding TYPE, which meshes with Explicit axes
+                # (``jax.make_mesh``'s default) need before the aggregate's
+                # gathers can resolve their output sharding
+                cols = {v: jax.sharding.reshard(c, rep)
                         for v, c in cols.items()}
-                freq = jax.lax.with_sharding_constraint(freq, rep)
+                freq = jax.sharding.reshard(freq, rep)
                 results.append(self._final_agg(plan, plan.root.op,
                                                _State(cols, freq)))
             return results

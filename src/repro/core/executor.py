@@ -77,7 +77,7 @@ class _State:
 class Executor:
     def __init__(self, db: dict[str, Table], schema: Schema,
                  freq_dtype=jnp.int32, backend: str = "xla",
-                 interpret: bool = True, oom_guard: int | None = None,
+                 oom_guard: int | None = None,
                  dense_domain: bool = False,
                  span_hook: Callable[[str], Any] | None = None,
                  profile_annotations: bool = False,
@@ -86,7 +86,6 @@ class Executor:
         self.schema = schema
         self.freq_dtype = freq_dtype
         self.backend = backend
-        self.interpret = interpret
         self.oom_guard = oom_guard
         # beyond-paper: sort-free scatter-add FreqJoin on dense key domains
         self.dense_domain = dense_domain
@@ -109,7 +108,7 @@ class Executor:
         ``compile()`` accepts.  Use when one benchmark harness drives both
         guarded eager baselines and jitted plans."""
         return Executor(self.db, self.schema, self.freq_dtype, self.backend,
-                        self.interpret, oom_guard=None,
+                        oom_guard=None,
                         dense_domain=self.dense_domain,
                         span_hook=self.span_hook,
                         profile_annotations=self.profile_annotations,
@@ -121,10 +120,7 @@ class Executor:
         trace annotation around one executor phase."""
         with contextlib.ExitStack() as stack:
             if self.profile_annotations:
-                try:
-                    stack.enter_context(jax.profiler.TraceAnnotation(name))
-                except Exception:
-                    pass  # profiler unavailable on this backend — skip
+                stack.enter_context(jax.profiler.TraceAnnotation(name))
             if self.span_hook is not None:
                 stack.enter_context(self.span_hook(name))
             yield
@@ -176,7 +172,6 @@ class Executor:
         ck, cdom = self._key(plan, op.child, c, op.on_vars)
         freq = kops.semi_join(pk, p.freq, ck, c.freq,
                               backend=self.backend,
-                              interpret=self.interpret,
                               domain=cdom,
                               config=self._tune_cfg(
                                   "semi_join", pk.shape[0], ck.shape[0]))
@@ -189,11 +184,10 @@ class Executor:
         cf = c.freq
         if op.pregroup and cdom is None:
             ck, cf, _valid = kops.group_by_sum(
-                ck, cf, backend=self.backend, interpret=self.interpret,
+                ck, cf, backend=self.backend,
                 config=self._tune_cfg("segment_sum", ck.shape[0]))
         freq = kops.freq_join(pk, p.freq, ck, cf,
                               backend=self.backend,
-                              interpret=self.interpret,
                               domain=cdom,
                               config=self._tune_cfg(
                                   "freq_join", pk.shape[0], ck.shape[0]))
@@ -274,7 +268,7 @@ class Executor:
                 f"tuples (> {self.oom_guard})")
         stats.record(f"join({op.parent}⋈{op.child})", total)
         pidx = np.repeat(np.arange(len(pk)), counts)
-        offs = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        offs = np.cumsum(counts) - counts    # also right with no live rows
         within = np.arange(total) - np.repeat(offs, counts)
         cidx = order[np.repeat(lo, counts) + within]
 
@@ -353,8 +347,7 @@ class Executor:
         whose semi/freq joins are ring sweeps over the mesh); the traversal
         itself — content-key memoisation, sub-DAG dedup, multi-plan fusion
         — is shared and lives only in ``_trace_plan``."""
-        return Executor(db, self.schema, self.freq_dtype,
-                        self.backend, self.interpret,
+        return Executor(db, self.schema, self.freq_dtype, self.backend,
                         dense_domain=self.dense_domain,
                         tuning=self.tuning)
 

@@ -24,7 +24,8 @@ The config space, per (kernel, backend):
   domain range the bucket may see, not at one lucky point.
 * ``("freq_join"|"semi_join", "pallas")`` — ``parent_block_rows`` ×
   ``child_block_rows`` for the blocked broadcast-compare kernels.
-* ``("segment_sum", "pallas")``          — ``lanes_wide`` block width.
+* ``("segment_sum", "pallas")``          — ``lanes_wide``, elements per
+  ``(lanes_wide // 128, 128)`` block (a multiple of one 8×128 tile).
 * ``("segment_sum", "xla")``             — nothing to tune (one
   candidate); ``search`` returns the default without measuring.
 
@@ -65,9 +66,10 @@ class KernelConfig:
     traces one program per (shapes, backend, config).
 
     The defaults reproduce the untuned behaviour exactly: 8×128 fp32
-    native tiles for the blocked joins, (1, 1024) blocks for the
-    segmented sum, and the historical ``max(4·nc, 2^20)`` dense-domain
-    crossover.  ``dense_ratio <= 0`` disables the dense path entirely.
+    native tiles for the blocked joins, (8, 128) blocks (``lanes_wide``
+    = 1024 elements) for the segmented sum, and the historical
+    ``max(4·nc, 2^20)`` dense-domain crossover.  ``dense_ratio <= 0``
+    disables the dense path entirely.
     """
 
     parent_block_rows: int = 8
@@ -120,7 +122,7 @@ def candidate_configs(kernel: str, backend: str) -> list[KernelConfig]:
                     DEFAULT_CONFIG, parent_block_rows=pbr,
                     child_block_rows=cbr))
     elif kernel == "segment_sum" and backend != "xla":
-        for lw in (512, 2048, 4096):
+        for lw in (2048, 4096, 8192):
             out.append(dataclasses.replace(DEFAULT_CONFIG, lanes_wide=lw))
     return out
 
@@ -237,13 +239,11 @@ class KernelTuner:
     depending on ``benchmarks/``.
     """
 
-    def __init__(self, store=None, *, backend: str = "xla",
-                 interpret: bool = True, repeats: int = 3,
+    def __init__(self, store=None, *, backend: str = "xla", repeats: int = 3,
                  row: Callable[..., Any] | None = None):
         self.table = TuneTable()
         self.store = store
         self.backend = backend
-        self.interpret = interpret
         self.repeats = repeats
         self.row = row
         self._lock = threading.Lock()
@@ -358,16 +358,15 @@ class KernelTuner:
                 def fn(cfg, args=args, dom=dom):
                     return ops.freq_join(
                         *args, mode=mode, backend=self.backend,
-                        interpret=self.interpret, domain=dom, config=cfg)
+                        domain=dom, config=cfg)
 
                 out.append((f"domain{dom}", fn))
             return out
         keys, vals = _synth_segment(bshape)
 
         def fn(cfg):
-            return ops.segment_sum_sorted(
-                keys, vals, backend=self.backend,
-                interpret=self.interpret, config=cfg)
+            return ops.segment_sum_sorted(keys, vals, backend=self.backend,
+                                          config=cfg)
 
         return [("sorted", fn)]
 

@@ -3,8 +3,9 @@
 Every physical operator has two interchangeable backends:
 
   * ``"pallas"`` — the TPU kernels in freq_join.py / semi_join.py /
-    segment_sum.py (on this CPU container they run in interpret mode,
-    which executes the kernel body in Python and is used for validation);
+    segment_sum.py — compiled by Mosaic on a TPU, and run through the
+    Pallas interpreter on any other platform (``interpret_mode``), which
+    is how the CPU tests validate them;
   * ``"xla"``    — algorithmically equivalent sort/searchsorted/segment-sum
     formulations lowered by XLA; these are what the CPU benchmarks time and
     what the distributed executor traces through `shard_map` (collectives
@@ -45,6 +46,14 @@ def default_backend() -> str:
     return os.environ.get("REPRO_KERNEL_BACKEND", "xla")
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run through the interpreter: never on a TPU,
+    always elsewhere (the interpreter is how the CPU tests run them).
+    Derived from the platform here and nowhere else, so no caller can run
+    kernel bodies through the interpreter on the chip."""
+    return jax.default_backend() != "tpu"
+
+
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
@@ -60,7 +69,7 @@ def _pad1(a: jax.Array, n: int, fill) -> jax.Array:
 # --------------------------------------------------------------------------
 def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
               mode: str = "sum", backend: str | None = None,
-              interpret: bool = True, domain: int | None = None,
+              domain: int | None = None,
               config: KernelConfig | None = None):
     """R ⋉^freq S — returns updated parent frequencies (paper §5).
 
@@ -78,7 +87,8 @@ def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
     backend = backend or default_backend()
     config = config or DEFAULT_CONFIG
     return _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq,
-                           mode=mode, backend=backend, interpret=interpret,
+                           mode=mode, backend=backend,
+                           interpret=interpret_mode(),
                            domain=domain, config=config)
 
 
@@ -140,20 +150,18 @@ def _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq, *,
 
 
 def semi_join(parent_keys, parent_freq, child_keys, child_freq, *,
-              backend: str | None = None, interpret: bool = True,
-              domain: int | None = None,
+              backend: str | None = None, domain: int | None = None,
               config: KernelConfig | None = None):
     """R ⋉ S over live tuples (0MA sweep step, paper §4.1)."""
     return freq_join(parent_keys, parent_freq, child_keys, child_freq,
-                     mode="any", backend=backend, interpret=interpret,
-                     domain=domain, config=config)
+                     mode="any", backend=backend, domain=domain,
+                     config=config)
 
 
 # --------------------------------------------------------------------------
 # Segment sum (sorted group-by-SUM)
 # --------------------------------------------------------------------------
 def segment_sum_sorted(sorted_keys, values, *, backend: str | None = None,
-                       interpret: bool = True,
                        config: KernelConfig | None = None):
     """GROUP BY key, SUM(value) over key-sorted input.
 
@@ -162,7 +170,7 @@ def segment_sum_sorted(sorted_keys, values, *, backend: str | None = None,
     backend = backend or default_backend()
     config = config or DEFAULT_CONFIG
     return _segment_sum_impl(sorted_keys, values, backend=backend,
-                             interpret=interpret, config=config)
+                             interpret=interpret_mode(), config=config)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "interpret",
@@ -191,15 +199,13 @@ def _segment_sum_impl(sorted_keys, values, *, backend: str, interpret: bool,
 
 
 def group_by_sum(keys, values, *, backend: str | None = None,
-                 interpret: bool = True,
                  config: KernelConfig | None = None):
     """Unsorted group-by: sort once, then segment-sum.  Returns
     (sorted_keys, sums, valid) so downstream FreqJoins can reuse the sort."""
     order = jnp.argsort(keys)
     ks = keys[order]
     vs = values[order]
-    sums, valid = segment_sum_sorted(ks, vs, backend=backend,
-                                     interpret=interpret, config=config)
+    sums, valid = segment_sum_sorted(ks, vs, backend=backend, config=config)
     return ks, sums, valid
 
 
@@ -211,11 +217,14 @@ def weighted_percentile(values, weights, q):
     """PERCENTILE(q, A, freq) — lower-interpolation weighted percentile.
 
     Rows with weight 0 (dead tuples) are ignored: their values are moved to
-    +inf before the sort so they never land below the target mass.
+    +inf before the sort so they never land below the target mass.  With
+    no rows at all the answer is that same +inf, as if all were dead.
     """
     big = jnp.asarray(jnp.finfo(values.dtype).max if
                       jnp.issubdtype(values.dtype, jnp.floating)
                       else jnp.iinfo(values.dtype).max, values.dtype)
+    if values.shape[0] == 0:
+        return big
     v = jnp.where(weights > 0, values, big)
     order = jnp.argsort(v)
     vs = v[order]

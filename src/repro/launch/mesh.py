@@ -12,14 +12,11 @@ import numpy as np
 
 
 def make_auto_mesh(shape, axes, devices=None):
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (≥0.5.x); older versions are Auto-only, so the kwarg is
-    simply dropped."""
-    kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+    """jax.make_mesh with Auto axis types (``jax.make_mesh`` defaults to
+    Explicit ones): the model code places arrays with sharding
+    constraints and leaves propagation to XLA."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

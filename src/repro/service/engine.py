@@ -69,9 +69,10 @@ requests for the same artefact build it once.  ``metrics()`` and
 an eager baseline run.
 
 Warm starts: ``QueryService(db, schema, cache_dir=...)`` persists every
-shareable plan to a ``PlanStore`` under ``cache_dir`` and points JAX's
-persistent compilation cache at ``cache_dir/xla`` — so a NEW process over
-the same schema replays known query structures with zero plan rebuilds
+shareable plan to a ``PlanStore`` under ``cache_dir`` and turns on JAX's
+persistent compilation cache (``enable_executable_cache``) — so a NEW
+process over the same schema replays known query structures with zero
+plan rebuilds
 (``plan_builds`` stays 0; the disk level answers, ``persist_hits``
 counting) and pulls previously compiled XLA binaries from disk instead of
 recompiling.  Plan lookup order is memory → disk → plan; disk failures of
@@ -270,7 +271,7 @@ class QueryService:
     def __init__(self, db: dict[str, Table], schema: Schema, *,
                  mode: str = "auto", use_fkpk: bool = False,
                  freq_dtype=jnp.int32, backend: str = "xla",
-                 interpret: bool = True, dense_domain: bool = False,
+                 dense_domain: bool = False,
                  plan_capacity: int = 256, exec_capacity: int = 512,
                  fused_capacity: int = 128, padded_capacity: int = 64,
                  min_bucket: int = 8, async_max_batch: int = 64,
@@ -358,7 +359,7 @@ class QueryService:
                 self.obs.set_gauge(f"mesh_shard_count_{a}", n)
         else:
             self._jit_executor = Executor(
-                self._db, schema, freq_dtype, backend, interpret,
+                self._db, schema, freq_dtype, backend,
                 dense_domain=dense_domain,
                 profile_annotations=profile_annotations)
             self._topo = ()
@@ -369,14 +370,14 @@ class QueryService:
             # the store identity covers schema AND planner configuration
             # AND shard topology: plans are planner output, so a store
             # warmed under another mode/use_fkpk must never serve this
-            # service, and a mesh config's warm-start state (incl. the XLA
-            # executable cache beside it) stays disjoint per topology
+            # service, and a mesh config's warm-start state stays disjoint
+            # per topology
             store = PlanStore(cache_dir,
                               store_fingerprint(schema, mode, use_fkpk,
                                                 topology=self._topo))
             # executables warm-start through JAX's own persistent
             # compilation cache (process-global; see plan_store docs)
-            enable_executable_cache(store.root / "xla")
+            enable_executable_cache()
             # tuned kernel configs persist beside the plans, scoped by the
             # same topology (per-shard buckets tune differently)
             tune_store = TuneStore(cache_dir, topology=self._topo)
@@ -387,8 +388,7 @@ class QueryService:
         # so serving (and ``autotune()``) re-measures nothing
         # (``tune_searches == 0``).  The executor reads the table at trace
         # time, so installed configs take effect on the next compile.
-        self.tuner = KernelTuner(tune_store, backend=backend,
-                                 interpret=interpret)
+        self.tuner = KernelTuner(tune_store, backend=backend)
         self.tuner.load_persisted()
         self._jit_executor.tuning = self.tuner.table
         # cost-calibrated planning: one statistics catalog feeds the gated
@@ -1323,7 +1323,7 @@ class QueryService:
             # database state even if update_table swaps relations mid-run
             sub_db = {rel: self._db[rel] for rel in u.plan.scanned_rels()}
         ex = Executor(sub_db, self.schema, base.freq_dtype, base.backend,
-                      base.interpret, dense_domain=base.dense_domain,
+                      dense_domain=base.dense_domain,
                       tuning=base.tuning)
         stats = ExecStats()
         with self.obs.span(roots, "run", eager=True) as rsp:

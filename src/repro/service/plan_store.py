@@ -13,10 +13,6 @@ store fingerprint — schema structure + planner configuration — so
 differently-configured services share a ``cache_dir`` without collisions)::
 
     <root>/plans/<sfp>/<fingerprint>.json   one plan per query structure
-    <root>/xla/...                          JAX persistent compilation
-                                            cache (it keys on the HLO, so
-                                            it is safely shared; see
-                                            ``enable_executable_cache``)
 
 Each entry is a JSON document with a header the loader verifies before
 trusting the body:
@@ -37,11 +33,12 @@ Writes are atomic (temp file + ``os.replace``) and best-effort: a full or
 read-only disk degrades the service to memory-only caching (counted in
 ``persist_write_errors``), it never fails a request.
 
-Executable persistence rides on JAX's own compilation cache:
-``enable_executable_cache`` points ``jax_compilation_cache_dir`` at the
-store's ``xla/`` subdirectory with thresholds zeroed, so a warm-started
-process that replays a known (graph_key, shape-bucket) trace gets its XLA
-binary from disk instead of recompiling.
+Executable persistence rides on JAX's own compilation cache, which keys
+on the program and so is shared by every store: ``enable_executable_cache``
+turns it on with thresholds zeroed, in the directory
+``JAX_COMPILATION_CACHE_DIR`` names or else at one fixed path inside the
+checkout, so a warm-started process that replays a known (graph_key,
+shape-bucket) trace gets its XLA binary from disk instead of recompiling.
 """
 
 from __future__ import annotations
@@ -88,9 +85,8 @@ def store_fingerprint(schema: Schema, mode: str = "auto",
     ``use_fkpk=True`` store must not impose FK-trusting semi-joins on a
     service configured not to trust the declared FKs.  ``topology`` is the
     serving mesh's ``(axis_names, shard_counts)`` (``()`` on a single
-    device): a mesh service's warm-start bookkeeping (and the XLA
-    executable cache living beside its entries) describes programs lowered
-    for that mesh shape, so differently-sharded services keep disjoint
+    device): a mesh service's warm-start bookkeeping describes programs
+    lowered for that mesh shape, so differently-sharded services keep disjoint
     entry directories under one ``cache_dir`` and never leak state across
     configs."""
     return hashlib.sha256(repr((schema_fingerprint(schema), mode,
@@ -106,44 +102,32 @@ def _canonical_body(payload: dict) -> bytes:
                       separators=(",", ":")).encode()
 
 
-def enable_executable_cache(path) -> bool:
-    """Point JAX's persistent compilation cache at `path` (thresholds
-    zeroed so every serving executable qualifies).  Best-effort and
-    process-global: JAX has ONE compilation cache directory, so the last
-    service to enable it wins — which is the common case of one service
-    per process.  Returns False (and leaves JAX untouched) when the flags
-    are unavailable or the directory cannot be created."""
-    import jax
+# The one place the persistent XLA cache lives when the environment does
+# not name one: a fixed path inside the checkout.  A directory that moves
+# between runs (a temp dir, a per-store subdirectory) never hits.
+EXECUTABLE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-    try:
-        os.makedirs(path, exist_ok=True)
-    except OSError:
-        return False
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except Exception:
-        return False
-    # thresholds and backend toggles are advisory — missing flags on an
-    # older jax leave the cache enabled with its defaults
-    for flag, value in (
-            ("jax_persistent_cache_min_compile_time_secs", 0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(flag, value)
-        except Exception:
-            pass
-    # jax initialises its cache handle lazily ON FIRST COMPILE and never
-    # re-reads the directory config afterwards — a service constructed
-    # after any prior jit (tests, another service) would silently get no
-    # persistence without this reset
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc,
-        )
+
+def enable_executable_cache() -> str:
+    """Turn on JAX's persistent compilation cache for every executable
+    (thresholds zeroed so serving programs qualify) and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has read
+    it and no directory is set here; otherwise the cache goes to
+    ``EXECUTABLE_CACHE_DIR``.  Process-global, like JAX's cache itself."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return jax.config.jax_compilation_cache_dir
+    path = str(EXECUTABLE_CACHE_DIR)
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+        # JAX opens its cache on the first compile and never re-reads the
+        # directory afterwards
         cc.reset_cache()
-    except Exception:
-        pass
-    return True
+    return path
 
 
 class PlanStore:

@@ -165,7 +165,7 @@ def test_pallas_backend_engine_agrees():
     db, schema = make_graph_db(n_nodes=10, n_edges=30, seed=5)
     q = path_query(2)
     want = brute_force_count(db, schema, q)
-    ex = Executor(db, schema, backend="pallas", interpret=True)
+    ex = Executor(db, schema, backend="pallas")
     got = ex.execute(plan_query(q, schema, mode="opt_plus"))["count(*)"]
     assert int(got) == want
 
@@ -210,6 +210,32 @@ def test_tpch_v1_fkpk_plan_uses_semijoins():
     # the ps→p and s→ps edges: ps child of s is NOT fk/pk (s holds PK),
     # so at least one FreqJoin must remain
     assert any(isinstance(op, FreqJoinOp) for op in plan.ops)
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["scalar", "grouped"])
+@pytest.mark.parametrize("mode", ["ref", "opt", "opt_plus"])
+def test_join_with_no_answers(mode, grouped):
+    """A selection no part passes: the eager baselines' joins materialise
+    no row at all, the frequency plans keep dead rows.  Every plan class
+    answers as dead rows do — COUNT 0, MIN and MEDIAN at the dtype's max,
+    MAX at its min, no live group — instead of failing on an empty
+    array."""
+    db, schema = make_tpch_db(scale=10, seed=1)
+    base = tpch_v1_query("minmax", price_threshold=1e9)
+    aggs = (Agg("min", "bal"), Agg("max", "bal"), Agg("median", "bal"),
+            Agg("count"))
+    q = AggQuery(atoms=base.atoms, aggregates=aggs,
+                 group_by=("nk",) if grouped else (),
+                 selections=base.selections)
+    res = Executor(db, schema).execute(plan_query(q, schema, mode=mode))
+    big = np.finfo(np.float32).max
+    if grouped:
+        assert not np.asarray(res["valid"]).any()
+        return
+    assert int(res["count(*)"]) == 0
+    assert float(res["min(bal)"]) == big
+    assert float(res["median(bal)"]) == big
+    assert float(res["max(bal)"]) == -big
 
 
 # ---------------------------------------------------------------------------

@@ -104,7 +104,7 @@ class LinearReference:
 
         def run(db):
             inner = Executor(db, outer.schema, outer.freq_dtype,
-                             outer.backend, outer.interpret,
+                             outer.backend,
                              dense_domain=outer.dense_domain)
             return self._sweep(inner, plan)
 

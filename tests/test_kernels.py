@@ -137,7 +137,7 @@ def test_freq_join_config_space_matches_oracle(config, np_, nc, backend):
                                   np.asarray(want_semi))
 
 
-@pytest.mark.parametrize("lanes", [512, 1024, 2048])
+@pytest.mark.parametrize("lanes", [4096, 1024, 2048])
 @pytest.mark.parametrize("n", [1000, 17, 4096])
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_segment_sum_config_space_matches_default(lanes, n, backend):
@@ -152,6 +152,46 @@ def test_segment_sum_config_space_matches_default(lanes, n, backend):
                                  config=KernelConfig(lanes_wide=lanes))
     for b, g in zip(base, got):
         np.testing.assert_array_equal(np.asarray(b), np.asarray(g))
+
+
+@pytest.mark.parametrize("distinct", [1, 3, 37])
+def test_pallas_segment_sum_runs_across_rows_and_blocks(distinct):
+    """Runs longer than a 128-lane row and a (8, 128) block: the row and
+    block carries of the kernel, against the oracle row by row."""
+    n = 3 * 1024 + 77
+    rng = np.random.default_rng(distinct)
+    keys = jnp.sort(jnp.asarray(rng.integers(0, distinct, n), jnp.int32))
+    vals = jnp.asarray(rng.integers(-3, 5, n), jnp.int32)
+    got, valid = ops.segment_sum_sorted(keys, vals, backend="pallas")
+    xla, xla_valid = ops.segment_sum_sorted(keys, vals, backend="xla")
+    np.testing.assert_array_equal(np.asarray(valid), np.asarray(xla_valid))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(xla))
+    want, first = ref.segment_sum_ref(keys, vals)
+    want, first = np.asarray(want), np.asarray(first)
+    np.testing.assert_array_equal(np.asarray(got)[np.asarray(valid)],
+                                  want[first])
+
+
+@pytest.mark.parametrize("platform,interpret",
+                         [("tpu", False), ("cpu", True), ("gpu", True)])
+def test_interpret_follows_platform(monkeypatch, platform, interpret):
+    """Pallas kernels compile on a TPU and run through the interpreter
+    anywhere else, with no option to say otherwise: every kernel entry
+    point hands its jitted implementation the platform's answer."""
+    k = jnp.arange(8, dtype=jnp.int32)
+    seen = []
+
+    def spy(*args, interpret, **kwargs):
+        seen.append(interpret)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(ops, "_freq_join_impl", spy)
+    monkeypatch.setattr(ops, "_segment_sum_impl", spy)
+    assert ops.interpret_mode() is interpret
+    ops.freq_join(k, k, k, k, backend="pallas")
+    ops.semi_join(k, k, k, k, backend="pallas")
+    ops.segment_sum_sorted(k, k, backend="pallas")
+    assert seen == [interpret] * 3
 
 
 # ---------------------------------------------------------------------------

@@ -81,6 +81,47 @@ def tpch():
     return make_tpch_db(scale=8, seed=7)
 
 
+GROUPBY = """
+SELECT COUNT(*) AS suppliers, AVG(s.s_acctbal) AS avg_bal
+FROM supplier s, nation n
+WHERE s.s_nationkey = n.n_nationkey
+GROUP BY s.s_nationkey
+"""
+
+
+def _assert_bitwise(want: dict, got: dict, ctx: str):
+    assert set(want) - {"__stats__"} == set(got) - {"__stats__"}, ctx
+    for k in set(want) - {"__stats__"}:
+        a, b = want[k], got[k]
+        pairs = ([(np.asarray(a[c]), np.asarray(b[c])) for c in a]
+                 if isinstance(a, dict) else [(np.asarray(a),
+                                               np.asarray(b))])
+        for x, y in pairs:
+            assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), (ctx, k)
+
+
+@pytest.mark.parametrize("query", ["median", "groupby"])
+def test_distributed_executor_on_default_explicit_mesh(tpch, query):
+    """``jax.make_mesh`` gives Explicit axes by default.  MEDIAN and GROUP
+    BY gather on the root columns after the shard_map, which only resolve
+    once those columns are replicated: the mesh answer must equal the
+    local executor's bitwise over identically padded tables."""
+    from repro.core import Executor, parse_sql, plan_query
+    from repro.core.distributed import DistributedExecutor
+
+    db, schema = tpch
+    mesh = _mesh1()
+    assert mesh.axis_types == (jax.sharding.AxisType.Explicit,)
+    q = (tpch_v1_query("median") if query == "median"
+         else parse_sql(GROUPBY, schema))
+    plan = plan_query(q, schema, mode="opt_plus")
+    dex = DistributedExecutor(schema, mesh)
+    sharded = dex.shard_db(db)
+    host = {k: db[k].pad_to(sharded[k].capacity) for k in db}
+    want = dict(Executor(db, schema).compile(plan)(host))
+    _assert_bitwise(want, dict(dex.compile(plan)(sharded)), query)
+
+
 def test_mesh_and_local_services_occupy_distinct_exec_entries(tpch):
     db, schema = tpch
     q = tpch_v1_query("minmax")

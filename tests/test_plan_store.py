@@ -16,6 +16,7 @@ import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -394,6 +395,41 @@ def test_unwritable_cache_dir_never_crashes_construction(tmp_path, tpch):
     m = svc.metrics()
     assert m["persist_hits"] == 0 and m["persist_entries"] == 0
     assert m["persist_write_errors"] >= 1
+
+
+def test_executable_cache_directory(tmp_path, monkeypatch, tpch):
+    """The XLA cache is never placed under ``cache_dir``: the directory
+    JAX read from ``JAX_COMPILATION_CACHE_DIR`` is left alone, and
+    without the variable the cache goes to one fixed path in the
+    checkout."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.service.plan_store import (
+        EXECUTABLE_CACHE_DIR,
+        enable_executable_cache,
+    )
+
+    db, schema = tpch
+    was = jax.config.jax_compilation_cache_dir
+    env_dir = str(tmp_path / "from-env")
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        jax.config.update("jax_compilation_cache_dir", env_dir)  # as read
+        svc = QueryService(db, schema, cache_dir=tmp_path / "plans")
+        assert svc.submit(COSTLY_PARTS).error is None
+        assert jax.config.jax_compilation_cache_dir == env_dir
+        assert enable_executable_cache() == env_dir
+        assert not list((tmp_path / "plans").rglob("xla"))
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_executable_cache() == str(EXECUTABLE_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir \
+            == str(EXECUTABLE_CACHE_DIR)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert EXECUTABLE_CACHE_DIR.parent == Path(repo)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        cc.reset_cache()
 
 
 def test_export_import_cache(tmp_path, tpch):

@@ -104,8 +104,7 @@ def test_compressed_psum_matches_psum_within_quant_error():
     def f(g, r):
         return CompressedPsum.psum(g, r, "pod")
 
-    from repro.core.distributed import _shard_map
-    out, new_res = jax.jit(_shard_map(
+    out, new_res = jax.jit(jax.shard_map(
         f, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(),) * 2,
         out_specs=jax.sharding.PartitionSpec()))(grads, res)
