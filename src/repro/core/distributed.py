@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.executor import Executor, _State
+from repro.core.executor import Executor, _State, named
 from repro.core.plan import (
     FreqJoinOp,
     PhysicalPlan,
@@ -252,11 +252,8 @@ class DistributedExecutor(Executor):
     def __init__(self, schema: Schema, mesh: jax.sharding.Mesh,
                  data_axes: Sequence[str] = ("data",),
                  freq_dtype=jnp.int32, presort: bool = False,
-                 dense_domain: bool = False,
-                 span_hook=None, profile_annotations: bool = False):
-        super().__init__({}, schema, freq_dtype,
-                         dense_domain=dense_domain, span_hook=span_hook,
-                         profile_annotations=profile_annotations)
+                 dense_domain: bool = False):
+        super().__init__({}, schema, freq_dtype, dense_domain=dense_domain)
         self.mesh = mesh
         self.data_axes = tuple(data_axes)
         self.presort = presort
@@ -359,24 +356,23 @@ class DistributedExecutor(Executor):
                 cols = {v: jax.sharding.reshard(c, rep)
                         for v, c in cols.items()}
                 freq = jax.sharding.reshard(freq, rep)
-                results.append(self._final_agg(plan, plan.root.op,
-                                               _State(cols, freq)))
+                with jax.named_scope("final_agg"):
+                    results.append(self._final_agg(plan, plan.root.op,
+                                                   _State(cols, freq)))
             return results
 
         return run
 
-    def compile(self, plan: PhysicalPlan):
+    def compile(self, plan: PhysicalPlan, name: str = "run"):
         """Jit one plan's ring program: sharded db → aggregates."""
         self._check_jittable([plan])
-        run = self._ring_program([plan])
-        return self._wrap_jitted(jax.jit(lambda db: run(db)[0]),
-                                 "executor.run")
+        ring = self._ring_program([plan])
+        return jax.jit(named(lambda db: ring(db)[0], name))
 
-    def compile_multi(self, plans: list[PhysicalPlan]):
+    def compile_multi(self, plans: list[PhysicalPlan], name: str = "run"):
         """Jit several plans into ONE mesh program (shared ring sweeps):
         sharded db → [aggregates], results in plan order."""
         if not plans:
             raise ValueError("compile_multi needs at least one plan")
         self._check_jittable(plans)
-        return self._wrap_jitted(jax.jit(self._ring_program(list(plans))),
-                                 "executor.run_multi")
+        return jax.jit(named(self._ring_program(list(plans)), name))

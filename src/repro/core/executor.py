@@ -31,7 +31,6 @@ raises ``MaterialisationLimit`` (reported as the paper's X entries).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Any, Callable
 
@@ -79,8 +78,6 @@ class Executor:
                  freq_dtype=jnp.int32, backend: str = "xla",
                  oom_guard: int | None = None,
                  dense_domain: bool = False,
-                 span_hook: Callable[[str], Any] | None = None,
-                 profile_annotations: bool = False,
                  tuning=None):
         self.db = db
         self.schema = schema
@@ -94,14 +91,6 @@ class Executor:
         # kernel input sizes — already bucket-padded on the serving path,
         # so the lookup lands on the bucket the entry was tuned at
         self.tuning = tuning
-        # observability hooks: span_hook(name) -> context manager wraps the
-        # trace/execute phases (the serving tier wires its own spans above
-        # this layer; the hook is for standalone Executor users), and
-        # profile_annotations=True additionally emits
-        # jax.profiler.TraceAnnotation markers so the phases show up named
-        # in a JAX/Perfetto profiler capture
-        self.span_hook = span_hook
-        self.profile_annotations = profile_annotations
 
     def jittable(self) -> "Executor":
         """Copy with eager-only options stripped — the configuration
@@ -110,20 +99,7 @@ class Executor:
         return Executor(self.db, self.schema, self.freq_dtype, self.backend,
                         oom_guard=None,
                         dense_domain=self.dense_domain,
-                        span_hook=self.span_hook,
-                        profile_annotations=self.profile_annotations,
                         tuning=self.tuning)
-
-    @contextlib.contextmanager
-    def _span(self, name: str):
-        """Enter the caller's span hook and (optionally) a jax.profiler
-        trace annotation around one executor phase."""
-        with contextlib.ExitStack() as stack:
-            if self.profile_annotations:
-                stack.enter_context(jax.profiler.TraceAnnotation(name))
-            if self.span_hook is not None:
-                stack.enter_context(self.span_hook(name))
-            yield
 
     # ------------------------------------------------------------------
     def _domains(self, plan: PhysicalPlan, alias: str) -> dict[str, int | None]:
@@ -183,9 +159,10 @@ class Executor:
         ck, cdom = self._key(plan, op.child, c, op.on_vars)
         cf = c.freq
         if op.pregroup and cdom is None:
-            ck, cf, _valid = kops.group_by_sum(
-                ck, cf, backend=self.backend,
-                config=self._tune_cfg("segment_sum", ck.shape[0]))
+            with jax.named_scope("pregroup"):
+                ck, cf, _valid = kops.group_by_sum(
+                    ck, cf, backend=self.backend,
+                    config=self._tune_cfg("segment_sum", ck.shape[0]))
         freq = kops.freq_join(pk, p.freq, ck, cf,
                               backend=self.backend,
                               domain=cdom,
@@ -203,12 +180,6 @@ class Executor:
         overwritten in place (a ref-mode chain of materialising joins must
         not retain every expanded intermediate until the end)."""
         stats = stats if stats is not None else ExecStats()
-        if self.span_hook is not None or self.profile_annotations:
-            with self._span("executor.execute"):
-                return self._execute_inner(plan, stats)
-        return self._execute_inner(plan, stats)
-
-    def _execute_inner(self, plan: PhysicalPlan, stats: ExecStats):
         consumers: dict[int, int] = {}
         for node in plan.nodes:
             for i in node.inputs:
@@ -364,6 +335,11 @@ class Executor:
         how a fused multi-query program runs each common sub-DAG exactly
         once even when the member plans' overall join shapes differ.
 
+        Each node's own work runs under a ``jax.named_scope`` of its
+        operator (``scan``, ``semi_join``, ``freq_join``, ``final_agg``),
+        entered after its inputs are evaluated, so a device operation's
+        ``op_name`` names the operator that emitted it and no other.
+
         ``root`` selects where evaluation stops (default: the whole plan,
         ``plan.root``).  The mesh path evaluates to ``plan.root.inputs[0]``
         — the pre-aggregate root state — inside its shard_map program and
@@ -378,7 +354,8 @@ class Executor:
             op = node.op
             key = node.key() if memo is not None else None
             if isinstance(op, ScanOp):
-                st = inner._scan(plan, op)
+                with jax.named_scope("scan"):
+                    st = inner._scan(plan, op)
                 if key is not None:
                     if key in memo:
                         st = _State(st.cols, memo[key])
@@ -390,13 +367,18 @@ class Executor:
                     st = _State(p.cols, memo[key])
                 else:
                     c = ev(node.inputs[1])
-                    st = inner._semi_join(plan, op, p, c) \
-                        if isinstance(op, SemiJoinOp) \
-                        else inner._freq_join(plan, op, p, c)
+                    if isinstance(op, SemiJoinOp):
+                        with jax.named_scope("semi_join"):
+                            st = inner._semi_join(plan, op, p, c)
+                    else:
+                        with jax.named_scope("freq_join"):
+                            st = inner._freq_join(plan, op, p, c)
                     if key is not None:
                         memo[key] = st.freq
             elif isinstance(op, FinalAggOp):
-                st = inner._final_agg(plan, op, ev(node.inputs[0]))
+                child = ev(node.inputs[0])
+                with jax.named_scope("final_agg"):
+                    st = inner._final_agg(plan, op, child)
             else:  # pragma: no cover — _check_jittable rejects these
                 raise TypeError(op)
             vals[id(node)] = st
@@ -404,8 +386,10 @@ class Executor:
 
         return ev(plan.root if root is None else root)
 
-    def compile(self, plan: PhysicalPlan):
-        """Jit the static plan classes (oma / opt_plus): db → aggregates."""
+    def compile(self, plan: PhysicalPlan, name: str = "run"):
+        """Jit the static plan classes (oma / opt_plus): db → aggregates.
+        ``name`` names the program: its XLA module is ``jit_<name>``, the
+        name a profiler trace gives its runs."""
         self._check_jittable([plan])
 
         def run(db: dict[str, Table]):
@@ -413,9 +397,9 @@ class Executor:
             # (self-joins scanning one relation twice, say)
             return self._trace_plan(db, plan, memo={})
 
-        return self._wrap_jitted(jax.jit(run), "executor.run")
+        return jax.jit(named(run, name))
 
-    def compile_multi(self, plans: list[PhysicalPlan]):
+    def compile_multi(self, plans: list[PhysicalPlan], name: str = "run"):
         """Jit several static plans into ONE program: db → [aggregates].
 
         The member plans' DAG evaluations share a trace-level memo keyed by
@@ -423,7 +407,8 @@ class Executor:
         across members — a whole prefix, or just a shared scan/semi-join
         chain under different join shapes — is computed once and its
         frequency vector fanned out to every consumer.  One XLA compilation
-        serves every member query; results are returned in plan order."""
+        serves every member query; results are returned in plan order.
+        ``name`` names the program, as in ``compile``."""
         if not plans:
             raise ValueError("compile_multi needs at least one plan")
         self._check_jittable(plans)
@@ -432,20 +417,14 @@ class Executor:
             memo: dict = {}
             return [self._trace_plan(db, plan, memo) for plan in plans]
 
-        return self._wrap_jitted(jax.jit(run), "executor.run_multi")
+        return jax.jit(named(run, name))
 
-    def _wrap_jitted(self, jitted, name: str):
-        """With hooks active, run the jitted callable under a span (its
-        first call also covers the XLA trace + compile); otherwise return
-        it untouched so the serving hot path pays nothing."""
-        if self.span_hook is None and not self.profile_annotations:
-            return jitted
 
-        def wrapped(db: dict[str, Table]):
-            with self._span(name):
-                return jitted(db)
-
-        return wrapped
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: ``jax.jit`` names its program after the
+    function, so the XLA module is ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def shared_subplan_savings(plans: list[PhysicalPlan]) -> int:

@@ -26,6 +26,14 @@ and the config, then dispatch to jitted implementations that carry both
 as static arguments.  Under an outer ``jax.jit`` trace the wrappers
 inline like any other Python, so compiled plans pay nothing for the
 indirection.
+
+Each public entry point opens a ``jax.named_scope`` of its own name
+(``freq_join``, ``semi_join``, ``segment_sum``, ``group_by_sum``,
+``weighted_percentile``), and the sort-based freq-join names its child
+sort (``sort``) and its two binary searches (``search``).  Scopes are HLO
+``op_name`` metadata only: they change neither fusion nor the code that
+runs, and they let a profiler trace name the kernel behind every device
+operation (``repro.core.scopes``).
 """
 
 from __future__ import annotations
@@ -84,12 +92,18 @@ def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
     the domain is unknown or too sparse to justify the accumulator; the
     crossover comes from ``config`` (``dense_ratio``/``dense_floor``).
     """
-    backend = backend or default_backend()
-    config = config or DEFAULT_CONFIG
+    with jax.named_scope("freq_join"):
+        return _freq_join_call(parent_keys, parent_freq, child_keys,
+                               child_freq, mode=mode, backend=backend,
+                               domain=domain, config=config)
+
+
+def _freq_join_call(parent_keys, parent_freq, child_keys, child_freq, *,
+                    mode, backend, domain, config):
     return _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq,
-                           mode=mode, backend=backend,
+                           mode=mode, backend=backend or default_backend(),
                            interpret=interpret_mode(),
-                           domain=domain, config=config)
+                           domain=domain, config=config or DEFAULT_CONFIG)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "backend", "interpret",
@@ -119,15 +133,17 @@ def _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq, *,
             if mode == "any":
                 mult = (mult > 0).astype(parent_freq.dtype)
             return parent_freq * mult
-        order = jnp.argsort(child_keys)
-        ck = child_keys[order]
-        cf = child_freq[order]
+        with jax.named_scope("sort"):
+            order = jnp.argsort(child_keys)
+            ck = child_keys[order]
+            cf = child_freq[order]
         if mode == "any":
             cf = (cf > 0).astype(parent_freq.dtype)
         zero = jnp.zeros((1,), cf.dtype)
         prefix = jnp.concatenate([zero, jnp.cumsum(cf)])
-        lo = jnp.searchsorted(ck, parent_keys, side="left")
-        hi = jnp.searchsorted(ck, parent_keys, side="right")
+        with jax.named_scope("search"):
+            lo = jnp.searchsorted(ck, parent_keys, side="left")
+            hi = jnp.searchsorted(ck, parent_keys, side="right")
         mult = (prefix[hi] - prefix[lo]).astype(parent_freq.dtype)
         if mode == "any":
             mult = (mult > 0).astype(parent_freq.dtype)
@@ -153,9 +169,10 @@ def semi_join(parent_keys, parent_freq, child_keys, child_freq, *,
               backend: str | None = None, domain: int | None = None,
               config: KernelConfig | None = None):
     """R ⋉ S over live tuples (0MA sweep step, paper §4.1)."""
-    return freq_join(parent_keys, parent_freq, child_keys, child_freq,
-                     mode="any", backend=backend, domain=domain,
-                     config=config)
+    with jax.named_scope("semi_join"):
+        return _freq_join_call(parent_keys, parent_freq, child_keys,
+                               child_freq, mode="any", backend=backend,
+                               domain=domain, config=config)
 
 
 # --------------------------------------------------------------------------
@@ -167,10 +184,11 @@ def segment_sum_sorted(sorted_keys, values, *, backend: str | None = None,
 
     Returns (sums, valid): run total at the LAST row of each run.
     """
-    backend = backend or default_backend()
-    config = config or DEFAULT_CONFIG
-    return _segment_sum_impl(sorted_keys, values, backend=backend,
-                             interpret=interpret_mode(), config=config)
+    with jax.named_scope("segment_sum"):
+        return _segment_sum_impl(sorted_keys, values,
+                                 backend=backend or default_backend(),
+                                 interpret=interpret_mode(),
+                                 config=config or DEFAULT_CONFIG)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "interpret",
@@ -202,11 +220,13 @@ def group_by_sum(keys, values, *, backend: str | None = None,
                  config: KernelConfig | None = None):
     """Unsorted group-by: sort once, then segment-sum.  Returns
     (sorted_keys, sums, valid) so downstream FreqJoins can reuse the sort."""
-    order = jnp.argsort(keys)
-    ks = keys[order]
-    vs = values[order]
-    sums, valid = segment_sum_sorted(ks, vs, backend=backend, config=config)
-    return ks, sums, valid
+    with jax.named_scope("group_by_sum"):
+        order = jnp.argsort(keys)
+        ks = keys[order]
+        vs = values[order]
+        sums, valid = segment_sum_sorted(ks, vs, backend=backend,
+                                         config=config)
+        return ks, sums, valid
 
 
 # --------------------------------------------------------------------------
@@ -220,18 +240,20 @@ def weighted_percentile(values, weights, q):
     +inf before the sort so they never land below the target mass.  With
     no rows at all the answer is that same +inf, as if all were dead.
     """
-    big = jnp.asarray(jnp.finfo(values.dtype).max if
-                      jnp.issubdtype(values.dtype, jnp.floating)
-                      else jnp.iinfo(values.dtype).max, values.dtype)
-    if values.shape[0] == 0:
-        return big
-    v = jnp.where(weights > 0, values, big)
-    order = jnp.argsort(v)
-    vs = v[order]
-    acc_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    ws = weights[order].astype(acc_dtype)
-    cw = jnp.cumsum(ws)
-    target = q * cw[-1]
-    idx = jnp.clip(jnp.searchsorted(cw, target, side="left"), 0,
-                   values.shape[0] - 1)
-    return vs[idx]
+    with jax.named_scope("weighted_percentile"):
+        big = jnp.asarray(jnp.finfo(values.dtype).max if
+                          jnp.issubdtype(values.dtype, jnp.floating)
+                          else jnp.iinfo(values.dtype).max, values.dtype)
+        if values.shape[0] == 0:
+            return big
+        v = jnp.where(weights > 0, values, big)
+        order = jnp.argsort(v)
+        vs = v[order]
+        acc_dtype = jnp.float64 if jax.config.jax_enable_x64 \
+            else jnp.float32
+        ws = weights[order].astype(acc_dtype)
+        cw = jnp.cumsum(ws)
+        target = q * cw[-1]
+        idx = jnp.clip(jnp.searchsorted(cw, target, side="left"), 0,
+                       values.shape[0] - 1)
+        return vs[idx]

@@ -125,7 +125,12 @@ view, ``export_trace(path)`` writes Chrome-trace/Perfetto JSON, and
 ``explain(query)`` names the cache level that answered one request.
 Construct with ``tracing=False`` to drop every span (per-stage
 ``ServeStats`` timings read 0.0 then — counters keep working); answers
-are bitwise-identical either way.
+are bitwise-identical either way.  Counters beside the spans:
+``pad_rows_held`` / ``pad_rows_added`` (rows of the scanned tables and
+the padding their buckets add, once per executed program) and
+``compiles`` split by cause into ``compiles_new_program``,
+``compiles_new_bucket``, ``compiles_invalidated`` and
+``compiles_evicted``.
 """
 
 from __future__ import annotations
@@ -146,6 +151,7 @@ from repro.core.executor import (
 )
 from repro.core.plan import MaterializeJoinOp, PhysicalPlan, segment_plan
 from repro.core.rewrite import plan_query
+from repro.core.scopes import scope_table
 from repro.core.sql import parse_sql
 from repro.core.stats import FUSION_COST_DISPARITY, StatsCatalog
 from repro.service.fingerprint import CanonicalQuery, canonicalize
@@ -233,6 +239,17 @@ class QueryResult:
         return self.error is None
 
 
+@dataclasses.dataclass(frozen=True)
+class _Program:
+    """A cached executable: the jitted callable, its XLA module name (what
+    the profiler trace calls its runs) and, under ``profile_annotations``,
+    its ``{HLO instruction: scope path}`` table."""
+
+    fn: Callable
+    name: str
+    scopes: dict[str, str] | None = None
+
+
 @dataclasses.dataclass
 class _Request:
     canon: CanonicalQuery | None
@@ -268,6 +285,19 @@ class _Unit:
 
 
 class QueryService:
+    """The serving front door (module docstring).
+
+    Taking a profiler trace: construct with ``profile_annotations=True``
+    and wrap the window in ``jax.profiler.trace(log_dir)``.  Each stage
+    span then also appears on the profiler's clock as ``service.<stage>``;
+    programs run as ``jit_q_<fingerprint[:12]>`` (one query) and
+    ``jit_fused_<signature[:12]>`` (a fused group), and each ``run`` span
+    notes its ``program`` and a ``scopes`` table that reads every device
+    operation of the capture back to its operator and kernel scope
+    (``repro.service.observability``, ``repro.core.scopes``).  With the
+    flag off, no HLO text is read.
+    """
+
     def __init__(self, db: dict[str, Table], schema: Schema, *,
                  mode: str = "auto", use_fkpk: bool = False,
                  freq_dtype=jnp.int32, backend: str = "xla",
@@ -306,12 +336,19 @@ class QueryService:
         # gauges, per-stage histograms, and per-request span trees.
         # tracing=False keeps counters/gauges but makes every span a no-op
         # (no clock reads on the hot path — the overhead baseline).
-        self.obs = Observability(clock, enabled=tracing)
+        self.obs = Observability(clock, enabled=tracing,
+                                 profile_annotations=profile_annotations)
         # root-span handoff from the async batcher to submit_many (see
         # there) — thread-local, so concurrent sync callers never see it
         self._trace_handoff = threading.local()
         self.obs.register_counters([
             "requests", "batches", "dedup_saved", "compiles",
+            # "compiles" by cause (PlanCache.compile_cause); they sum to it
+            "compiles_new_program", "compiles_new_bucket",
+            "compiles_invalidated", "compiles_evicted",
+            # rows of the tables each executed program scans, and the
+            # padding their shape buckets add (host integers)
+            "pad_rows_held", "pad_rows_added",
             "eager_requests",
             "plan_builds",            # plan_query pipeline actually ran
                                       # (0 in a fully warm-started process)
@@ -346,8 +383,7 @@ class QueryService:
                 else tuple(mesh.axis_names)
             self._jit_executor = DistributedExecutor(
                 schema, mesh, data_axes=axes, freq_dtype=freq_dtype,
-                presort=mesh_presort, dense_domain=dense_domain,
-                profile_annotations=profile_annotations)
+                presort=mesh_presort, dense_domain=dense_domain)
             # the shape-relevant mesh identity, folded into every
             # executable-cache key and the persistent store fingerprint:
             # a ring program compiled for one mesh shape must never answer
@@ -360,8 +396,7 @@ class QueryService:
         else:
             self._jit_executor = Executor(
                 self._db, schema, freq_dtype, backend,
-                dense_domain=dense_domain,
-                profile_annotations=profile_annotations)
+                dense_domain=dense_domain)
             self._topo = ()
             self._row_sharding = None
         store = None
@@ -552,12 +587,16 @@ class QueryService:
         immutable, so the snapshot stays consistent after release — which
         is what lets the padding itself (``Table.pad_to``, device work)
         run OUTSIDE the lock, serialised per (relation, capacity) by
-        in-flight events exactly like compiles."""
+        in-flight events exactly like compiles.  Counts the rows held and
+        the padding added, from capacities alone (no device reads)."""
         with self._lock:
             base = {rel: self._db[rel] for rel in rels}
             bucket: ShapeBucket = tuple(
                 (rel, self._bucket_cap(base[rel].capacity))
                 for rel in rels)
+        held = sum(base[rel].capacity for rel in rels)
+        self.obs.inc("pad_rows_held", held)
+        self.obs.inc("pad_rows_added", sum(cap for _, cap in bucket) - held)
         sub_db = {rel: self._padded_view(rel, base[rel], cap)
                   for rel, cap in bucket}
         return bucket, sub_db
@@ -1184,11 +1223,17 @@ class QueryService:
                 self._inflight.pop(fk, None)
             ev.set()
 
-    def _invoke(self, fn: Callable, sub_db: dict[str, Table], run_span):
-        """Execute one ready program to completion.  On a mesh, the
-        execution is additionally wrapped in a ``ring_sweep`` child span of
-        the request's ``run`` span — the collective sweep is the mesh
-        path's distinguishing cost and deserves its own timing row."""
+    def _invoke(self, prog: _Program, sub_db: dict[str, Table], run_span):
+        """Execute one ready program to completion, noting on the ``run``
+        span which program ran (and its scope table, when built).  On a
+        mesh, the execution is additionally wrapped in a ``ring_sweep``
+        child span of the request's ``run`` span — the collective sweep is
+        the mesh path's distinguishing cost and deserves its own timing
+        row."""
+        run_span.note(program=prog.name)
+        if prog.scopes is not None:
+            run_span.note(scopes=prog.scopes)
+        fn = prog.fn
         if self._mesh is not None:
             axes, counts = self._topo
             with self.obs.span(run_span, "ring_sweep",
@@ -1223,10 +1268,10 @@ class QueryService:
         roots = [r.trace for r in u.group]
         with self.obs.span(roots, "pad"):
             bucket, sub_db = self._snapshot(u.plan.scanned_rels())
-        fn, exec_hit, compile_s = self._executable(u.canon, u.plan, bucket,
-                                                   sub_db, roots)
+        prog, exec_hit, compile_s = self._executable(u.canon, u.plan, bucket,
+                                                     sub_db, roots)
         with self.obs.span(roots, "run") as rsp:
-            results = self._invoke(fn, sub_db, rsp)
+            results = self._invoke(prog, sub_db, rsp)
         self._finish_unit(u, results, exec_hit=exec_hit, bucket=bucket,
                           compile_s=compile_s, run_s=rsp.duration_s,
                           exec_source="exec_cache" if exec_hit
@@ -1252,24 +1297,26 @@ class QueryService:
             # the signature _admit_fusion computes for the same member set
             u.served_sig = signature
         compile_s = 0.0
+        key = PlanCache.fused_key(signature, bucket, self._topo)
 
         def build():
             nonlocal compile_s
+            with self._lock:
+                cause = PlanCache.compile_cause(self.cache.fused, key)
             with self.obs.span(roots, "compile", cold=True, fused=True,
-                               members=len(units)) as sp:
-                fn = self._jit_executor.compile_multi(plans)
+                               members=len(units), cause=cause) as sp:
+                name = f"fused_{signature[:12]}"
+                fn = self._jit_executor.compile_multi(plans, name=name)
                 jax.block_until_ready(fn(sub_db))
+                prog = self._program(fn, name, sub_db, sp)
             compile_s = sp.duration_s
-            self.obs.inc("compiles")
+            self._count_compile(cause, compile_s)
             self.obs.inc("fused_compiles")
-            self.obs.inc("compile_s_total", compile_s)
-            return fn
+            return prog
 
-        fn, exec_hit = self._get_or_build(
-            self.cache.fused,
-            PlanCache.fused_key(signature, bucket, self._topo), build)
+        prog, exec_hit = self._get_or_build(self.cache.fused, key, build)
         with self.obs.span(roots, "run", fused=True) as rsp:
-            outs = self._invoke(fn, sub_db, rsp)
+            outs = self._invoke(prog, sub_db, rsp)
 
         self.obs.inc("fused_batches")
         self.obs.inc("fused_queries", len(units))
@@ -1288,28 +1335,48 @@ class QueryService:
     def _executable(self, canon: CanonicalQuery, plan: PhysicalPlan,
                     bucket: ShapeBucket, sub_db: dict[str, Table],
                     parents=(),
-                    ) -> tuple[Callable, bool, float]:
+                    ) -> tuple[_Program, bool, float]:
         compile_s = 0.0
+        key = PlanCache.exec_key(canon.fingerprint, bucket, self._topo)
 
         def build():
             nonlocal compile_s
+            with self._lock:
+                cause = PlanCache.compile_cause(self.cache.execs, key)
             with self.obs.span(parents, "compile", cold=True, fused=False,
-                               fingerprint=canon.fingerprint) as sp:
-                fn = self._jit_executor.compile(plan)
+                               fingerprint=canon.fingerprint,
+                               cause=cause) as sp:
+                name = f"q_{canon.fingerprint[:12]}"
+                fn = self._jit_executor.compile(plan, name=name)
                 # trace + compile now, against the snapshot's bucket
                 # shapes, so the cache entry is a ready-to-run program and
                 # `run_s` is pure execution
                 jax.block_until_ready(fn(sub_db))
+                prog = self._program(fn, name, sub_db, sp)
             compile_s = sp.duration_s
-            self.obs.inc("compiles")
-            self.obs.inc("compile_s_total", compile_s)
-            return fn
+            self._count_compile(cause, compile_s)
+            return prog
 
-        fn, hit = self._get_or_build(
-            self.cache.execs,
-            PlanCache.exec_key(canon.fingerprint, bucket, self._topo),
-            build)
-        return fn, hit, compile_s
+        prog, hit = self._get_or_build(self.cache.execs, key, build)
+        return prog, hit, compile_s
+
+    def _program(self, fn: Callable, name: str, sub_db: dict[str, Table],
+                 compile_span) -> _Program:
+        """A freshly compiled (and once run) program, named; under
+        ``profile_annotations`` with its scope table, read from the
+        optimised HLO of the executable the run just used (the lowering
+        and the compile come from JAX's caches, so nothing compiles
+        twice)."""
+        scopes = None
+        if self.obs.profile_annotations:
+            with self.obs.span(compile_span, "scope_table"):
+                scopes = scope_table(fn.lower(sub_db).compile().as_text())
+        return _Program(fn, f"jit_{name}", scopes)
+
+    def _count_compile(self, cause: str, compile_s: float) -> None:
+        self.obs.inc("compiles")
+        self.obs.inc(f"compiles_{cause}")
+        self.obs.inc("compile_s_total", compile_s)
 
     def _serve_eager(self, u: _Unit) -> None:
         """Fallback for non-jittable (materialising) plans: serve eagerly

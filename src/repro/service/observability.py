@@ -32,6 +32,27 @@ Design constraints, in order:
   Counters and gauges keep working either way (cache-hit accounting is
   correctness bookkeeping, not observability sugar).
 
+* **On the profiler's clock, on request.**  ``profile_annotations=True``
+  mirrors every child span (each stage: ``parse``, ``fingerprint``,
+  ``plan``, ``pad``, ``compile`` and its ``scope_table``, ``run``,
+  ``ring_sweep``, ``batch_form``, ``queue_wait``) into a JAX profiler
+  capture as a ``jax.profiler.TraceAnnotation`` named
+  ``service.<stage>``.  A span opened on one thread and closed on another
+  (``queue_wait``: enqueued by the caller, claimed by the batcher) is
+  recorded on the closing thread with its true start.  With tracing
+  disabled there are no spans to mirror; with the mirror off, a span
+  costs one attribute check more at open and one at close.
+
+Taking a trace: construct ``QueryService(..., profile_annotations=True)``
+and wrap the window of interest in ``jax.profiler.trace(log_dir)``.  The
+capture then holds the host stages as ``service.*`` events, every device
+operation under its program's name (``jit_q_<fingerprint>`` for one
+query, ``jit_fused_<signature>`` for a fused group), and each execution's
+``run`` span notes ``program`` (that module name) and ``scopes``
+(``{HLO instruction: operator/kernel scope path}``,
+``repro.core.scopes``), which names the operator and kernel behind each
+device operation.
+
 Export surfaces:
 
 * ``snapshot()``          — ``{"counters", "gauges", "histograms"}``
@@ -52,6 +73,8 @@ import os
 import threading
 import time
 from typing import Any, Callable, Iterable
+
+import jax
 
 # The one sanctioned monotonic time source for the serving tier
 # (scripts/lint.py forbids raw time.perf_counter elsewhere in
@@ -145,7 +168,8 @@ class TraceSpan:
     (the export dedups by object identity, so it renders once).
     """
 
-    __slots__ = ("name", "t0", "t1", "tid", "args", "children")
+    __slots__ = ("name", "t0", "t1", "tid", "args", "children",
+                 "annotation")
 
     def __init__(self, name: str, t0: float, tid: int,
                  args: dict | None = None):
@@ -155,6 +179,8 @@ class TraceSpan:
         self.tid = tid
         self.args = args if args is not None else {}
         self.children: list[TraceSpan] = []
+        # its profiler mirror, while open (profile_annotations only)
+        self.annotation = None
 
     @property
     def closed(self) -> bool:
@@ -242,9 +268,11 @@ class Observability:
     behind one lock.  See the module docstring for the contract."""
 
     def __init__(self, clock: Callable[[], float] | None = None, *,
-                 enabled: bool = True, max_traces: int = 512):
+                 enabled: bool = True, max_traces: int = 512,
+                 profile_annotations: bool = False):
         self.clock = clock if clock is not None else MONOTONIC
         self.enabled = enabled
+        self.profile_annotations = profile_annotations
         self._lock = threading.Lock()
         self._counters: dict[str, int | float] = {}
         self._gauges: dict[str, int | float] = {}
@@ -355,6 +383,9 @@ class Observability:
         if not self.enabled:
             return NULL_SPAN
         span = TraceSpan(name, self.clock(), threading.get_ident(), args)
+        if self.profile_annotations:
+            span.annotation = jax.profiler.TraceAnnotation(f"service.{name}")
+            span.annotation.__enter__()
         if parents is None:
             parents = ()
         elif isinstance(parents, (TraceSpan, _NullSpan)):
@@ -373,6 +404,9 @@ class Observability:
             return 0.0
         if not span.closed:
             span.t1 = self.clock()
+            if span.annotation is not None:
+                span.annotation.__exit__(None, None, None)
+                span.annotation = None
         dur = span.duration_s
         with self._lock:
             self._observe_locked(span.name, dur)

@@ -31,7 +31,9 @@ bookkeeping, its failures degrade to memory-only caching, and its
 ``persist_*`` counters ride along in ``metrics()``.
 
 All levels are bounded LRU with hit/miss/eviction counters; ``metrics()``
-flattens them into the dict the serving engine exposes.
+flattens them into the dict the serving engine exposes.  Each level also
+remembers why recent entries left (``LRUCache.dropped``), so the engine
+can count each compile by its cause (``compile_cause``).
 """
 
 from __future__ import annotations
@@ -55,6 +57,14 @@ class LRUCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # key → why it left ("evicted" | "invalidated"): the newest
+        # 4 × capacity of them, each forgotten once its key is put again
+        self.dropped: dict[Hashable, str] = {}
+
+    def _drop(self, key, why: str) -> None:
+        self.dropped[key] = why
+        if len(self.dropped) > 4 * self.capacity:
+            del self.dropped[next(iter(self.dropped))]
 
     def __len__(self) -> int:
         return len(self._d)
@@ -86,9 +96,11 @@ class LRUCache:
         if key in self._d:
             self._d.move_to_end(key)
         self._d[key] = value
+        self.dropped.pop(key, None)
         if len(self._d) > self.capacity:
-            self._d.popitem(last=False)
+            old, _ = self._d.popitem(last=False)
             self.evictions += 1
+            self._drop(old, "evicted")
 
     def get_or_create(self, key, factory: Callable[[], Any]):
         """Return (value, hit) — counting exactly one hit or miss."""
@@ -110,6 +122,7 @@ class LRUCache:
         doomed = [k for k in self._d if pred(k)]
         for k in doomed:
             del self._d[k]
+            self._drop(k, "invalidated")
         return len(doomed)
 
     def invalidate_items(self,
@@ -120,6 +133,7 @@ class LRUCache:
         doomed = [k for k, v in self._d.items() if pred(k, v)]
         for k in doomed:
             del self._d[k]
+            self._drop(k, "invalidated")
         return len(doomed)
 
     def counters(self) -> dict[str, int]:
@@ -188,6 +202,22 @@ class PlanCache:
     def fused_key(signature: str, bucket: ShapeBucket,
                   topo: tuple = ()) -> tuple:
         return (signature, topo, bucket)
+
+    @staticmethod
+    def compile_cause(cache: LRUCache, key: tuple) -> str:
+        """Why building ``key`` of an executable level compiles:
+        ``evicted`` or ``invalidated`` when that entry was dropped,
+        ``new_bucket`` when its program (fingerprint or signature, and
+        topology) is or was cached at another bucket, else
+        ``new_program``."""
+        why = cache.dropped.get(key)
+        if why is not None:
+            return why
+        program = key[:2]
+        known = [k for k, _ in cache.items()] + list(cache.dropped)
+        if any(k[:2] == program for k in known):
+            return "new_bucket"
+        return "new_program"
 
     def get_executable(self, fingerprint: str, bucket: ShapeBucket,
                        factory: Callable[[], Callable],

@@ -334,10 +334,10 @@ def test_metrics_and_updates_not_blocked_by_compile():
     release = threading.Event()
     real_compile = svc._jit_executor.compile
 
-    def slow_compile(plan):
+    def slow_compile(plan, **kw):
         compiling.set()
         assert release.wait(30), "test orchestration stalled"
-        return real_compile(plan)
+        return real_compile(plan, **kw)
 
     svc._jit_executor.compile = slow_compile
     out: list = []
