@@ -1366,11 +1366,12 @@ class QueryService:
         ``profile_annotations`` with its scope table, read from the
         optimised HLO of the executable the run just used (the lowering
         and the compile come from JAX's caches, so nothing compiles
-        twice)."""
+        twice).  A backend that shows no HLO text gives no table."""
         scopes = None
         if self.obs.profile_annotations:
             with self.obs.span(compile_span, "scope_table"):
-                scopes = scope_table(fn.lower(sub_db).compile().as_text())
+                text = fn.lower(sub_db).compile().as_text()
+                scopes = scope_table(text) if text is not None else None
         return _Program(fn, f"jit_{name}", scopes)
 
     def _count_compile(self, cause: str, compile_s: float) -> None:
