@@ -44,6 +44,7 @@ identically-padded tables (see ``tables.table.sharded_bucket_capacity``).
 
 from __future__ import annotations
 
+import collections
 from typing import Sequence
 
 import jax
@@ -186,26 +187,32 @@ class _RingExecutor(Executor):
     mesh axes.  Instantiated by ``DistributedExecutor._inner_executor``
     inside its shard_map program — every other node type (scans, the
     content-key memo, selection masking) is inherited unchanged, which is
-    the whole point: one interpreter, two lowerings."""
+    the whole point: one interpreter, two lowerings.
+
+    The all-reduce variant runs where ``dense_domain`` is set and the keys
+    declare a domain; every other join is a ring.  The tally
+    (``Executor.joins``) counts the all-reduce as "dense" and the ring,
+    which sorts and searches, as "sorted"."""
 
     def __init__(self, db: dict[str, Table], schema: Schema, freq_dtype,
                  ring_axes: Sequence[str], presort: bool,
                  dense_domain: bool):
-        super().__init__(db, schema, freq_dtype,
-                         dense_domain=dense_domain)
+        super().__init__(db, schema, freq_dtype)
         self.ring_axes = tuple(ring_axes)
         self.presort = presort
+        self.dense_domain = dense_domain
 
     def _key(self, plan, alias, st, on_vars):
         key, dom = super()._key(plan, alias, st, on_vars)
-        if dom is not None and dom >= (1 << 31):
+        if not self.dense_domain or (dom is not None and dom >= (1 << 31)):
             # the all-reduce variant scatter-adds into a domain-sized
-            # accumulator per shard — cap it at int32 indexing range and
-            # fall back to the ring
+            # accumulator per shard — only where asked, and capped at
+            # int32 indexing range; otherwise the ring
             dom = None
         return key, dom
 
     def _ring(self, pk, pf, ck, cf, cdom, mode: str):
+        self.joins["sorted" if cdom is None else "dense"] += 1
         if cdom is not None:
             return allreduce_freq_join(pk, pf, ck, cf,
                                        ring_axes=self.ring_axes,
@@ -253,10 +260,12 @@ class DistributedExecutor(Executor):
                  data_axes: Sequence[str] = ("data",),
                  freq_dtype=jnp.int32, presort: bool = False,
                  dense_domain: bool = False):
-        super().__init__({}, schema, freq_dtype, dense_domain=dense_domain)
+        super().__init__({}, schema, freq_dtype)
         self.mesh = mesh
         self.data_axes = tuple(data_axes)
         self.presort = presort
+        # all-reduce joins on declared key domains (``_RingExecutor``)
+        self.dense_domain = dense_domain
 
     def jittable(self) -> "DistributedExecutor":
         return self          # never carries eager-only options
@@ -322,19 +331,24 @@ class DistributedExecutor(Executor):
                 need.add(ag.var)
         return need
 
-    def _ring_program(self, plans: list[PhysicalPlan]):
+    def _ring_program(self, plans: list[PhysicalPlan],
+                      joins: collections.Counter | None):
         """db → [result dict per plan]: one shard_map sweep evaluating
         every member to its root state (shared trace memo, exactly like
-        the local ``compile_multi``), then replicated final aggregation."""
+        the local ``compile_multi``), then replicated final aggregation.
+        ``joins`` as in ``Executor.compile``."""
         spec = jax.sharding.PartitionSpec(self.data_axes)
         rep = self.replicated_sharding()
 
         def sweep(db: dict[str, Table]):
+            if joins is not None:
+                joins.clear()
             memo: dict = {}
             outs = []
             for plan in plans:
                 st = self._trace_plan(db, plan, memo,
-                                      root=self._agg_state_node(plan))
+                                      root=self._agg_state_node(plan),
+                                      joins=joins)
                 need = self._agg_cols(plan)
                 outs.append(({v: c for v, c in st.cols.items()
                               if v in need}, st.freq))
@@ -363,16 +377,18 @@ class DistributedExecutor(Executor):
 
         return run
 
-    def compile(self, plan: PhysicalPlan, name: str = "run"):
+    def compile(self, plan: PhysicalPlan, name: str = "run",
+                joins: collections.Counter | None = None):
         """Jit one plan's ring program: sharded db → aggregates."""
         self._check_jittable([plan])
-        ring = self._ring_program([plan])
+        ring = self._ring_program([plan], joins)
         return jax.jit(named(lambda db: ring(db)[0], name))
 
-    def compile_multi(self, plans: list[PhysicalPlan], name: str = "run"):
+    def compile_multi(self, plans: list[PhysicalPlan], name: str = "run",
+                      joins: collections.Counter | None = None):
         """Jit several plans into ONE mesh program (shared ring sweeps):
         sharded db → [aggregates], results in plan order."""
         if not plans:
             raise ValueError("compile_multi needs at least one plan")
         self._check_jittable(plans)
-        return jax.jit(named(self._ring_program(list(plans)), name))
+        return jax.jit(named(self._ring_program(list(plans), joins), name))

@@ -27,10 +27,19 @@ shapes differ — is computed exactly once in the fused XLA program.
 
 An ``oom_guard`` bounds materialisation for the baselines: exceeding it
 raises ``MaterialisationLimit`` (reported as the paper's X entries).
+
+Each semi/freq join passes the kernel its keys' declared domain (the
+product of the key columns' ``ColumnMeta.domain``, None when one is
+undeclared); the kernel chooses the dense or the sorted path from that
+domain and the child's length (``kops.join_path``).  Every executor
+tallies the paths its join calls took in ``joins``; ``compile`` and
+``compile_multi`` hand a program's tally, counted while it is traced, to
+the caller.  A memoised sub-DAG is traced, and counted, once.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Callable
 
@@ -77,15 +86,14 @@ class Executor:
     def __init__(self, db: dict[str, Table], schema: Schema,
                  freq_dtype=jnp.int32, backend: str = "xla",
                  oom_guard: int | None = None,
-                 dense_domain: bool = False,
                  tuning=None):
         self.db = db
         self.schema = schema
         self.freq_dtype = freq_dtype
         self.backend = backend
         self.oom_guard = oom_guard
-        # beyond-paper: sort-free scatter-add FreqJoin on dense key domains
-        self.dense_domain = dense_domain
+        # join kernel calls by path ("dense", "sorted", "pallas")
+        self.joins: collections.Counter = collections.Counter()
         # tuned kernel configs (repro.kernels.autotune.TuneTable, or None
         # for untuned defaults): looked up at trace time by the concrete
         # kernel input sizes — already bucket-padded on the serving path,
@@ -97,9 +105,7 @@ class Executor:
         ``compile()`` accepts.  Use when one benchmark harness drives both
         guarded eager baselines and jitted plans."""
         return Executor(self.db, self.schema, self.freq_dtype, self.backend,
-                        oom_guard=None,
-                        dense_domain=self.dense_domain,
-                        tuning=self.tuning)
+                        oom_guard=None, tuning=self.tuning)
 
     # ------------------------------------------------------------------
     def _domains(self, plan: PhysicalPlan, alias: str) -> dict[str, int | None]:
@@ -120,14 +126,15 @@ class Executor:
 
     def _key(self, plan: PhysicalPlan, alias: str, st: _State,
              on_vars: tuple[str, ...]):
-        """Packed join key + (optional) dense key-domain size."""
+        """Packed join key + its declared domain (the product of the key
+        columns' domains; None when one is undeclared)."""
         if not on_vars:
             return jnp.zeros(st.freq.shape, jnp.int32), 1
         doms = self._domains(plan, alias)
         dlist = [doms.get(v) for v in on_vars]
         key = pack_keys([st.cols[v] for v in on_vars], dlist)
         domain = None
-        if self.dense_domain and all(d is not None for d in dlist):
+        if all(d is not None for d in dlist):
             domain = 1
             for d in dlist:
                 domain *= d
@@ -142,32 +149,39 @@ class Executor:
             return None
         return self.tuning.lookup(kernel, sizes, self.backend)
 
+    def _join_path(self, kernel: str, n_parent: int, n_child: int,
+                   domain: int | None):
+        """(tuned config, path) of one join kernel call, the path tallied."""
+        cfg = self._tune_cfg(kernel, n_parent, n_child)
+        path = kops.join_path(domain, n_child, backend=self.backend,
+                              config=cfg)
+        self.joins[path] += 1
+        return cfg, path
+
     def _semi_join(self, plan: PhysicalPlan, op: SemiJoinOp,
                    p: _State, c: _State) -> _State:
         pk, _pd = self._key(plan, op.parent, p, op.on_vars)
         ck, cdom = self._key(plan, op.child, c, op.on_vars)
-        freq = kops.semi_join(pk, p.freq, ck, c.freq,
-                              backend=self.backend,
-                              domain=cdom,
-                              config=self._tune_cfg(
-                                  "semi_join", pk.shape[0], ck.shape[0]))
+        cfg, _path = self._join_path("semi_join", pk.shape[0], ck.shape[0],
+                                     cdom)
+        freq = kops.semi_join(pk, p.freq, ck, c.freq, backend=self.backend,
+                              domain=cdom, config=cfg)
         return _State(p.cols, freq)
 
     def _freq_join(self, plan: PhysicalPlan, op: FreqJoinOp,
                    p: _State, c: _State) -> _State:
         pk, _pd = self._key(plan, op.parent, p, op.on_vars)
         ck, cdom = self._key(plan, op.child, c, op.on_vars)
+        cfg, path = self._join_path("freq_join", pk.shape[0], ck.shape[0],
+                                    cdom)
         cf = c.freq
-        if op.pregroup and cdom is None:
+        if op.pregroup and path != "dense":
             with jax.named_scope("pregroup"):
                 ck, cf, _valid = kops.group_by_sum(
                     ck, cf, backend=self.backend,
                     config=self._tune_cfg("segment_sum", ck.shape[0]))
-        freq = kops.freq_join(pk, p.freq, ck, cf,
-                              backend=self.backend,
-                              domain=cdom,
-                              config=self._tune_cfg(
-                                  "freq_join", pk.shape[0], ck.shape[0]))
+        freq = kops.freq_join(pk, p.freq, ck, cf, backend=self.backend,
+                              domain=cdom, config=cfg)
         return _State(p.cols, freq)
 
     # ------------------------------------------------------------------
@@ -319,12 +333,12 @@ class Executor:
         itself — content-key memoisation, sub-DAG dedup, multi-plan fusion
         — is shared and lives only in ``_trace_plan``."""
         return Executor(db, self.schema, self.freq_dtype, self.backend,
-                        dense_domain=self.dense_domain,
                         tuning=self.tuning)
 
     def _trace_plan(self, db: dict[str, Table], plan: PhysicalPlan,
                     memo: dict | None = None,
-                    root: PlanNode | None = None) -> Any:
+                    root: PlanNode | None = None,
+                    joins: collections.Counter | None = None) -> Any:
         """One plan's DAG evaluation, for use under tracing.
 
         ``memo`` maps node content keys (``PlanNode.key``) to the frequency
@@ -343,7 +357,10 @@ class Executor:
         ``root`` selects where evaluation stops (default: the whole plan,
         ``plan.root``).  The mesh path evaluates to ``plan.root.inputs[0]``
         — the pre-aggregate root state — inside its shard_map program and
-        aggregates outside, so the same traversal serves both lowerings."""
+        aggregates outside, so the same traversal serves both lowerings.
+
+        ``joins``, when given, gains the paths of the join kernel calls
+        traced (``Executor.joins``)."""
         inner = self._inner_executor(db)
         vals: dict[int, _State] = {}
 
@@ -384,22 +401,30 @@ class Executor:
             vals[id(node)] = st
             return st
 
-        return ev(plan.root if root is None else root)
+        out = ev(plan.root if root is None else root)
+        if joins is not None:
+            joins.update(inner.joins)
+        return out
 
-    def compile(self, plan: PhysicalPlan, name: str = "run"):
+    def compile(self, plan: PhysicalPlan, name: str = "run",
+                joins: collections.Counter | None = None):
         """Jit the static plan classes (oma / opt_plus): db → aggregates.
         ``name`` names the program: its XLA module is ``jit_<name>``, the
-        name a profiler trace gives its runs."""
+        name a profiler trace gives its runs.  ``joins``, when given, holds
+        the paths of the program's join kernel calls once it is traced."""
         self._check_jittable([plan])
 
         def run(db: dict[str, Table]):
+            if joins is not None:
+                joins.clear()
             # a fresh memo still dedups repeated sub-DAGs *within* the plan
             # (self-joins scanning one relation twice, say)
-            return self._trace_plan(db, plan, memo={})
+            return self._trace_plan(db, plan, memo={}, joins=joins)
 
         return jax.jit(named(run, name))
 
-    def compile_multi(self, plans: list[PhysicalPlan], name: str = "run"):
+    def compile_multi(self, plans: list[PhysicalPlan], name: str = "run",
+                      joins: collections.Counter | None = None):
         """Jit several static plans into ONE program: db → [aggregates].
 
         The member plans' DAG evaluations share a trace-level memo keyed by
@@ -408,14 +433,17 @@ class Executor:
         chain under different join shapes — is computed once and its
         frequency vector fanned out to every consumer.  One XLA compilation
         serves every member query; results are returned in plan order.
-        ``name`` names the program, as in ``compile``."""
+        ``name`` and ``joins`` as in ``compile``."""
         if not plans:
             raise ValueError("compile_multi needs at least one plan")
         self._check_jittable(plans)
 
         def run(db: dict[str, Table]):
+            if joins is not None:
+                joins.clear()
             memo: dict = {}
-            return [self._trace_plan(db, plan, memo) for plan in plans]
+            return [self._trace_plan(db, plan, memo, joins=joins)
+                    for plan in plans]
 
         return jax.jit(named(run, name))
 

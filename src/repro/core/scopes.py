@@ -4,11 +4,12 @@ The executor evaluates every plan node under a ``jax.named_scope`` of its
 operator (``scan``, ``semi_join``, ``freq_join`` with ``pregroup`` inside
 it, ``final_agg``), and ``kernels/ops.py`` opens one per kernel
 (``freq_join``, ``semi_join``, ``segment_sum``, ``group_by_sum``,
-``weighted_percentile``, and ``sort`` and ``search`` inside the sort-based
-freq-join).  JAX writes the scopes into each HLO instruction's ``op_name``
-metadata between transform names (``jit(...)``, ``vmap()``), control-flow
-markers (``while``, ``body``) and, last, the primitive's own name; XLA
-keeps the metadata through optimisation.
+``weighted_percentile``, ``sort`` and ``search`` inside the sort-based
+freq-join, ``scatter`` and ``gather`` inside the dense one).  JAX writes
+the scopes into each HLO instruction's ``op_name`` metadata between
+transform names (``jit(...)``, ``vmap()``), control-flow markers
+(``while``, ``body``) and, last, the primitive's own name; XLA keeps the
+metadata through optimisation.
 
 ``scope_table`` turns the optimised HLO text of a compiled program
 (``Compiled.as_text()``, whose instruction names are the ones a device
@@ -29,6 +30,7 @@ import re
 SCOPES = frozenset({
     "scan", "semi_join", "freq_join", "pregroup", "final_agg",
     "segment_sum", "group_by_sum", "weighted_percentile", "sort", "search",
+    "scatter", "gather",
 })
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([^\s(]+) \(")

@@ -29,8 +29,9 @@ indirection.
 
 Each public entry point opens a ``jax.named_scope`` of its own name
 (``freq_join``, ``semi_join``, ``segment_sum``, ``group_by_sum``,
-``weighted_percentile``), and the sort-based freq-join names its child
-sort (``sort``) and its two binary searches (``search``).  Scopes are HLO
+``weighted_percentile``); the sort-based freq-join names its child
+sort (``sort``) and its two binary searches (``search``), the dense one
+its scatter-add (``scatter``) and its gather (``gather``).  Scopes are HLO
 ``op_name`` metadata only: they change neither fusion nor the code that
 runs, and they let a profiler trace name the kernel behind every device
 operation (``repro.core.scopes``).
@@ -75,6 +76,20 @@ def _pad1(a: jax.Array, n: int, fill) -> jax.Array:
 # --------------------------------------------------------------------------
 # FreqJoin
 # --------------------------------------------------------------------------
+def join_path(domain: int | None, n_child: int, *, backend: str | None = None,
+              config: KernelConfig | None = None) -> str:
+    """The path ``freq_join``/``semi_join`` take for a child of ``n_child``
+    rows whose packed keys lie in ``[0, domain)`` (None: unknown):
+    ``"dense"`` (one scatter-add, one gather), ``"sorted"`` (argsort and
+    two binary searches) or ``"pallas"``.  The dispatch itself asks this,
+    so a caller that tallies paths counts what runs."""
+    if (backend or default_backend()) != "xla":
+        return "pallas"
+    if (config or DEFAULT_CONFIG).dense_ok(domain, n_child):
+        return "dense"
+    return "sorted"
+
+
 def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
               mode: str = "sum", backend: str | None = None,
               domain: int | None = None,
@@ -84,13 +99,14 @@ def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
     mode="sum": ℕ-semiring (COUNT/SUM propagation);
     mode="any": Boolean semiring (semi-join).
 
-    `domain` (beyond-paper, EXPERIMENTS §Perf): when the packed join-key
-    domain is known and dense, the sort+searchsorted pipeline collapses to
-    one scatter-add into a domain-sized accumulator plus one gather —
-    O(N) instead of O(N log N), and on TPU the exact memory pattern of an
-    embedding-gradient update (well-optimised).  Falls back to sorting when
-    the domain is unknown or too sparse to justify the accumulator; the
-    crossover comes from ``config`` (``dense_ratio``/``dense_floor``).
+    `domain` (beyond-paper, EXPERIMENTS §Perf): the packed join keys'
+    declared domain, None when unknown.  Where it is dense enough for the
+    child (``join_path``), the sort+searchsorted pipeline collapses to one
+    scatter-add into a domain-sized accumulator plus one gather — O(N)
+    instead of O(N log N), and on TPU the exact memory pattern of an
+    embedding-gradient update.  Keys outside the domain contribute
+    nothing.  Otherwise it sorts; the crossover comes from ``config``
+    (``dense_ratio``/``dense_floor``).
     """
     with jax.named_scope("freq_join"):
         return _freq_join_call(parent_keys, parent_freq, child_keys,
@@ -113,7 +129,7 @@ def _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq, *,
                     domain: int | None, config: KernelConfig):
     if backend == "xla":
         nc = child_keys.shape[0]
-        if config.dense_ok(domain, nc):
+        if join_path(domain, nc, backend=backend, config=config) == "dense":
             cf = child_freq
             if mode == "any":
                 cf = (cf > 0).astype(parent_freq.dtype)
@@ -122,17 +138,19 @@ def _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq, *,
             # negative ones (wrapping them onto valid slots), which would
             # corrupt acc[domain-1] whenever dead/out-of-range child keys
             # are negative — mask to zero contribution instead
-            live = (child_keys >= 0) & (child_keys < domain)
-            acc = jnp.zeros((domain,), cf.dtype)
-            acc = acc.at[jnp.clip(child_keys, 0, domain - 1)].add(
-                jnp.where(live, cf, 0))
-            mult = acc[jnp.clip(parent_keys, 0, domain - 1)]
-            mult = jnp.where(
-                (parent_keys >= 0) & (parent_keys < domain), mult, 0)
-            mult = mult.astype(parent_freq.dtype)
-            if mode == "any":
-                mult = (mult > 0).astype(parent_freq.dtype)
-            return parent_freq * mult
+            with jax.named_scope("scatter"):
+                live = (child_keys >= 0) & (child_keys < domain)
+                acc = jnp.zeros((domain,), cf.dtype)
+                acc = acc.at[jnp.clip(child_keys, 0, domain - 1)].add(
+                    jnp.where(live, cf, 0))
+            with jax.named_scope("gather"):
+                mult = acc[jnp.clip(parent_keys, 0, domain - 1)]
+                mult = jnp.where(
+                    (parent_keys >= 0) & (parent_keys < domain), mult, 0)
+                mult = mult.astype(parent_freq.dtype)
+                if mode == "any":
+                    mult = (mult > 0).astype(parent_freq.dtype)
+                return parent_freq * mult
         with jax.named_scope("sort"):
             order = jnp.argsort(child_keys)
             ck = child_keys[order]

@@ -130,11 +130,14 @@ are bitwise-identical either way.  Counters beside the spans:
 the padding their buckets add, once per executed program) and
 ``compiles`` split by cause into ``compiles_new_program``,
 ``compiles_new_bucket``, ``compiles_invalidated`` and
-``compiles_evicted``.
+``compiles_evicted``; ``joins_dense``, ``joins_sorted`` and
+``joins_pallas``, the paths of each executed program's join kernel calls
+(``Executor.joins``, tallied once when the program is traced).
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import threading
@@ -242,11 +245,13 @@ class QueryResult:
 @dataclasses.dataclass(frozen=True)
 class _Program:
     """A cached executable: the jitted callable, its XLA module name (what
-    the profiler trace calls its runs) and, under ``profile_annotations``,
-    its ``{HLO instruction: scope path}`` table."""
+    the profiler trace calls its runs), its join kernel calls by path and,
+    under ``profile_annotations``, its ``{HLO instruction: scope path}``
+    table."""
 
     fn: Callable
     name: str
+    joins: dict[str, int]
     scopes: dict[str, str] | None = None
 
 
@@ -301,7 +306,6 @@ class QueryService:
     def __init__(self, db: dict[str, Table], schema: Schema, *,
                  mode: str = "auto", use_fkpk: bool = False,
                  freq_dtype=jnp.int32, backend: str = "xla",
-                 dense_domain: bool = False,
                  plan_capacity: int = 256, exec_capacity: int = 512,
                  fused_capacity: int = 128, padded_capacity: int = 64,
                  min_bucket: int = 8, async_max_batch: int = 64,
@@ -314,6 +318,7 @@ class QueryService:
                  mesh: "jax.sharding.Mesh | None" = None,
                  data_axes: tuple[str, ...] | None = None,
                  mesh_presort: bool = False,
+                 mesh_dense_domain: bool = False,
                  fusion_disparity: float | None = None,
                  tenants: "dict[str, Any] | None" = None):
         self._db = dict(db)
@@ -349,6 +354,8 @@ class QueryService:
             # rows of the tables each executed program scans, and the
             # padding their shape buckets add (host integers)
             "pad_rows_held", "pad_rows_added",
+            # join kernel calls of the executed programs, by path
+            "joins_dense", "joins_sorted", "joins_pallas",
             "eager_requests",
             "plan_builds",            # plan_query pipeline actually ran
                                       # (0 in a fully warm-started process)
@@ -383,7 +390,7 @@ class QueryService:
                 else tuple(mesh.axis_names)
             self._jit_executor = DistributedExecutor(
                 schema, mesh, data_axes=axes, freq_dtype=freq_dtype,
-                presort=mesh_presort, dense_domain=dense_domain)
+                presort=mesh_presort, dense_domain=mesh_dense_domain)
             # the shape-relevant mesh identity, folded into every
             # executable-cache key and the persistent store fingerprint:
             # a ring program compiled for one mesh shape must never answer
@@ -394,9 +401,8 @@ class QueryService:
             for a, n in zip(*self._topo):
                 self.obs.set_gauge(f"mesh_shard_count_{a}", n)
         else:
-            self._jit_executor = Executor(
-                self._db, schema, freq_dtype, backend,
-                dense_domain=dense_domain)
+            self._jit_executor = Executor(self._db, schema, freq_dtype,
+                                          backend)
             self._topo = ()
             self._row_sharding = None
         store = None
@@ -1231,6 +1237,7 @@ class QueryService:
         the mesh path's distinguishing cost and deserves its own timing
         row."""
         run_span.note(program=prog.name)
+        self._count_joins(prog.joins)
         if prog.scopes is not None:
             run_span.note(scopes=prog.scopes)
         fn = prog.fn
@@ -1306,9 +1313,11 @@ class QueryService:
             with self.obs.span(roots, "compile", cold=True, fused=True,
                                members=len(units), cause=cause) as sp:
                 name = f"fused_{signature[:12]}"
-                fn = self._jit_executor.compile_multi(plans, name=name)
+                joins = collections.Counter()
+                fn = self._jit_executor.compile_multi(plans, name=name,
+                                                      joins=joins)
                 jax.block_until_ready(fn(sub_db))
-                prog = self._program(fn, name, sub_db, sp)
+                prog = self._program(fn, name, joins, sub_db, sp)
             compile_s = sp.duration_s
             self._count_compile(cause, compile_s)
             self.obs.inc("fused_compiles")
@@ -1347,12 +1356,13 @@ class QueryService:
                                fingerprint=canon.fingerprint,
                                cause=cause) as sp:
                 name = f"q_{canon.fingerprint[:12]}"
-                fn = self._jit_executor.compile(plan, name=name)
+                joins = collections.Counter()
+                fn = self._jit_executor.compile(plan, name=name, joins=joins)
                 # trace + compile now, against the snapshot's bucket
                 # shapes, so the cache entry is a ready-to-run program and
                 # `run_s` is pure execution
                 jax.block_until_ready(fn(sub_db))
-                prog = self._program(fn, name, sub_db, sp)
+                prog = self._program(fn, name, joins, sub_db, sp)
             compile_s = sp.duration_s
             self._count_compile(cause, compile_s)
             return prog
@@ -1360,9 +1370,10 @@ class QueryService:
         prog, hit = self._get_or_build(self.cache.execs, key, build)
         return prog, hit, compile_s
 
-    def _program(self, fn: Callable, name: str, sub_db: dict[str, Table],
-                 compile_span) -> _Program:
-        """A freshly compiled (and once run) program, named; under
+    def _program(self, fn: Callable, name: str, joins: dict[str, int],
+                 sub_db: dict[str, Table], compile_span) -> _Program:
+        """A freshly compiled (and once run) program, named, with the
+        paths its trace tallied for its join kernel calls; under
         ``profile_annotations`` with its scope table, read from the
         optimised HLO of the executable the run just used (the lowering
         and the compile come from JAX's caches, so nothing compiles
@@ -1372,7 +1383,11 @@ class QueryService:
             with self.obs.span(compile_span, "scope_table"):
                 text = fn.lower(sub_db).compile().as_text()
                 scopes = scope_table(text) if text is not None else None
-        return _Program(fn, f"jit_{name}", scopes)
+        return _Program(fn, f"jit_{name}", dict(joins), scopes)
+
+    def _count_joins(self, joins: dict[str, int]) -> None:
+        for path, n in joins.items():
+            self.obs.inc(f"joins_{path}", n)
 
     def _count_compile(self, cause: str, compile_s: float) -> None:
         self.obs.inc("compiles")
@@ -1391,7 +1406,6 @@ class QueryService:
             # database state even if update_table swaps relations mid-run
             sub_db = {rel: self._db[rel] for rel in u.plan.scanned_rels()}
         ex = Executor(sub_db, self.schema, base.freq_dtype, base.backend,
-                      dense_domain=base.dense_domain,
                       tuning=base.tuning)
         stats = ExecStats()
         with self.obs.span(roots, "run", eager=True) as rsp:
@@ -1400,6 +1414,7 @@ class QueryService:
             # answer column: it travels via ServeStats.exec_stats only
             results.pop("__stats__", None)
             jax.block_until_ready(list(results.values()))
+        self._count_joins(ex.joins)
         self._finish_unit(u, results, exec_hit=False, bucket=(),
                           compile_s=0.0, run_s=rsp.duration_s,
                           exec_source="eager")

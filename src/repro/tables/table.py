@@ -77,6 +77,14 @@ class Schema:
                 return True
         return False
 
+    def without_domains(self) -> "Schema":
+        """This schema with no column declaring a domain: every join then
+        takes the sorted path, the control for dense-domain dispatch."""
+        return dataclasses.replace(self, relations={
+            name: dataclasses.replace(rel, columns=tuple(
+                dataclasses.replace(c, domain=None) for c in rel.columns))
+            for name, rel in self.relations.items()})
+
 
 @jax.tree_util.register_pytree_node_class
 class Table:

@@ -53,8 +53,7 @@ def check(db, schema, q, mode, mesh, data_axes, name, **dex_opts):
     sharded = dex.shard_db(db)
     # the single-device reference over the SAME padded capacities
     host = {k: db[k].pad_to(sharded[k].capacity) for k in db}
-    ex = Executor(db, schema,
-                  dense_domain=dex_opts.get("dense_domain", False))
+    ex = Executor(db, schema)
     plan = plan_query(q, schema, mode=mode)
 
     want = dict(ex.compile(plan)(host))

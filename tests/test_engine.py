@@ -2,6 +2,8 @@
 (ref == opt == opt_plus == brute force), the paper's running example, and
 materialisation accounting (the Fig. 6 invariant)."""
 
+import collections
+import dataclasses
 import itertools
 
 import jax
@@ -24,7 +26,9 @@ from repro.data import (
     path_query,
     tree_query,
 )
+from repro.core.plan import FreqJoinOp, SemiJoinOp
 from repro.data.relational import stats_count_query, tpch_v1_query
+from repro.kernels.autotune import KernelConfig
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -330,25 +334,77 @@ def test_compiled_plan_matches_eager():
 
 
 # ---------------------------------------------------------------------------
-# beyond-paper: dense-domain (sort-free) FreqJoin must be a pure perf knob
+# dense-domain dispatch: declared key domains choose the scatter-add path,
+# and its answers are the sorted path's (the same data, domains stripped)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("qmaker", [lambda: path_query(3),
                                     lambda: tree_query(2)])
 def test_dense_domain_freqjoin_equivalence(qmaker):
     db, schema = make_graph_db(n_nodes=14, n_edges=45, seed=21)
-    q = qmaker()
-    base = Executor(db, schema).execute(
-        plan_query(q, schema, mode="opt_plus"))["count(*)"]
-    fast = Executor(db, schema, dense_domain=True).execute(
-        plan_query(q, schema, mode="opt_plus"))["count(*)"]
-    assert int(base) == int(fast)
+    plan = plan_query(qmaker(), schema, mode="opt_plus")
+    dense = Executor(db, schema)
+    sort = Executor(db, schema.without_domains())
+    assert int(dense.execute(plan)["count(*)"]) == \
+        int(sort.execute(plan)["count(*)"])
+    assert dense.joins["dense"] > 0 and not dense.joins["sorted"]
+    assert sort.joins["sorted"] > 0 and not sort.joins["dense"]
 
 
 def test_dense_domain_semijoin_equivalence():
     db, schema = make_tpch_db(scale=40, seed=6)
-    q = tpch_v1_query("minmax")
-    r1 = Executor(db, schema).execute(plan_query(q, schema, mode="oma"))
-    r2 = Executor(db, schema, dense_domain=True).execute(
-        plan_query(q, schema, mode="oma"))
-    np.testing.assert_allclose(float(r1["min(bal)"]), float(r2["min(bal)"]))
-    np.testing.assert_allclose(float(r1["max(bal)"]), float(r2["max(bal)"]))
+    plan = plan_query(tpch_v1_query("minmax"), schema, mode="oma")
+    dense = Executor(db, schema)
+    sort = Executor(db, schema.without_domains())
+    r1, r2 = sort.execute(plan), dense.execute(plan)
+    assert float(r1["min(bal)"]) == float(r2["min(bal)"])
+    assert float(r1["max(bal)"]) == float(r2["max(bal)"])
+    assert dense.joins["dense"] > 0 and not dense.joins["sorted"]
+    assert sort.joins["sorted"] > 0 and not sort.joins["dense"]
+
+
+def _with_domain(schema, domain):
+    """`schema` with every declared domain replaced by `domain`."""
+    return dataclasses.replace(schema, relations={
+        name: dataclasses.replace(rel, columns=tuple(
+            c if c.domain is None else dataclasses.replace(c, domain=domain)
+            for c in rel.columns))
+        for name, rel in schema.relations.items()})
+
+
+@pytest.mark.parametrize("domain, path", [
+    (1 << 20, "dense"),         # at the crossover max(4·n_child, 2^20)
+    ((1 << 20) + 1, "sorted"),  # just above it: too sparse for the child
+    (None, "sorted"),           # undeclared
+])
+def test_declared_domain_chooses_the_join_path(domain, path):
+    db, schema = make_graph_db(n_nodes=14, n_edges=20, seed=21)
+    q = path_query(3)
+    plan = plan_query(q, schema, mode="opt_plus")
+    want = brute_force_count(db, schema, q)
+    ex = Executor(db, _with_domain(schema, domain))
+    assert int(ex.execute(plan)["count(*)"]) == want
+    assert set(ex.joins) == {path}
+    # a compiled program tallies the same paths, counted at trace time
+    joins = collections.Counter()
+    fn = ex.compile(plan, joins=joins)
+    assert int(fn(db)["count(*)"]) == want
+    assert set(joins) == {path} and sum(joins.values()) == len(
+        [op for op in plan.ops if isinstance(op, (FreqJoinOp, SemiJoinOp))])
+
+
+class _AnyDomain:
+    """A tuning whose crossover admits every domain."""
+
+    def lookup(self, kernel, sizes, backend):
+        return KernelConfig(dense_floor=1 << 40)
+
+
+@pytest.mark.parametrize("domain, path", [((1 << 31) - 1, "dense"),
+                                          (1 << 31, "sorted")])
+def test_dense_domain_cap_holds_whatever_the_tuning(domain, path):
+    db, schema = make_graph_db(n_nodes=14, n_edges=45, seed=21)
+    plan = plan_query(path_query(3), schema, mode="opt_plus")
+    ex = Executor(db, _with_domain(schema, domain), tuning=_AnyDomain())
+    joins = collections.Counter()
+    ex.compile(plan, joins=joins).lower(db)   # traced, never run
+    assert set(joins) == {path}

@@ -4,6 +4,7 @@ operator and kernel scopes read back from the optimised HLO
 padded-row and compile-cause counters, and the two benchmark readers that
 use them (``freq_join_device_ms``, ``padded_row_pct``)."""
 
+import collections
 import glob
 import os
 import pathlib
@@ -132,7 +133,8 @@ def test_scope_table_inherits_from_callers_and_from_what_is_fused():
 def test_scope_table_of_a_compiled_plan(tpch):
     db, schema = tpch
     plan = plan_query(parse_sql(FIG1, schema), schema, mode="opt_plus")
-    fn = Executor(db, schema).compile(plan, name="q_fig1")
+    # no declared domains: every join takes the sorted path
+    fn = Executor(db, schema.without_domains()).compile(plan, name="q_fig1")
     text = fn.lower(db).compile().as_text()
     assert text.startswith("HloModule jit_q_fig1,")
     table = scope_table(text)
@@ -144,6 +146,27 @@ def test_scope_table_of_a_compiled_plan(tpch):
     assert all(table.values())
     assert {p.split("/")[0] for p in table.values()} <= {
         "scan", "semi_join", "freq_join", "final_agg"}
+
+
+def test_scope_table_of_the_dense_path(tpch):
+    db, schema = tpch
+    plan = plan_query(parse_sql(FIG1, schema), schema, mode="opt_plus")
+    joins = collections.Counter()
+    fn = Executor(db, schema).compile(plan, name="q_fig1", joins=joins)
+    text = fn.lower(db).compile().as_text()
+    assert joins["dense"] and not joins["sorted"]
+    table = scope_table(text)
+    scatters = [m.group(1) for m in re.finditer(
+        r"^\s+(?:ROOT )?%(\S+) = \S+ scatter\(", text, re.M)]
+    assert scatters
+    for name in scatters:
+        assert table[name] == "freq_join/freq_join/scatter", (name,
+                                                              table[name])
+    paths = set(table.values())
+    assert {"freq_join/freq_join/scatter",
+            "freq_join/freq_join/gather"} <= paths
+    assert not any(p.endswith(("/sort", "/search")) or "pregroup" in p
+                   for p in paths)
 
 
 def test_every_named_scope_of_the_programs_is_known():
@@ -227,6 +250,22 @@ def test_pad_rows_count_bucket_padding_of_the_scanned_relations(tpch):
     m = svc.metrics()
     assert m["pad_rows_held"] == 2 * held
     assert m["pad_rows_added"] == 2 * added
+
+
+@pytest.mark.parametrize("strip, path", [(False, "joins_dense"),
+                                         (True, "joins_sorted")])
+def test_join_paths_counted_once_per_executed_program(tpch, strip, path):
+    db, schema = tpch
+    svc = QueryService(db, schema.without_domains() if strip else schema)
+    svc.submit_many([FIG1, COUNT])           # one fused program, compiled
+    first = svc.metrics()
+    svc.submit_many([FIG1, COUNT])           # the same program, cached
+    m = svc.metrics()
+    other = ({"joins_dense", "joins_sorted"} - {path}).pop()
+    assert first[path] > 0 and first[other] == 0
+    # a cached program adds its tally again, once per execution
+    assert m[path] == 2 * first[path] and m[other] == 0
+    assert m["joins_pallas"] == 0
 
 
 CAUSES = ("new_program", "new_bucket", "invalidated", "evicted")
