@@ -130,8 +130,8 @@ are bitwise-identical either way.  Counters beside the spans:
 the padding their buckets add, once per executed program) and
 ``compiles`` split by cause into ``compiles_new_program``,
 ``compiles_new_bucket``, ``compiles_invalidated`` and
-``compiles_evicted``; ``joins_dense``, ``joins_sorted`` and
-``joins_pallas``, the paths of each executed program's join kernel calls
+``compiles_evicted``; ``joins_dense`` and ``joins_sorted``, the paths
+of each executed program's XLA join kernel calls
 (``Executor.joins``, tallied once when the program is traced).
 """
 
@@ -323,6 +323,8 @@ class QueryService:
                  tenants: "dict[str, Any] | None" = None):
         self._db = dict(db)
         self.schema = schema
+        for name, table in self._db.items():
+            schema.check_domains(name, table)
         self.mode = mode
         self.use_fkpk = use_fkpk
         self.min_bucket = min_bucket
@@ -355,7 +357,7 @@ class QueryService:
             # padding their shape buckets add (host integers)
             "pad_rows_held", "pad_rows_added",
             # join kernel calls of the executed programs, by path
-            "joins_dense", "joins_sorted", "joins_pallas",
+            "joins_dense", "joins_sorted",
             "eager_requests",
             "plan_builds",            # plan_query pipeline actually ran
                                       # (0 in a fully warm-started process)
@@ -481,7 +483,10 @@ class QueryService:
     def update_table(self, name: str, table: Table) -> None:
         """Swap in new data for one relation.  Growth inside the relation's
         shape bucket keeps every compiled executable valid; crossing a
-        bucket boundary invalidates only the executables that scan it."""
+        bucket boundary invalidates only the executables that scan it.
+        Data whose live rows leave a declared domain is refused
+        (``Schema.check_domains``), as it is at construction: the joins
+        read the domains."""
         if name not in self.schema.relations:
             raise KeyError(f"unknown relation {name!r}")
         want = set(self.schema.relations[name].column_names())
@@ -489,6 +494,7 @@ class QueryService:
         if want != have:
             raise ValueError(f"table {name!r} columns {sorted(have)} != "
                              f"schema columns {sorted(want)}")
+        self.schema.check_domains(name, table)
         old = self._db.get(name)
         if old is not None:
             # shape buckets key on capacity only; a dtype change would turn
@@ -1386,8 +1392,8 @@ class QueryService:
         return _Program(fn, f"jit_{name}", dict(joins), scopes)
 
     def _count_joins(self, joins: dict[str, int]) -> None:
-        for path, n in joins.items():
-            self.obs.inc(f"joins_{path}", n)
+        for path in ("dense", "sorted"):
+            self.obs.inc(f"joins_{path}", joins.get(path, 0))
 
     def _count_compile(self, cause: str, compile_s: float) -> None:
         self.obs.inc("compiles")

@@ -77,6 +77,34 @@ class Schema:
                 return True
         return False
 
+    def check_domains(self, name: str, table: "Table") -> None:
+        """Raise ``ValueError`` if a live row of ``table``, the data of
+        relation ``name``, holds a value outside a column's declared domain.
+        Packed join keys and the dense freq-join both rely on every live
+        value lying in ``[0, domain)``."""
+        rel = self.relations.get(name)
+        if rel is None:
+            return
+        declared = [c for c in rel.columns
+                    if c.domain is not None and c.name in table.columns]
+        if not declared:
+            return
+        live = np.asarray(table.freq) > 0
+        every = bool(live.all())
+        bad = []
+        for c in declared:
+            vals = np.asarray(table.columns[c.name])
+            if not every:
+                vals = vals[live]
+            if vals.size and (vals.min() < 0 or vals.max() >= c.domain):
+                n = int(((vals < 0) | (vals >= c.domain)).sum())
+                bad.append(f"{c.name}: {n} live rows outside "
+                           f"[0, {c.domain}), seen [{vals.min()}, "
+                           f"{vals.max()}]")
+        if bad:
+            raise ValueError(f"table {name!r} breaks its declared domains: "
+                             + "; ".join(bad))
+
     def without_domains(self) -> "Schema":
         """This schema with no column declaring a domain: every join then
         takes the sorted path, the control for dense-domain dispatch."""

@@ -9,8 +9,9 @@ join shapes, random selections, aggregates, GROUP BY, data) the two must
 agree to the bit in every plan class (ref / opt / opt_plus / oma), eagerly
 and compiled, and fused multi-query execution must match per-plan
 compilation bitwise.  The reference reads the schema with its domains
-stripped, so its joins take the sorted path while the executor's, on the
-declared domains, take the dense one.
+stripped, so its joins take the sorted path; the executor runs both on
+the declared domains, whose joins take the dense one, and on the stripped
+schema, whose joins sort.
 
 Runs as a hypothesis property test when hypothesis is installed, else as a
 seeded sweep over the same case builder (visible, not silent, degradation).
@@ -183,6 +184,9 @@ def _check_case(seed: int):
     query = _make_query(rng)
     ref = LinearReference(db, SCHEMA.without_domains())
     new = Executor(db, SCHEMA)
+    # the graph IR on both join paths: declared domains go dense, the
+    # stripped schema sorts as the reference does
+    sort = Executor(db, SCHEMA.without_domains())
 
     jit_plans, jit_results = [], []
     for mode in ("ref", "opt", "opt_plus", "oma"):
@@ -191,18 +195,23 @@ def _check_case(seed: int):
         except ValueError:
             continue  # mode not applicable (not 0MA, say) — by design
         want = ref.execute(plan)
-        got = new.execute(plan)
-        _assert_bitwise(want, got, ctx=f"eager/{mode}")
-        assert (want["__stats__"].peak_tuples
-                == got["__stats__"].peak_tuples), mode
         if mode in ("opt_plus", "oma"):
             want_c = dict(ref.compile(plan)(db))
-            got_c = dict(new.compile(plan)(db))
-            _assert_bitwise(want_c, got_c, ctx=f"compiled/{mode}")
-            _assert_bitwise(want, got_c, ctx=f"eager-vs-compiled/{mode}")
-            jit_plans.append(plan)
-            jit_results.append(got_c)
-    assert not ref.ex.joins["dense"] and not new.joins["sorted"]
+        for ex, path in ((new, "dense"), (sort, "sorted")):
+            got = ex.execute(plan)
+            _assert_bitwise(want, got, ctx=f"eager/{mode}/{path}")
+            assert (want["__stats__"].peak_tuples
+                    == got["__stats__"].peak_tuples), (mode, path)
+            if mode in ("opt_plus", "oma"):
+                got_c = dict(ex.compile(plan)(db))
+                _assert_bitwise(want_c, got_c, ctx=f"compiled/{mode}/{path}")
+                _assert_bitwise(want, got_c,
+                                ctx=f"eager-vs-compiled/{mode}/{path}")
+                if ex is new:
+                    jit_plans.append(plan)
+                    jit_results.append(got_c)
+    assert not ref.ex.joins["dense"] and not sort.joins["dense"]
+    assert not new.joins["sorted"]
 
     # fused multi-query execution (shared trace memo across members,
     # including an extra sibling so sub-DAGs overlap partially) must match

@@ -5,6 +5,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -279,6 +280,35 @@ def test_service_same_bucket_growth_zero_recompiles():
     np.testing.assert_allclose(
         float(res2.values["min(s.s_acctbal)"]),
         float(res.values["min(s.s_acctbal)"]))
+
+
+@pytest.mark.parametrize("live", [True, False])
+def test_service_refuses_keys_outside_declared_domain(live):
+    """A declared domain is a contract the joins read: the dense freq-join
+    gives keys outside it no partner.  So data whose live rows break it is
+    refused, on update and at construction, and the service goes on
+    answering over the data it holds; a dead row may hold any value."""
+    db, schema = make_tpch_db(scale=50, seed=7)
+    svc = QueryService(db, schema)
+    count = "SELECT COUNT(*) FROM supplier s, partsupp ps " \
+            "WHERE s.s_suppkey = ps.ps_suppkey"
+    want = int(svc.submit(count).values["count(*)"])
+    dom = schema.relations["partsupp"].meta("ps_suppkey").domain
+    cols = {c: np.concatenate([np.asarray(v), np.asarray(v)[:1]])
+            for c, v in db["partsupp"].columns.items()}
+    cols["ps_suppkey"][-1] = dom          # one row past the domain
+    freq = np.ones(len(cols["ps_suppkey"]), np.int32)
+    freq[-1] = int(live)
+    grown = Table({c: jnp.asarray(v) for c, v in cols.items()},
+                  jnp.asarray(freq))
+    if live:
+        with pytest.raises(ValueError, match="ps_suppkey: 1 live rows"):
+            svc.update_table("partsupp", grown)
+        with pytest.raises(ValueError, match="declared domains"):
+            QueryService({**db, "partsupp": grown}, schema)
+    else:
+        svc.update_table("partsupp", grown)
+    assert int(svc.submit(count).values["count(*)"]) == want
 
 
 def test_service_eager_fallback_for_unguarded_plans():
