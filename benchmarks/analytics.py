@@ -73,9 +73,9 @@ def stats_query_family():
 
 def run(tpch_scale=5000, repeats=3):
     rows = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         db, schema = make_tpch_db(scale=tpch_scale, seed=0)
-        ex = Executor(db, schema, freq_dtype="int64", oom_guard=OOM_GUARD)
+        ex = Executor(db, schema, oom_guard=OOM_GUARD)
         for name, agg, fkpk in [
             ("TPC-H V.1 minmax (0MA)", "minmax", False),
             ("TPC-H V.1 median", "median", False),
@@ -89,7 +89,7 @@ def run(tpch_scale=5000, repeats=3):
 
         sdb, sschema = make_stats_db(n_users=20_000, n_posts=100_000,
                                      n_comments=400_000, n_votes=250_000)
-        sex = Executor(sdb, sschema, freq_dtype="int64",
+        sex = Executor(sdb, sschema,
                        oom_guard=OOM_GUARD)
         e2e_opt, e2e_ref = 0.0, 0.0
         ref_failed = False

@@ -33,9 +33,9 @@ def _time(fn, repeats=3):
 
 
 def run(n_nodes=20_000, n_edges=200_000, seed=0, repeats=3, queries=None):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         db, schema = make_graph_db(n_nodes, n_edges, seed=seed)
-        ex = Executor(db, schema, freq_dtype="float64",
+        ex = Executor(db, schema,
                       oom_guard=OOM_GUARD)
         if queries is None:
             queries = [(f"path-{k:02d}", path_query(k)) for k in (3, 4, 5)] \

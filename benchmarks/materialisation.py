@@ -26,9 +26,9 @@ def peak_tuples(ex, db, schema, q, mode):
 
 def run():
     rows = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         db, schema = make_graph_db(5_000, 60_000, seed=2)
-        ex = Executor(db, schema, freq_dtype="int64", oom_guard=OOM_GUARD)
+        ex = Executor(db, schema, oom_guard=OOM_GUARD)
         base_max = max(int(t.live_count()) for t in db.values())
         for k in (2, 3, 4):
             q = path_query(k)
@@ -39,7 +39,7 @@ def run():
 
         sdb, sschema = make_stats_db(n_users=5_000, n_posts=20_000,
                                      n_comments=100_000, n_votes=60_000)
-        sex = Executor(sdb, sschema, freq_dtype="int64",
+        sex = Executor(sdb, sschema,
                        oom_guard=OOM_GUARD)
         base_max = max(int(t.live_count()) for t in sdb.values())
         q = stats_count_query()

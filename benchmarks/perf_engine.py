@@ -41,7 +41,7 @@ def _time(fn, repeats=5):
 
 def bench_local():
     rows = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cases = []
         db, schema = make_tpch_db(scale=5000, seed=0)
         cases.append(("tpch-v1-median", db, schema, tpch_v1_query("median")))
@@ -58,7 +58,7 @@ def bench_local():
             # against the declared domains (dense joins)
             for label, sch in (("baseline", schema_.without_domains()),
                                ("dense_domain", schema_)):
-                ex = Executor(db_, sch, freq_dtype="float64")
+                ex = Executor(db_, sch)
                 fn = ex.compile(plan)
 
                 def run():
@@ -75,7 +75,7 @@ def bench_local():
             rows.append(row)
             # Ref comparison (eager numpy baseline)
             try:
-                ex = Executor(db_, schema_, freq_dtype="float64",
+                ex = Executor(db_, schema_,
                               oom_guard=20_000_000)
                 row["ref"] = _time(
                     lambda: ex.execute(plan_query(q, schema_, mode="ref")),

@@ -12,6 +12,8 @@ import argparse
 import jax
 
 from repro.core import Executor, plan_query
+from repro.core.plan import PlanningError
+from repro.core.stats import db_counts
 from repro.data import make_graph_db, path_query, tree_query
 
 
@@ -22,16 +24,22 @@ def main():
     ap.add_argument("--edges", type=int, default=60000)
     args = ap.parse_args()
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         db, schema = make_graph_db(args.nodes, args.edges, seed=0)
-        ex = Executor(db, schema, freq_dtype="float64")
+        ex = Executor(db, schema)
 
         for name, q in [("path-03", path_query(3)),
                         ("path-05", path_query(5)),
                         ("tree-02", tree_query(2))]:
             plan = plan_query(q, schema, mode="opt_plus")
-            res = ex.execute(plan)
-            print(f"{name}: {float(res['count(*)']):.6e} homomorphisms, "
+            try:
+                # exact counts in the width the statistics' bound needs;
+                # past 2^63 the query is refused, never wrapped
+                res = ex.execute(plan)
+            except PlanningError as e:
+                print(f"{name}: refused: {e}")
+                continue
+            print(f"{name}: {int(res['count(*)'])} homomorphisms, "
                   f"peak tuples {res['__stats__'].peak_tuples} "
                   f"(largest relation {args.edges})")
 
@@ -41,14 +49,18 @@ def main():
             mesh = jax.make_mesh(
                 (n,), ("data",),
                 axis_types=(jax.sharding.AxisType.Auto,))
-            dex = DistributedExecutor(schema, mesh, data_axes=("data",),
-                                      freq_dtype="float64")
+            dex = DistributedExecutor(schema, mesh, data_axes=("data",))
             sharded = dex.shard_db(db)
-            fn = dex.compile(plan_query(path_query(4), schema,
-                                        mode="opt_plus"))
+            plan = plan_query(path_query(1), schema, mode="opt_plus")
+            try:
+                # the ring sweeps hold 32-bit counts: a query whose bound
+                # passes 2^31 is refused, never wrapped
+                fn = dex.compile(plan, counts=db_counts([plan], db, schema))
+            except PlanningError as e:
+                print(f"[distributed x{n}] path-01: refused: {e}")
+                return
             out = fn(sharded)
-            print(f"[distributed x{n}] path-04: "
-                  f"{float(out['count(*)']):.6e}")
+            print(f"[distributed x{n}] path-01: {int(out['count(*)'])}")
 
 
 if __name__ == "__main__":

@@ -25,9 +25,13 @@ from repro.tables.table import pack_keys
 
 
 def _acc_dtype(dt):
+    """A sum's accumulator: floats keep their type; integers sum in the
+    width of the counts, which ``dt`` (the frequencies' dtype, or a
+    value's promoted with it) carries — int32, or int64 in a program with
+    64-bit counts."""
     if jnp.issubdtype(dt, jnp.floating):
         return dt
-    return jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
+    return jnp.promote_types(dt, jnp.int32)
 
 
 def _big(dt):

@@ -52,10 +52,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.executor import Executor, _State, named
+from repro.core.stats import Counts
 from repro.core.plan import (
     FreqJoinOp,
     PhysicalPlan,
     PlanNode,
+    PlanningError,
     SemiJoinOp,
 )
 from repro.tables.table import (
@@ -194,10 +196,10 @@ class _RingExecutor(Executor):
     (``Executor.joins``) counts the all-reduce as "dense" and the ring,
     which sorts and searches, as "sorted"."""
 
-    def __init__(self, db: dict[str, Table], schema: Schema, freq_dtype,
+    def __init__(self, db: dict[str, Table], schema: Schema,
                  ring_axes: Sequence[str], presort: bool,
                  dense_domain: bool):
-        super().__init__(db, schema, freq_dtype)
+        super().__init__(db, schema)
         self.ring_axes = tuple(ring_axes)
         self.presort = presort
         self.dense_domain = dense_domain
@@ -227,8 +229,8 @@ class _RingExecutor(Executor):
         return _State(p.cols, self._ring(pk, p.freq, ck, c.freq, cdom,
                                          "any"))
 
-    def _freq_join(self, plan, op: FreqJoinOp, p: _State,
-                   c: _State) -> _State:
+    def _freq_join(self, plan, op: FreqJoinOp, p: _State, c: _State,
+                   limbs=None) -> _State:
         # op.pregroup (pre-summing duplicate child keys) is a local-engine
         # micro-optimisation; the ring accumulates exact per-shard sums
         # anyway, so it is ignored — identical integers by the semiring law
@@ -258,9 +260,8 @@ class DistributedExecutor(Executor):
 
     def __init__(self, schema: Schema, mesh: jax.sharding.Mesh,
                  data_axes: Sequence[str] = ("data",),
-                 freq_dtype=jnp.int32, presort: bool = False,
-                 dense_domain: bool = False):
-        super().__init__({}, schema, freq_dtype)
+                 presort: bool = False, dense_domain: bool = False):
+        super().__init__({}, schema)
         self.mesh = mesh
         self.data_axes = tuple(data_axes)
         self.presort = presort
@@ -309,10 +310,26 @@ class DistributedExecutor(Executor):
                 for name, t in db.items()}
 
     # -- plan execution ----------------------------------------------------
-    def _inner_executor(self, db: dict[str, Table]) -> Executor:
-        return _RingExecutor(db, self.schema, self.freq_dtype,
-                             self.data_axes, self.presort,
+    def _inner_executor(self, db: dict[str, Table],
+                        count_bits: int) -> Executor:
+        return _RingExecutor(db, self.schema, self.data_axes, self.presort,
                              self.dense_domain)
+
+    def _counts(self, plans: list[PhysicalPlan],
+                counts: Counts | None) -> Counts:
+        """``counts``, which the caller must give (the mesh executor holds
+        no tables to bound them from: ``repro.core.stats.db_counts`` of
+        the unsharded ones does), and only at 32 bits."""
+        if counts is None:
+            raise ValueError(
+                "the mesh executor holds no tables to bound the counts "
+                "from: pass counts (repro.core.stats.db_counts)")
+        if counts.bits != 32:
+            raise PlanningError(
+                f"this query needs {counts.bits}-bit counts, and the mesh "
+                "executor's ring sweeps hold 32-bit counts; serve it on "
+                "one device")
+        return counts
 
     @staticmethod
     def _agg_state_node(plan: PhysicalPlan) -> PlanNode:
@@ -378,17 +395,26 @@ class DistributedExecutor(Executor):
         return run
 
     def compile(self, plan: PhysicalPlan, name: str = "run",
-                joins: collections.Counter | None = None):
-        """Jit one plan's ring program: sharded db → aggregates."""
+                joins: collections.Counter | None = None,
+                counts: Counts | None = None,
+                sizes: collections.Counter | None = None):
+        """Jit one plan's ring program: sharded db → aggregates.  Its
+        counts are 32-bit: ``counts`` is required, and wider ones are
+        refused.  The ring sweeps tally no ``sizes``."""
         self._check_jittable([plan])
+        self._counts([plan], counts)
         ring = self._ring_program([plan], joins)
         return jax.jit(named(lambda db: ring(db)[0], name))
 
     def compile_multi(self, plans: list[PhysicalPlan], name: str = "run",
-                      joins: collections.Counter | None = None):
+                      joins: collections.Counter | None = None,
+                      counts: Counts | None = None,
+                      sizes: collections.Counter | None = None):
         """Jit several plans into ONE mesh program (shared ring sweeps):
-        sharded db → [aggregates], results in plan order."""
+        sharded db → [aggregates], results in plan order; 32-bit counts
+        as in ``compile``."""
         if not plans:
             raise ValueError("compile_multi needs at least one plan")
         self._check_jittable(plans)
+        self._counts(plans, counts)
         return jax.jit(named(self._ring_program(list(plans), joins), name))

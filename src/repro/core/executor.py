@@ -28,18 +28,31 @@ shapes differ — is computed exactly once in the fused XLA program.
 An ``oom_guard`` bounds materialisation for the baselines: exceeding it
 raises ``MaterialisationLimit`` (reported as the paper's X entries).
 
+Counts are 32 or 64 bits wide (``counts`` of ``compile``,
+``compile_multi`` and ``execute``: a ``repro.core.stats.Counts`` the
+caller chooses from the statistics, ``count_plan``, or by default the
+narrowest the executor's own tables allow, ``db_counts``): frequencies
+and COUNT's sum take that width, and at 64 bits each dense freq-join
+scatters its child's frequencies in the 32-bit limbs ``counts.limbs``
+names for it (``kops.freq_join``, which refuses to run without them).  A 64-bit program is traced, compiled and run
+under JAX's x64 types, switched on for the calling thread alone
+(``count_scope``), so no 32-bit program changes.
+
 Each semi/freq join passes the kernel its keys' declared domain (the
 product of the key columns' ``ColumnMeta.domain``, None when one is
 undeclared); the kernel chooses the dense or the sorted path from that
 domain and the child's length (``kops.join_path``).  Every executor
-tallies the paths its join calls took in ``joins``; ``compile`` and
-``compile_multi`` hand a program's tally, counted while it is traced, to
-the caller.  A memoised sub-DAG is traced, and counted, once.
+tallies the paths its join calls took in ``joins``, and its freq-join
+calls' rows and dense accumulator slots by the counts' width in
+``join_sizes``; ``compile`` and ``compile_multi`` hand a program's
+tallies, counted while it is traced, to the caller.  A memoised sub-DAG
+is traced, and counted, once.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 from typing import Any, Callable
 
@@ -54,11 +67,43 @@ from repro.core.plan import (
     MaterializeJoinOp,
     PhysicalPlan,
     PlanNode,
+    PlanningError,
     ScanOp,
     SemiJoinOp,
 )
+from repro.core.stats import Counts, db_counts, join_id
 from repro.kernels import ops as kops
 from repro.tables.table import Schema, Table, pack_keys
+
+
+COUNT_DTYPES = {32: jnp.int32, 64: jnp.int64}
+
+
+def count_scope(count_bits: int):
+    """The context a program with ``count_bits``-wide counts is traced,
+    compiled and run in: JAX's x64 types for 64, on this thread only."""
+    if count_bits not in COUNT_DTYPES:
+        raise ValueError(f"count_bits {count_bits}: one of "
+                         f"{sorted(COUNT_DTYPES)}")
+    return jax.enable_x64(True) if count_bits == 64 \
+        else contextlib.nullcontext()
+
+
+class _Wide:
+    """A jitted program with 64-bit counts: every call and lowering runs
+    under ``count_scope(64)``, so its trace holds s64 counts while the
+    process's other programs stay 32-bit."""
+
+    def __init__(self, fn: Callable):
+        self._fn = fn
+
+    def __call__(self, *args):
+        with count_scope(64):
+            return self._fn(*args)
+
+    def lower(self, *args):
+        with count_scope(64):
+            return self._fn.lower(*args)
 
 
 class MaterialisationLimit(RuntimeError):
@@ -84,16 +129,19 @@ class _State:
 
 class Executor:
     def __init__(self, db: dict[str, Table], schema: Schema,
-                 freq_dtype=jnp.int32, backend: str = "xla",
-                 oom_guard: int | None = None,
+                 backend: str = "xla", oom_guard: int | None = None,
                  tuning=None):
         self.db = db
         self.schema = schema
-        self.freq_dtype = freq_dtype
         self.backend = backend
+        # the frequencies' dtype while tracing or executing (count_bits)
+        self.count_dtype = jnp.int32
         self.oom_guard = oom_guard
         # join kernel calls by path ("dense", "sorted", "pallas")
         self.joins: collections.Counter = collections.Counter()
+        # freq-join kernel calls' rows and dense slots, by the counts'
+        # width: "rows<w>" (child + parent), "parent_rows<w>", "slots<w>"
+        self.join_sizes: collections.Counter = collections.Counter()
         # tuned kernel configs (repro.kernels.autotune.TuneTable, or None
         # for untuned defaults): looked up at trace time by the concrete
         # kernel input sizes — already bucket-padded on the serving path,
@@ -104,7 +152,7 @@ class Executor:
         """Copy with eager-only options stripped — the configuration
         ``compile()`` accepts.  Use when one benchmark harness drives both
         guarded eager baselines and jitted plans."""
-        return Executor(self.db, self.schema, self.freq_dtype, self.backend,
+        return Executor(self.db, self.schema, self.backend,
                         oom_guard=None, tuning=self.tuning)
 
     # ------------------------------------------------------------------
@@ -122,7 +170,7 @@ class Executor:
         cols = {}
         for i, cname in enumerate(rel.column_names()):
             cols[atom.vars[i]] = tab.columns[cname]
-        return _State(cols, tab.freq.astype(self.freq_dtype))
+        return _State(cols, tab.freq.astype(self.count_dtype))
 
     def _key(self, plan: PhysicalPlan, alias: str, st: _State,
              on_vars: tuple[str, ...]):
@@ -169,11 +217,20 @@ class Executor:
         return _State(p.cols, freq)
 
     def _freq_join(self, plan: PhysicalPlan, op: FreqJoinOp,
-                   p: _State, c: _State) -> _State:
+                   p: _State, c: _State,
+                   limbs: tuple[int, int] | None = None) -> _State:
         pk, _pd = self._key(plan, op.parent, p, op.on_vars)
         ck, cdom = self._key(plan, op.child, c, op.on_vars)
         cfg, path = self._join_path("freq_join", pk.shape[0], ck.shape[0],
                                     cdom)
+        # bytes the call must move, by the counts' width: both key
+        # columns, the child's and the parent's frequencies, and the dense
+        # path's accumulator
+        bits = 8 * jnp.dtype(self.count_dtype).itemsize
+        self.join_sizes[f"rows{bits}"] += pk.shape[0] + ck.shape[0]
+        self.join_sizes[f"parent_rows{bits}"] += pk.shape[0]
+        if path == "dense":
+            self.join_sizes[f"slots{bits}"] += cdom
         cf = c.freq
         if op.pregroup and path != "dense":
             with jax.named_scope("pregroup"):
@@ -181,18 +238,44 @@ class Executor:
                     ck, cf, backend=self.backend,
                     config=self._tune_cfg("segment_sum", ck.shape[0]))
         freq = kops.freq_join(pk, p.freq, ck, cf, backend=self.backend,
-                              domain=cdom, config=cfg)
+                              domain=cdom, config=cfg, limbs=limbs)
         return _State(p.cols, freq)
 
     # ------------------------------------------------------------------
-    def execute(self, plan: PhysicalPlan, stats: ExecStats | None = None):
-        """Eager DAG interpretation (every plan class, per-step stats).
+    def _counts(self, plans: list[PhysicalPlan],
+                counts: Counts | None) -> Counts:
+        """``counts``, or where the caller gives none the narrowest its
+        own tables allow (``db_counts``); refused where the join kernels
+        cannot hold them."""
+        if counts is None:
+            counts = db_counts(plans, self.db, self.schema)
+        if counts.bits != 32 and self.backend != "xla":
+            raise PlanningError(
+                f"the {self.backend} join kernels hold 32-bit counts; this "
+                f"query needs {counts.bits}-bit counts")
+        return counts
+
+    def execute(self, plan: PhysicalPlan, stats: ExecStats | None = None,
+                counts: Counts | None = None):
+        """Eager DAG interpretation (every plan class, per-step stats),
+        with ``counts`` as in ``compile``.
 
         Intermediate states are dropped after their last consumer, so peak
         host memory tracks the largest live intermediate — matching the
         linear interpreter this replaced, whose per-alias state slots were
         overwritten in place (a ref-mode chain of materialising joins must
         not retain every expanded intermediate until the end)."""
+        counts = self._counts([plan], counts)
+        with count_scope(counts.bits):
+            dtype, self.count_dtype = \
+                self.count_dtype, COUNT_DTYPES[counts.bits]
+            try:
+                return self._execute(plan, stats, counts)
+            finally:
+                self.count_dtype = dtype
+
+    def _execute(self, plan: PhysicalPlan, stats: ExecStats | None,
+                 counts: Counts):
         stats = stats if stats is not None else ExecStats()
         consumers: dict[int, int] = {}
         for node in plan.nodes:
@@ -211,7 +294,8 @@ class Executor:
                 stats.record(f"semijoin({op.parent}⋉{op.child})",
                              int(jnp.sum(st.freq > 0)))
             elif isinstance(op, FreqJoinOp):
-                st = self._freq_join(plan, op, ins[0], ins[1])
+                st = self._freq_join(plan, op, ins[0], ins[1],
+                                     limbs=counts.limbs.get(join_id(node)))
                 stats.record(f"freqjoin({op.parent}⋉ᶠ{op.child})",
                              int(jnp.sum(st.freq > 0)))
             elif isinstance(op, MaterializeJoinOp):
@@ -325,20 +409,25 @@ class Executor:
                 "execute() for guarded baselines, or build the Executor "
                 "without oom_guard to compile.")
 
-    def _inner_executor(self, db: dict[str, Table]) -> "Executor":
+    def _inner_executor(self, db: dict[str, Table],
+                        count_bits: int) -> "Executor":
         """The node evaluator ``_trace_plan`` traces with — a fresh
-        executor bound to the traced-through database.  Subclasses swap in
-        alternative evaluators here (``DistributedExecutor`` returns one
-        whose semi/freq joins are ring sweeps over the mesh); the traversal
+        executor bound to the traced-through database, with
+        ``count_bits``-wide frequencies.  Subclasses swap in alternative
+        evaluators here (``DistributedExecutor`` returns one whose
+        semi/freq joins are ring sweeps over the mesh); the traversal
         itself — content-key memoisation, sub-DAG dedup, multi-plan fusion
         — is shared and lives only in ``_trace_plan``."""
-        return Executor(db, self.schema, self.freq_dtype, self.backend,
-                        tuning=self.tuning)
+        ex = Executor(db, self.schema, self.backend, tuning=self.tuning)
+        ex.count_dtype = COUNT_DTYPES[count_bits]
+        return ex
 
     def _trace_plan(self, db: dict[str, Table], plan: PhysicalPlan,
                     memo: dict | None = None,
                     root: PlanNode | None = None,
-                    joins: collections.Counter | None = None) -> Any:
+                    joins: collections.Counter | None = None,
+                    counts: Counts = Counts(),
+                    sizes: collections.Counter | None = None) -> Any:
         """One plan's DAG evaluation, for use under tracing.
 
         ``memo`` maps node content keys (``PlanNode.key``) to the frequency
@@ -360,8 +449,9 @@ class Executor:
         aggregates outside, so the same traversal serves both lowerings.
 
         ``joins``, when given, gains the paths of the join kernel calls
-        traced (``Executor.joins``)."""
-        inner = self._inner_executor(db)
+        traced (``Executor.joins``), and ``sizes`` their rows and slots
+        (``Executor.join_sizes``)."""
+        inner = self._inner_executor(db, counts.bits)
         vals: dict[int, _State] = {}
 
         def ev(node: PlanNode) -> Any:
@@ -389,7 +479,9 @@ class Executor:
                             st = inner._semi_join(plan, op, p, c)
                     else:
                         with jax.named_scope("freq_join"):
-                            st = inner._freq_join(plan, op, p, c)
+                            st = inner._freq_join(
+                                plan, op, p, c,
+                                limbs=counts.limbs.get(join_id(node)))
                     if key is not None:
                         memo[key] = st.freq
             elif isinstance(op, FinalAggOp):
@@ -404,27 +496,37 @@ class Executor:
         out = ev(plan.root if root is None else root)
         if joins is not None:
             joins.update(inner.joins)
+        if sizes is not None:
+            sizes.update(inner.join_sizes)
         return out
 
     def compile(self, plan: PhysicalPlan, name: str = "run",
-                joins: collections.Counter | None = None):
+                joins: collections.Counter | None = None,
+                counts: Counts | None = None,
+                sizes: collections.Counter | None = None):
         """Jit the static plan classes (oma / opt_plus): db → aggregates.
         ``name`` names the program: its XLA module is ``jit_<name>``, the
-        name a profiler trace gives its runs.  ``joins``, when given, holds
-        the paths of the program's join kernel calls once it is traced."""
+        name a profiler trace gives its runs.  ``joins`` and ``sizes``,
+        when given, hold the paths of the program's join kernel calls and
+        their rows and slots (``Executor.join_sizes``) once it is traced.
+        ``counts``: how it holds its counts, by default the narrowest the
+        executor's own tables allow (``repro.core.stats.db_counts``)."""
         self._check_jittable([plan])
+        counts = self._counts([plan], counts)
 
         def run(db: dict[str, Table]):
-            if joins is not None:
-                joins.clear()
+            _clear(joins, sizes)
             # a fresh memo still dedups repeated sub-DAGs *within* the plan
             # (self-joins scanning one relation twice, say)
-            return self._trace_plan(db, plan, memo={}, joins=joins)
+            return self._trace_plan(db, plan, memo={}, joins=joins,
+                                    counts=counts, sizes=sizes)
 
-        return jax.jit(named(run, name))
+        return _jit(run, name, counts.bits)
 
     def compile_multi(self, plans: list[PhysicalPlan], name: str = "run",
-                      joins: collections.Counter | None = None):
+                      joins: collections.Counter | None = None,
+                      counts: Counts | None = None,
+                      sizes: collections.Counter | None = None):
         """Jit several static plans into ONE program: db → [aggregates].
 
         The member plans' DAG evaluations share a trace-level memo keyed by
@@ -433,19 +535,33 @@ class Executor:
         chain under different join shapes — is computed once and its
         frequency vector fanned out to every consumer.  One XLA compilation
         serves every member query; results are returned in plan order.
-        ``name`` and ``joins`` as in ``compile``."""
+        ``name``, ``joins``, ``counts`` and ``sizes`` as in
+        ``compile``."""
         if not plans:
             raise ValueError("compile_multi needs at least one plan")
         self._check_jittable(plans)
+        counts = self._counts(plans, counts)
 
         def run(db: dict[str, Table]):
-            if joins is not None:
-                joins.clear()
+            _clear(joins, sizes)
             memo: dict = {}
-            return [self._trace_plan(db, plan, memo, joins=joins)
+            return [self._trace_plan(db, plan, memo, joins=joins,
+                                     counts=counts, sizes=sizes)
                     for plan in plans]
 
-        return jax.jit(named(run, name))
+        return _jit(run, name, counts.bits)
+
+
+def _clear(*tallies) -> None:
+    """Empty the caller's tallies before a trace fills them."""
+    for t in tallies:
+        if t is not None:
+            t.clear()
+
+
+def _jit(run: Callable, name: str, count_bits: int) -> Callable:
+    fn = jax.jit(named(run, name))
+    return _Wide(fn) if count_bits == 64 else fn
 
 
 def named(fn: Callable, name: str) -> Callable:

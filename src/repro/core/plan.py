@@ -40,6 +40,14 @@ from repro.core.hypergraph import JoinTree
 from repro.core.query import Agg, Atom, selection_from_spec
 
 
+class PlanningError(ValueError):
+    """A query the planner cannot lower (cyclic, a forced mode whose
+    preconditions the query fails, or counts no exact width holds).
+    Subclasses ``ValueError`` so existing callers' handlers keep working;
+    the serving tier catches it per request so one unplannable query never
+    aborts its batch-mates."""
+
+
 # ---------------------------------------------------------------------------
 # Op payloads (the per-node physical operator descriptions)
 # ---------------------------------------------------------------------------

@@ -65,6 +65,7 @@ from repro.core.plan import (
     MaterializeJoinOp,
     PhysicalPlan,
     PlanNode,
+    PlanningError,
     ScanOp,
     SemiJoinOp,
     make_final_agg_node,
@@ -80,13 +81,6 @@ from repro.core.stats import (
     PREFILTER_MIN_PARENT_ROWS,
 )
 from repro.tables.table import Schema
-
-
-class PlanningError(ValueError):
-    """A query the planner cannot lower (cyclic, or a forced mode whose
-    preconditions the query fails).  Subclasses ``ValueError`` so existing
-    callers' handlers keep working; the serving tier catches it per
-    request so one unplannable query never aborts its batch-mates."""
 
 
 def _var_cols(query: AggQuery, schema: Schema) -> dict[str, dict[str, str]]:

@@ -6,17 +6,23 @@ enforces that threshold constants live here and nowhere else).  The shape
 mirrors the decision cards in SNIPPETS.md: each rewrite/fusion decision is
 a structural gate followed by a *calibration* against numbers kept here.
 
-Three layers:
+Four layers:
 
   * ``TableStats`` / ``ColumnStats`` — cheap per-relation summaries (live
-    row counts, distinct estimates, min/max, FK orphan counts) computed
-    once per table load/update from the numpy columns.  Each carries the
+    row counts, the largest base frequency, distinct counts, min/max, the
+    largest multiplicity of one value, FK orphan counts) computed once per
+    table load/update from the numpy columns.  Each carries the
     table's content ``token`` so a consumer can tell exactly which data
     version a decision was calibrated against.
   * ``StatsCatalog`` — the live registry the planner reads: selectivity
     estimation for declarative selection specs, a padded-shape cost model
     for fusion admission, and decision-dependency validation (a recorded
     decision is stale iff a table it consulted changed token).
+  * ``count_bound`` / ``count_bits`` / ``count_plan`` — a sound upper
+    bound on every frequency a plan's sweep produces and on its COUNT,
+    from those summaries, the width of the counts it needs (32 bits, 64
+    bits, or a ``PlanningError`` that names the bound), and at 64 bits the
+    32-bit limbs each dense join scatters (``Counts``, ``limb_split``).
   * serve-time feedback — EWMA solo vs. fused serve times per
     (fingerprint, fusion-group signature); a fusion that consistently
     regresses a member vs. its solo baseline is *demoted* and the grouper
@@ -30,6 +36,7 @@ join.
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 
 import numpy as np
@@ -39,12 +46,13 @@ from repro.core.plan import (
     FreqJoinOp,
     MaterializeJoinOp,
     PhysicalPlan,
+    PlanningError,
     ScanOp,
     SemiJoinOp,
 )
 from repro.tables.table import Schema, Table
 
-STATS_VERSION = 1
+STATS_VERSION = 2
 
 # ---------------------------------------------------------------------------
 # Policy constants (the ONLY allowed home for these — see scripts/lint.py).
@@ -85,14 +93,18 @@ class ColumnStats:
     distinct: int
     lo: float | None = None
     hi: float | None = None
+    #: the most live rows that hold one value
+    max_mult: int = 0
 
     def to_payload(self) -> dict:
-        return {"distinct": self.distinct, "lo": self.lo, "hi": self.hi}
+        return {"distinct": self.distinct, "lo": self.lo, "hi": self.hi,
+                "max_mult": self.max_mult}
 
     @classmethod
     def from_payload(cls, p: dict) -> "ColumnStats":
         return cls(distinct=int(p["distinct"]),
-                   lo=p.get("lo"), hi=p.get("hi"))
+                   lo=p.get("lo"), hi=p.get("hi"),
+                   max_mult=int(p["max_mult"]))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +113,7 @@ class TableStats:
 
     relation: str
     rows: int                  # live tuples (freq > 0)
+    max_freq: int              # the largest base frequency of a row
     capacity: int              # padded physical capacity
     token: str                 # Table.content_token() of the data version
     columns: dict[str, ColumnStats]
@@ -115,6 +128,7 @@ class TableStats:
             "version": STATS_VERSION,
             "relation": self.relation,
             "rows": self.rows,
+            "max_freq": self.max_freq,
             "capacity": self.capacity,
             "token": self.token,
             "columns": {c: s.to_payload() for c, s in self.columns.items()},
@@ -128,7 +142,8 @@ class TableStats:
                              f"{STATS_VERSION}")
         return cls(
             relation=p["relation"], rows=int(p["rows"]),
-            capacity=int(p["capacity"]), token=p["token"],
+            max_freq=int(p["max_freq"]), capacity=int(p["capacity"]),
+            token=p["token"],
             columns={c: ColumnStats.from_payload(s)
                      for c, s in p["columns"].items()},
             fk_orphans={k: int(v) for k, v in p["fk_orphans"].items()},
@@ -151,11 +166,12 @@ def compute_table_stats(name: str, table: Table, schema: Schema,
         if vals.size == 0:
             columns[col] = ColumnStats(distinct=0)
             continue
-        distinct = int(np.unique(vals).size)
+        _, mult = np.unique(vals, return_counts=True)
         lo = hi = None
         if np.issubdtype(vals.dtype, np.number):
             lo, hi = float(vals.min()), float(vals.max())
-        columns[col] = ColumnStats(distinct=distinct, lo=lo, hi=hi)
+        columns[col] = ColumnStats(distinct=int(mult.size), lo=lo, hi=hi,
+                                   max_mult=int(mult.max()))
 
     fk_orphans: dict[str, int] = {}
     for fk in schema.foreign_keys:
@@ -168,9 +184,168 @@ def compute_table_stats(name: str, table: Table, schema: Schema,
         orphans = int((~np.isin(src_vals, dst_vals)).sum())
         fk_orphans[f"{fk.src_col}->{fk.dst}.{fk.dst_col}"] = orphans
 
-    return TableStats(relation=name, rows=rows, capacity=table.capacity,
+    return TableStats(relation=name, rows=rows,
+                      max_freq=int(freq.max(initial=0)),
+                      capacity=table.capacity,
                       token=table.content_token(), columns=columns,
                       fk_orphans=fk_orphans)
+
+
+# ---------------------------------------------------------------------------
+# The width of the counts
+# ---------------------------------------------------------------------------
+
+#: the largest count each width holds exactly, narrowest first
+COUNT_LIMITS = ((32, 2 ** 31 - 1), (64, 2 ** 63 - 1))
+
+
+@dataclasses.dataclass(frozen=True)
+class Counts:
+    """How a program holds its counts: ``bits`` wide (32 or 64) and, at 64
+    bits, for each freq-join (``join_id``) the split of the child's
+    frequencies into 32-bit limbs (``limb_split``) that its dense
+    scatter-add needs.  Those two are a compiled program's identity.  The
+    rest records what they were chosen from: ``bound``, the largest count
+    of the plans; ``joins``, each freq-join's most child rows on one key
+    and child frequency bound; ``tokens``, the data version of each
+    relation whose statistics were read."""
+
+    bits: int = 32
+    limbs: dict = dataclasses.field(default_factory=dict)
+    bound: int = dataclasses.field(default=0, compare=False)
+    joins: dict = dataclasses.field(default_factory=dict, compare=False)
+    tokens: dict = dataclasses.field(default_factory=dict, compare=False)
+
+    def current(self, tables: dict[str, "TableStats"]) -> bool:
+        """True iff ``tables`` describe the data these counts were chosen
+        from."""
+        return all((st := tables.get(rel)) is not None and st.token == tok
+                   for rel, tok in self.tokens.items())
+
+
+def join_id(node) -> object:
+    """The key of a freq-join in ``Counts.limbs``: its content key, which
+    a fused program's shared sub-DAGs share, or, below a materialising
+    join (no content key; eager plans only), the node itself."""
+    key = node.key()
+    return key if key is not None else id(node)
+
+
+def limb_split(mult: int, freq: int) -> tuple[int, int]:
+    """(bits a limb, limbs) to split child frequencies up to ``freq`` into
+    so that each limb's sum over at most ``mult`` rows stays below 2^32:
+    a dense scatter-add of 64-bit counts in 32-bit words, exact."""
+    bits = 32 - max(int(mult), 1).bit_length()
+    return bits, max(1, -(-max(int(freq), 1).bit_length() // bits))
+
+
+def count_plan(plan: PhysicalPlan,
+               tables: dict[str, "TableStats"]) -> Counts:
+    """The ``Counts`` of ``plan`` over the data ``tables`` describe: the
+    narrowest width that holds its counts and, at 64 bits, each join's
+    limbs.  A ``PlanningError`` where no width holds them."""
+    joins: dict = {}
+    bound = count_bound(plan, tables, joins)
+    return _counts(bound, joins,
+                   {rel: tables[rel].token for rel in plan.scanned_rels()})
+
+
+def widest(counts: list[Counts]) -> Counts:
+    """The ``Counts`` of one program that runs plans counted apart: the
+    width of the widest, with limbs for every member's joins."""
+    if len(counts) == 1:
+        return counts[0]
+    return _counts(max(c.bound for c in counts),
+                   {k: v for c in counts for k, v in c.joins.items()},
+                   {k: v for c in counts for k, v in c.tokens.items()})
+
+
+def db_counts(plans: list[PhysicalPlan], db: dict[str, Table],
+              schema: Schema) -> Counts:
+    """The ``Counts`` of one program running ``plans`` over ``db``, from
+    statistics computed on the spot: for callers that hold their tables
+    without a catalog."""
+    rels = sorted({rel for p in plans for rel in p.scanned_rels()})
+    tables = {rel: compute_table_stats(rel, db[rel], schema, db)
+              for rel in rels}
+    return widest([count_plan(p, tables) for p in plans])
+
+
+def _counts(bound: int, joins: dict, tokens: dict) -> Counts:
+    bits = count_bits(bound)
+    limbs = {k: limb_split(m, f) for k, (m, f) in joins.items()} \
+        if bits == 64 else {}
+    return Counts(bits, limbs, bound, joins, tokens)
+
+
+def count_bound(plan: PhysicalPlan, tables: dict[str, "TableStats"],
+                joins: dict | None = None) -> int:
+    """A sound upper bound on every frequency ``plan``'s sweep produces
+    and on its COUNT, from the statistics of the relations it scans.
+
+    Per node, ``rows`` bounds its state's live rows and ``freq`` each
+    row's frequency.  A scan holds its relation's live rows at their
+    largest base frequency; a semi-join keeps its parent's bounds; a
+    freq-join multiplies its parent's ``freq`` by the most child rows one
+    key can match (the least ``max_mult`` of the child's key columns,
+    since the sweep keeps the child relation's rows) and by the child's
+    ``freq``.  A materialising join (the eager baselines) bounds its rows
+    and, regrouped, its groups the same way.  The answer's bound is the
+    root's rows times its ``freq``.  ``joins``, when given, gains for each
+    freq-join (``join_id``) the most child rows one key holds and the
+    child's ``freq``."""
+    rows: dict[int, int] = {}
+    freq: dict[int, int] = {}
+    base: dict[int, str | None] = {}   # relation whose rows a state holds
+
+    def mult(node, alias: str, on_vars) -> int:
+        rel = base[id(node)]
+        if rel is None:
+            return rows[id(node)]
+        cols = tables[rel].columns
+        names = [plan.var_cols[alias][v] for v in on_vars]
+        return min((cols[c].max_mult for c in names),
+                   default=rows[id(node)])
+
+    for node in plan.nodes:
+        op, i = node.op, id(node)
+        if isinstance(op, ScanOp):
+            st = tables[op.rel]
+            rows[i], freq[i], base[i] = st.rows, st.max_freq, op.rel
+            continue
+        p = node.inputs[0]
+        rows[i], freq[i], base[i] = rows[id(p)], freq[id(p)], base[id(p)]
+        if isinstance(op, FreqJoinOp):
+            c = node.inputs[1]
+            m = mult(c, op.child, op.on_vars)
+            freq[i] *= m * freq[id(c)]
+            if joins is not None:
+                joins[join_id(node)] = (m, freq[id(c)])
+        elif isinstance(op, MaterializeJoinOp):
+            c = node.inputs[1]
+            m = mult(c, op.child, op.on_vars)
+            if op.regroup:
+                # a group sums the parent rows that share every value
+                freq[i] *= mult(p, op.parent, tuple(plan.var_cols[op.parent])
+                                ) * m * freq[id(c)]
+            else:
+                rows[i] *= m
+                freq[i] *= freq[id(c)]
+            base[i] = None
+    root = id(plan.root)
+    return max(max(freq.values()), rows[root] * freq[root])
+
+
+def count_bits(bound: int) -> int:
+    """The narrowest width that holds every count up to ``bound``
+    exactly; a ``PlanningError`` naming the bound where none does."""
+    for bits, limit in COUNT_LIMITS:
+        if bound <= limit:
+            return bits
+    raise PlanningError(
+        f"the counts of this query are bounded by {bound} "
+        f"(2^{math.log2(bound):.1f}), past 2^63 - 1: no exact width holds "
+        "them")
 
 
 # ---------------------------------------------------------------------------
@@ -408,6 +583,15 @@ __all__ = [
     "FeedbackRecord",
     "StatsCatalog",
     "compute_table_stats",
+    "count_bound",
+    "count_bits",
+    "count_plan",
+    "Counts",
+    "db_counts",
+    "join_id",
+    "limb_split",
+    "widest",
+    "COUNT_LIMITS",
     "FK_ELIM_MAX_ORPHANS",
     "PREFILTER_MAX_SELECTIVITY",
     "PREFILTER_MIN_PARENT_ROWS",

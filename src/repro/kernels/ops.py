@@ -93,7 +93,8 @@ def join_path(domain: int | None, n_child: int, *, backend: str | None = None,
 def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
               mode: str = "sum", backend: str | None = None,
               domain: int | None = None,
-              config: KernelConfig | None = None):
+              config: KernelConfig | None = None,
+              limbs: tuple[int, int] | None = None):
     """R ⋉^freq S — returns updated parent frequencies (paper §5).
 
     mode="sum": ℕ-semiring (COUNT/SUM propagation);
@@ -107,50 +108,83 @@ def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
     embedding-gradient update.  Keys outside the domain contribute
     nothing.  Otherwise it sorts; the crossover comes from ``config``
     (``dense_ratio``/``dense_floor``).
+
+    64-bit frequencies: XLA's s64 scatter-add and gather cost 11.9× and
+    2.7× their s32 twins on a v5e (PERF.md), so the dense path
+    scatters and gathers 32-bit words only.  The child's frequencies are
+    split into ``limbs`` = (bits a limb, limbs), which the caller chooses
+    from the statistics (``repro.core.stats.limb_split``); each limb is
+    scatter-added and gathered as uint32, exact, and the limbs are
+    recombined in s64.  64-bit frequencies on the dense path without
+    ``limbs`` are refused.
     """
     with jax.named_scope("freq_join"):
         return _freq_join_call(parent_keys, parent_freq, child_keys,
                                child_freq, mode=mode, backend=backend,
-                               domain=domain, config=config)
+                               domain=domain, config=config, limbs=limbs)
 
 
 def _freq_join_call(parent_keys, parent_freq, child_keys, child_freq, *,
-                    mode, backend, domain, config):
+                    mode, backend, domain, config, limbs=None):
     return _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq,
                            mode=mode, backend=backend or default_backend(),
                            interpret=interpret_mode(),
-                           domain=domain, config=config or DEFAULT_CONFIG)
+                           domain=domain, config=config or DEFAULT_CONFIG,
+                           limbs=limbs)
+
+
+def _scatter_gather(parent_keys, child_keys, values, domain: int):
+    """``acc[parent_keys]`` where ``acc[k]`` sums ``values`` over the child
+    rows of key k; keys outside ``[0, domain)`` contribute and receive
+    nothing."""
+    # scatter-add with EXPLICIT masking: ``mode="drop"`` alone drops
+    # indices >= domain but follows NumPy semantics for negative ones
+    # (wrapping them onto valid slots), which would corrupt acc[domain-1]
+    # whenever dead/out-of-range child keys are negative — mask to zero
+    # contribution instead
+    with jax.named_scope("scatter"):
+        live = (child_keys >= 0) & (child_keys < domain)
+        acc = jnp.zeros((domain,), values.dtype)
+        acc = acc.at[jnp.clip(child_keys, 0, domain - 1)].add(
+            jnp.where(live, values, 0))
+    with jax.named_scope("gather"):
+        mult = acc[jnp.clip(parent_keys, 0, domain - 1)]
+        return jnp.where((parent_keys >= 0) & (parent_keys < domain), mult,
+                         0)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "backend", "interpret",
-                                             "domain", "config"))
+                                             "domain", "config", "limbs"))
 def _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq, *,
                     mode: str, backend: str, interpret: bool,
-                    domain: int | None, config: KernelConfig):
+                    domain: int | None, config: KernelConfig,
+                    limbs: tuple[int, int] | None = None):
     if backend == "xla":
         nc = child_keys.shape[0]
         if join_path(domain, nc, backend=backend, config=config) == "dense":
-            cf = child_freq
+            dt = parent_freq.dtype
             if mode == "any":
-                cf = (cf > 0).astype(parent_freq.dtype)
-            # scatter-add with EXPLICIT masking: ``mode="drop"`` alone
-            # drops indices >= domain but follows NumPy semantics for
-            # negative ones (wrapping them onto valid slots), which would
-            # corrupt acc[domain-1] whenever dead/out-of-range child keys
-            # are negative — mask to zero contribution instead
-            with jax.named_scope("scatter"):
-                live = (child_keys >= 0) & (child_keys < domain)
-                acc = jnp.zeros((domain,), cf.dtype)
-                acc = acc.at[jnp.clip(child_keys, 0, domain - 1)].add(
-                    jnp.where(live, cf, 0))
-            with jax.named_scope("gather"):
-                mult = acc[jnp.clip(parent_keys, 0, domain - 1)]
-                mult = jnp.where(
-                    (parent_keys >= 0) & (parent_keys < domain), mult, 0)
-                mult = mult.astype(parent_freq.dtype)
-                if mode == "any":
-                    mult = (mult > 0).astype(parent_freq.dtype)
-                return parent_freq * mult
+                # a count of live child rows, below 2^31 at any width
+                mult = _scatter_gather(parent_keys, child_keys,
+                                       (child_freq > 0).astype(jnp.int32),
+                                       domain)
+                return parent_freq * (mult > 0).astype(dt)
+            if dt.itemsize <= 4:
+                mult = _scatter_gather(parent_keys, child_keys, child_freq,
+                                       domain)
+                return parent_freq * mult.astype(dt)
+            if limbs is None:
+                raise ValueError(
+                    "64-bit frequencies on the dense path need their limb "
+                    "split (repro.core.stats.limb_split)")
+            bits, n = limbs
+            mask = (1 << bits) - 1
+            mult = jnp.zeros(parent_freq.shape, dt)
+            for i in range(n):
+                limb = ((child_freq >> (bits * i)) & mask).astype(jnp.uint32)
+                part = _scatter_gather(parent_keys, child_keys, limb, domain)
+                mult = mult + (part.astype(dt) << (bits * i))
+            return parent_freq * mult
         with jax.named_scope("sort"):
             order = jnp.argsort(child_keys)
             ck = child_keys[order]

@@ -133,6 +133,26 @@ the padding their buckets add, once per executed program) and
 ``compiles_evicted``; ``joins_dense`` and ``joins_sorted``, the paths
 of each executed program's XLA join kernel calls
 (``Executor.joins``, tallied once when the program is traced).
+
+Exact counts: each plan's counts are bounded from the statistics
+(``repro.core.stats.count_bound``, noted as ``count_bound_log2`` on the
+``plan`` span), and its program holds them in the narrowest width that
+fits: 32 bits, 64 bits, or a ``PlanningError`` naming the bound; at 64
+bits each dense join scatters 32-bit limbs sized from the statistics
+(``count_plan``, ``Counts``).  A fused program takes its widest member's
+width.  ``update_table`` installs a table's statistics with its data,
+under one lock acquisition, and a program's ``Counts`` come from the
+statistics its snapshot took with the data: a plan's own, from planning,
+while they still describe that data, else counted again.  The ``Counts``
+are part of a cached program: a program whose ``Counts`` the
+snapshot's statistics no longer choose — after an ``update_table`` whose
+multiplicities cross 2^31, say — is invalid, and recompiles
+(``compiles_invalidated``).  Each execution
+counts its program by width (``programs_count32``, ``programs_count64``;
+its ``run`` span notes ``count_bits``) and its freq-join kernel calls'
+rows and the dense path's accumulator slots by width
+(``join_rows<w>``: child plus parent rows; ``join_parent_rows<w>``;
+``join_slots<w>``), tallied once per program like ``joins_*``.
 """
 
 from __future__ import annotations
@@ -140,14 +160,15 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import math
 import threading
 from concurrent.futures import Future
 from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.executor import (
+    COUNT_DTYPES,
     ExecStats,
     Executor,
     shared_subplan_savings,
@@ -156,7 +177,9 @@ from repro.core.plan import MaterializeJoinOp, PhysicalPlan, segment_plan
 from repro.core.rewrite import plan_query
 from repro.core.scopes import scope_table
 from repro.core.sql import parse_sql
-from repro.core.stats import FUSION_COST_DISPARITY, StatsCatalog
+from repro.core.stats import (FUSION_COST_DISPARITY, Counts, StatsCatalog,
+                              TableStats, compute_table_stats, count_plan,
+                              widest)
 from repro.service.fingerprint import CanonicalQuery, canonicalize
 from repro.service.observability import (DEFAULT_TENANT, NULL_SPAN,
                                          Observability, TraceSpan)
@@ -245,13 +268,16 @@ class QueryResult:
 @dataclasses.dataclass(frozen=True)
 class _Program:
     """A cached executable: the jitted callable, its XLA module name (what
-    the profiler trace calls its runs), its join kernel calls by path and,
-    under ``profile_annotations``, its ``{HLO instruction: scope path}``
+    the profiler trace calls its runs), the width of its counts, its join
+    kernel calls by path (and their rows and slots by width) and, under
+    ``profile_annotations``, its ``{HLO instruction: scope path}``
     table."""
 
     fn: Callable
     name: str
+    counts: Counts
     joins: dict[str, int]
+    sizes: dict[str, int]
     scopes: dict[str, str] | None = None
 
 
@@ -279,6 +305,7 @@ class _Unit:
     prefix_key: str | None            # whole-prefix identity (diagnostics)
     subplans: frozenset               # non-trivial subplan content keys
     sig: str                          # member signature for the fused cache
+    counts: Counts                    # its counts, from planning's stats
     plan_source: str = "memory"       # memory | disk | built
     results: dict = dataclasses.field(default_factory=dict)
     served_sig: str = ""              # fusion-group signature it ran under
@@ -305,7 +332,7 @@ class QueryService:
 
     def __init__(self, db: dict[str, Table], schema: Schema, *,
                  mode: str = "auto", use_fkpk: bool = False,
-                 freq_dtype=jnp.int32, backend: str = "xla",
+                 backend: str = "xla",
                  plan_capacity: int = 256, exec_capacity: int = 512,
                  fused_capacity: int = 128, padded_capacity: int = 64,
                  min_bucket: int = 8, async_max_batch: int = 64,
@@ -358,6 +385,11 @@ class QueryService:
             "pad_rows_held", "pad_rows_added",
             # join kernel calls of the executed programs, by path
             "joins_dense", "joins_sorted",
+            # executed programs by the width of their counts, and their
+            # freq-join calls' rows and accumulator slots by width
+            *(f"{c}{w}" for w in COUNT_DTYPES
+              for c in ("programs_count", "join_rows", "join_parent_rows",
+                        "join_slots")),
             "eager_requests",
             "plan_builds",            # plan_query pipeline actually ran
                                       # (0 in a fully warm-started process)
@@ -391,8 +423,8 @@ class QueryService:
             axes = tuple(data_axes) if data_axes is not None \
                 else tuple(mesh.axis_names)
             self._jit_executor = DistributedExecutor(
-                schema, mesh, data_axes=axes, freq_dtype=freq_dtype,
-                presort=mesh_presort, dense_domain=mesh_dense_domain)
+                schema, mesh, data_axes=axes, presort=mesh_presort,
+                dense_domain=mesh_dense_domain)
             # the shape-relevant mesh identity, folded into every
             # executable-cache key and the persistent store fingerprint:
             # a ring program compiled for one mesh shape must never answer
@@ -403,8 +435,7 @@ class QueryService:
             for a, n in zip(*self._topo):
                 self.obs.set_gauge(f"mesh_shard_count_{a}", n)
         else:
-            self._jit_executor = Executor(self._db, schema, freq_dtype,
-                                          backend)
+            self._jit_executor = Executor(self._db, schema, backend)
             self._topo = ()
             self._row_sharding = None
         store = None
@@ -451,7 +482,8 @@ class QueryService:
         self._tokens: dict[str, str] = {
             name: t.content_token() for name, t in self._db.items()}
         for name in sorted(self._db):
-            self._refresh_stats(name)
+            self.stats.install(self._table_stats(name, self._db,
+                                                 self._tokens))
         if self.stats_store is not None:
             fb = self.stats_store.load_feedback()
             if fb is not None:
@@ -469,6 +501,9 @@ class QueryService:
         # in-flight events
         self._lock = threading.RLock()
         self._inflight: dict[tuple, threading.Event] = {}
+        # serialises update_table calls with each other only: each
+        # computes its statistics from the database the previous one left
+        self._update_lock = threading.Lock()
         # async tier: started lazily on the first submit_async.
         # ``tenants`` maps tenant name -> TenantPolicy (quota / queue
         # bound / DRR weight / priority lane); unlisted tenants get the
@@ -511,62 +546,74 @@ class QueryService:
                 raise ValueError(
                     f"table {name!r} freq dtype {table.freq.dtype} != "
                     f"existing {old.freq.dtype}")
-        with self._lock:
-            old_bucket = self._bucket_cap(self._db[name].capacity) \
-                if name in self._db else None
-            self._db[name] = table
-            self.cache.drop_padded(name)
-            new_bucket = self._bucket_cap(table.capacity)
-            if old_bucket != new_bucket:
-                n = self.cache.invalidate_relation(name)
-                self.obs.inc("bucket_invalidations", n)
-        # statistics follow the data: refresh this table, plus every table
-        # whose FK points AT it (their orphan counts read the new data).
-        # Outside the lock — stats computes touch device arrays and the
-        # catalog has its own synchronisation.
-        self._tokens[name] = table.content_token()
-        self._refresh_stats(name)
-        for fk in self.schema.foreign_keys:
-            if fk.dst == name and fk.src in self._db:
-                self._refresh_stats(fk.src)
-        # cached plans whose gating decisions consulted now-changed
-        # statistics must re-plan: the same fingerprint may deserve a
-        # different graph under the new data distribution
-        with self._lock:
-            self.cache.plans.invalidate_items(
-                lambda fp, plan: not self._decisions_valid(plan))
+        with self._update_lock:
+            # statistics follow the data: this table's, plus those of
+            # every table whose FK points AT it (their orphan counts read
+            # the new data).  Computed before the swap and outside the
+            # service lock (stats computes touch device arrays), then
+            # installed WITH the swap under one acquisition, so a snapshot
+            # never pairs new data with old statistics: the width of its
+            # counts reads them
+            with self._lock:
+                db = {**self._db, name: table}
+            tokens = {**self._tokens, name: table.content_token()}
+            fresh = [self._table_stats(n, db, tokens)
+                     for n in dict.fromkeys(
+                         [name] + [fk.src for fk in self.schema.foreign_keys
+                                   if fk.dst == name and fk.src in db])]
+            with self._lock:
+                old_bucket = self._bucket_cap(self._db[name].capacity) \
+                    if name in self._db else None
+                self._db[name] = table
+                self._tokens = tokens
+                for st in fresh:
+                    self.stats.install(st)
+                self.cache.drop_padded(name)
+                new_bucket = self._bucket_cap(table.capacity)
+                if old_bucket != new_bucket:
+                    n = self.cache.invalidate_relation(name)
+                    self.obs.inc("bucket_invalidations", n)
+                # cached plans whose gating decisions consulted
+                # now-changed statistics must re-plan: the same
+                # fingerprint may deserve a different graph under the new
+                # data distribution
+                self.cache.plans.invalidate_items(
+                    lambda fp, plan: not self._decisions_valid(plan))
 
     # ---- statistics ------------------------------------------------------
-    def _stats_store_token(self, name: str) -> str:
+    def _stats_store_token(self, name: str, tokens: dict[str, str]) -> str:
         """Composite content token keying ``name``'s persisted stats: its
         own data version plus its FK destinations' (orphan counts depend on
-        both sides).  Any change to either side forces a fresh compute."""
-        parts = [self._tokens[name]]
+        both sides), from the data versions ``tokens``.  Any change to
+        either side forces a fresh compute."""
+        parts = [tokens[name]]
         for fk in sorted(self.schema.foreign_keys,
                          key=lambda f: (f.src, f.src_col)):
-            if fk.src == name and fk.dst in self._tokens:
-                parts.append(self._tokens[fk.dst])
+            if fk.src == name and fk.dst in tokens:
+                parts.append(tokens[fk.dst])
         if len(parts) == 1:
             return parts[0]
         return hashlib.sha256("\n".join(parts).encode()).hexdigest()
 
-    def _refresh_stats(self, name: str) -> None:
-        """Bring ``name``'s catalog entry up to date: persisted stats at
-        the current composite token install without recomputation; a miss
-        computes fresh (counted ``stat_refreshes``) and writes back."""
-        token = self._stats_store_token(name)
+    def _table_stats(self, name: str, db: dict[str, Table],
+                     tokens: dict[str, str]) -> TableStats:
+        """``name``'s statistics over ``db``, whose data versions are
+        ``tokens``: persisted stats at the composite token load without
+        recomputation; a miss computes fresh (counted ``stat_refreshes``)
+        and writes back.  The caller installs them."""
+        token = self._stats_store_token(name, tokens)
         if self.stats_store is not None:
             stats = self.stats_store.load(name, token)
             if stats is not None:
-                self.stats.install(stats)
-                return
-        stats = self.stats.refresh(name, self._db[name], self._db)
+                return stats
+        stats = compute_table_stats(name, db[name], self.schema, db)
         self.obs.inc("stat_refreshes")
         if self.stats_store is not None:
             # keyed by the composite token (the staleness discipline); the
             # payload keeps the table's OWN token, so a warm install puts
             # exactly what a cold compute would into the catalog
             self.stats_store.save(stats, token=token)
+        return stats
 
     def _decisions_valid(self, plan: PhysicalPlan) -> bool:
         """True iff every statistic a plan's gating decisions consulted
@@ -588,15 +635,19 @@ class QueryService:
                                                      self.min_bucket)
         return bucket_capacity(n_rows, self.min_bucket)
 
-    def _snapshot(self, rels) -> tuple[ShapeBucket, dict[str, Table]]:
-        """Shape bucket + bucket-padded table views for `rels`.
+    def _snapshot(self, rels) -> tuple[ShapeBucket, dict[str, Table],
+                                       dict[str, TableStats]]:
+        """Shape bucket + bucket-padded table views for `rels`, and the
+        statistics of the data they hold.
 
-        The raw tables and the bucket are captured under ONE lock
-        acquisition so they describe the same database state: a concurrent
-        bucket-crossing ``update_table`` can never pair a stale-bucket
-        cache key with fresh-shaped inputs (which would make the cached
-        jitted fn silently retrace inside ``jax.jit``).  Tables are
-        immutable, so the snapshot stays consistent after release — which
+        The raw tables, the bucket and the statistics are captured under
+        ONE lock acquisition so they describe the same database state: a
+        concurrent bucket-crossing ``update_table`` can never pair a
+        stale-bucket cache key with fresh-shaped inputs (which would make
+        the cached jitted fn silently retrace inside ``jax.jit``), nor new
+        data with old statistics, from which its counts' width is chosen.
+        Tables are immutable, so the snapshot stays consistent after
+        release — which
         is what lets the padding itself (``Table.pad_to``, device work)
         run OUTSIDE the lock, serialised per (relation, capacity) by
         in-flight events exactly like compiles.  Counts the rows held and
@@ -606,12 +657,13 @@ class QueryService:
             bucket: ShapeBucket = tuple(
                 (rel, self._bucket_cap(base[rel].capacity))
                 for rel in rels)
+            tables = self.stats.tables()
         held = sum(base[rel].capacity for rel in rels)
         self.obs.inc("pad_rows_held", held)
         self.obs.inc("pad_rows_added", sum(cap for _, cap in bucket) - held)
         sub_db = {rel: self._padded_view(rel, base[rel], cap)
                   for rel, cap in bucket}
-        return bucket, sub_db
+        return bucket, sub_db, tables
 
     def _padded_view(self, rel: str, table: Table, cap: int) -> Table:
         """`table` padded to `cap`, from the bounded padded-view cache.
@@ -1027,7 +1079,9 @@ class QueryService:
                            fingerprint=canon.fingerprint) as sp:
             plan, plan_hit = self._get_or_build(
                 self.cache.plans, canon.fingerprint, build)
-            sp.note(source="memory" if plan_hit else source, hit=plan_hit)
+            counts = count_plan(plan, self.stats.tables())
+            sp.note(source="memory" if plan_hit else source, hit=plan_hit,
+                    count_bound_log2=math.log2(max(counts.bound, 1)))
         plan_s = sp.duration_s
         with self._lock:
             seg = self._segments.get(canon.fingerprint)
@@ -1050,7 +1104,7 @@ class QueryService:
                 self._segments[canon.fingerprint] = seg
         eager, prefix_key, subplans, sig = seg
         unit = _Unit(group, plan, plan_hit, plan_s, eager, prefix_key,
-                     subplans, sig,
+                     subplans, sig, counts,
                      plan_source="memory" if plan_hit else source)
         for r in group:
             r.unit = unit
@@ -1242,8 +1296,8 @@ class QueryService:
         child span of the request's ``run`` span — the collective sweep is
         the mesh path's distinguishing cost and deserves its own timing
         row."""
-        run_span.note(program=prog.name)
-        self._count_joins(prog.joins)
+        run_span.note(program=prog.name, count_bits=prog.counts.bits)
+        self._count_program(prog.counts.bits, prog.joins, prog.sizes)
         if prog.scopes is not None:
             run_span.note(scopes=prog.scopes)
         fn = prog.fn
@@ -1280,9 +1334,9 @@ class QueryService:
         """The classic path: one fingerprint, one executable."""
         roots = [r.trace for r in u.group]
         with self.obs.span(roots, "pad"):
-            bucket, sub_db = self._snapshot(u.plan.scanned_rels())
-        prog, exec_hit, compile_s = self._executable(u.canon, u.plan, bucket,
-                                                     sub_db, roots)
+            bucket, sub_db, tables = self._snapshot(u.plan.scanned_rels())
+        prog, exec_hit, compile_s = self._executable(
+            u.canon, u.plan, bucket, sub_db, self._counts([u], tables), roots)
         with self.obs.span(roots, "run") as rsp:
             results = self._invoke(prog, sub_db, rsp)
         self._finish_unit(u, results, exec_hit=exec_hit, bucket=bucket,
@@ -1302,7 +1356,7 @@ class QueryService:
         roots = [r.trace for u in units for r in u.group]
         rels = sorted({rel for p in plans for rel in p.scanned_rels()})
         with self.obs.span(roots, "pad"):
-            bucket, sub_db = self._snapshot(rels)
+            bucket, sub_db, tables = self._snapshot(rels)
         signature = hashlib.sha256(
             repr(tuple(u.sig for u in units)).encode()).hexdigest()
         for u in units:
@@ -1311,25 +1365,29 @@ class QueryService:
             u.served_sig = signature
         compile_s = 0.0
         key = PlanCache.fused_key(signature, bucket, self._topo)
+        counts = self._counts(units, tables)
 
         def build():
             nonlocal compile_s
-            with self._lock:
-                cause = PlanCache.compile_cause(self.cache.fused, key)
+            cause = self._compile_cause(self.cache.fused, key)
             with self.obs.span(roots, "compile", cold=True, fused=True,
                                members=len(units), cause=cause) as sp:
                 name = f"fused_{signature[:12]}"
-                joins = collections.Counter()
-                fn = self._jit_executor.compile_multi(plans, name=name,
-                                                      joins=joins)
+                joins, sizes = collections.Counter(), collections.Counter()
+                fn = self._jit_executor.compile_multi(
+                    plans, name=name, joins=joins, counts=counts,
+                    sizes=sizes)
                 jax.block_until_ready(fn(sub_db))
-                prog = self._program(fn, name, joins, sub_db, sp)
+                prog = self._program(fn, name, counts, joins, sizes, sub_db,
+                                     sp)
             compile_s = sp.duration_s
             self._count_compile(cause, compile_s)
             self.obs.inc("fused_compiles")
             return prog
 
-        prog, exec_hit = self._get_or_build(self.cache.fused, key, build)
+        prog, exec_hit = self._get_or_build(
+            self.cache.fused, key, build,
+            valid=lambda p: p.counts == counts)
         with self.obs.span(roots, "run", fused=True) as rsp:
             outs = self._invoke(prog, sub_db, rsp)
 
@@ -1349,34 +1407,56 @@ class QueryService:
 
     def _executable(self, canon: CanonicalQuery, plan: PhysicalPlan,
                     bucket: ShapeBucket, sub_db: dict[str, Table],
-                    parents=(),
+                    counts: Counts, parents=(),
                     ) -> tuple[_Program, bool, float]:
         compile_s = 0.0
         key = PlanCache.exec_key(canon.fingerprint, bucket, self._topo)
 
         def build():
             nonlocal compile_s
-            with self._lock:
-                cause = PlanCache.compile_cause(self.cache.execs, key)
+            cause = self._compile_cause(self.cache.execs, key)
             with self.obs.span(parents, "compile", cold=True, fused=False,
                                fingerprint=canon.fingerprint,
                                cause=cause) as sp:
                 name = f"q_{canon.fingerprint[:12]}"
-                joins = collections.Counter()
-                fn = self._jit_executor.compile(plan, name=name, joins=joins)
+                joins, sizes = collections.Counter(), collections.Counter()
+                fn = self._jit_executor.compile(plan, name=name, joins=joins,
+                                                counts=counts, sizes=sizes)
                 # trace + compile now, against the snapshot's bucket
                 # shapes, so the cache entry is a ready-to-run program and
                 # `run_s` is pure execution
                 jax.block_until_ready(fn(sub_db))
-                prog = self._program(fn, name, joins, sub_db, sp)
+                prog = self._program(fn, name, counts, joins, sizes, sub_db,
+                                     sp)
             compile_s = sp.duration_s
             self._count_compile(cause, compile_s)
             return prog
 
-        prog, hit = self._get_or_build(self.cache.execs, key, build)
+        prog, hit = self._get_or_build(self.cache.execs, key, build,
+                                       valid=lambda p: p.counts == counts)
         return prog, hit, compile_s
 
-    def _program(self, fn: Callable, name: str, joins: dict[str, int],
+    @staticmethod
+    def _counts(units: list[_Unit],
+                tables: dict[str, TableStats]) -> Counts:
+        """The ``Counts`` of one program running ``units``' plans over a
+        snapshot whose statistics are ``tables``: each unit's own from
+        planning while they describe the snapshot's data, counted again
+        from ``tables`` where an ``update_table`` came in between."""
+        return widest([u.counts if u.counts.current(tables)
+                       else count_plan(u.plan, tables) for u in units])
+
+    def _compile_cause(self, cache: LRUCache, key: tuple) -> str:
+        """Why building ``key`` compiles.  An entry still cached there was
+        found invalid: the live statistics choose other ``Counts`` for it
+        (another width, or other limbs)."""
+        with self._lock:
+            if cache.peek(key) is not None:
+                return "invalidated"
+            return PlanCache.compile_cause(cache, key)
+
+    def _program(self, fn: Callable, name: str, counts: Counts,
+                 joins: dict[str, int], sizes: dict[str, int],
                  sub_db: dict[str, Table], compile_span) -> _Program:
         """A freshly compiled (and once run) program, named, with the
         paths its trace tallied for its join kernel calls; under
@@ -1389,11 +1469,19 @@ class QueryService:
             with self.obs.span(compile_span, "scope_table"):
                 text = fn.lower(sub_db).compile().as_text()
                 scopes = scope_table(text) if text is not None else None
-        return _Program(fn, f"jit_{name}", dict(joins), scopes)
+        return _Program(fn, f"jit_{name}", counts, dict(joins), dict(sizes),
+                        scopes)
 
-    def _count_joins(self, joins: dict[str, int]) -> None:
+    def _count_program(self, bits: int, joins: dict[str, int],
+                       sizes: dict[str, int]) -> None:
+        """One execution of a program with ``bits``-wide counts and the
+        join tallies ``joins`` and ``sizes``."""
+        self.obs.inc(f"programs_count{bits}")
         for path in ("dense", "sorted"):
             self.obs.inc(f"joins_{path}", joins.get(path, 0))
+        for w in COUNT_DTYPES:
+            for c in ("rows", "parent_rows", "slots"):
+                self.obs.inc(f"join_{c}{w}", sizes.get(f"{c}{w}", 0))
 
     def _count_compile(self, cause: str, compile_s: float) -> None:
         self.obs.inc("compiles")
@@ -1407,20 +1495,23 @@ class QueryService:
         roots = [r.trace for r in u.group]
         self.obs.inc("eager_requests", len(u.group))
         with self._lock:
-            # snapshot the scanned tables under the lock (tables are
-            # immutable): execution then runs unlocked over a consistent
-            # database state even if update_table swaps relations mid-run
+            # snapshot the scanned tables and their statistics under the
+            # lock (tables are immutable): execution then runs unlocked
+            # over a consistent database state even if update_table swaps
+            # relations mid-run
             sub_db = {rel: self._db[rel] for rel in u.plan.scanned_rels()}
-        ex = Executor(sub_db, self.schema, base.freq_dtype, base.backend,
-                      tuning=base.tuning)
+            tables = self.stats.tables()
+        counts = self._counts([u], tables)
+        ex = Executor(sub_db, self.schema, base.backend, tuning=base.tuning)
         stats = ExecStats()
-        with self.obs.span(roots, "run", eager=True) as rsp:
-            results = ex.execute(u.plan, stats)
+        with self.obs.span(roots, "run", eager=True,
+                           count_bits=counts.bits) as rsp:
+            results = ex.execute(u.plan, stats, counts=counts)
             # the executor's "__stats__" sentinel is bookkeeping, not an
             # answer column: it travels via ServeStats.exec_stats only
             results.pop("__stats__", None)
             jax.block_until_ready(list(results.values()))
-        self._count_joins(ex.joins)
+        self._count_program(counts.bits, ex.joins, ex.join_sizes)
         self._finish_unit(u, results, exec_hit=False, bucket=(),
                           compile_s=0.0, run_s=rsp.duration_s,
                           exec_source="eager")

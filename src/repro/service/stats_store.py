@@ -46,7 +46,7 @@ from pathlib import Path
 
 from repro.core.stats import TableStats
 
-STATS_FORMAT_VERSION = 1
+STATS_FORMAT_VERSION = 2
 
 _FEEDBACK_KEY = "__feedback__"
 

@@ -32,6 +32,7 @@ from repro.core.plan import (
     SemiJoinOp,
 )
 from repro.core.query import Agg, AggQuery, Atom
+from repro.core.stats import db_counts
 from repro.tables.table import ColumnMeta, RelSchema, Schema, Table
 
 try:  # property tests degrade to a seeded sweep without hypothesis
@@ -106,8 +107,7 @@ class LinearReference:
         outer = self.ex
 
         def run(db):
-            inner = Executor(db, outer.schema, outer.freq_dtype,
-                             outer.backend)
+            inner = Executor(db, outer.schema, outer.backend)
             return self._sweep(inner, plan)
 
         return jax.jit(run)
@@ -237,10 +237,13 @@ def _check_case(seed: int):
         mesh_solo = []
         for plan in plans:
             want_c = dict(new.compile(plan)(host))
-            got_c = dict(dex.compile(plan)(sharded))
+            got_c = dict(dex.compile(
+                plan, counts=db_counts([plan], db, SCHEMA))(sharded))
             _assert_bitwise(want_c, got_c, ctx="mesh-vs-local")
             mesh_solo.append(got_c)
-        for want_c, got_c in zip(mesh_solo, dex.compile_multi(plans)(sharded)):
+        fused = dex.compile_multi(
+            plans, counts=db_counts(plans, db, SCHEMA))(sharded)
+        for want_c, got_c in zip(mesh_solo, fused):
             _assert_bitwise(want_c, dict(got_c), ctx="mesh-fused-vs-solo")
 
 

@@ -108,6 +108,7 @@ def test_distributed_executor_on_default_explicit_mesh(tpch, query):
     local executor's bitwise over identically padded tables."""
     from repro.core import Executor, parse_sql, plan_query
     from repro.core.distributed import DistributedExecutor
+    from repro.core.stats import db_counts
 
     db, schema = tpch
     mesh = _mesh1()
@@ -119,7 +120,9 @@ def test_distributed_executor_on_default_explicit_mesh(tpch, query):
     sharded = dex.shard_db(db)
     host = {k: db[k].pad_to(sharded[k].capacity) for k in db}
     want = dict(Executor(db, schema).compile(plan)(host))
-    _assert_bitwise(want, dict(dex.compile(plan)(sharded)), query)
+    counts = db_counts([plan], db, schema)
+    _assert_bitwise(want, dict(dex.compile(plan, counts=counts)(sharded)),
+                    query)
 
 
 def test_mesh_and_local_services_occupy_distinct_exec_entries(tpch):

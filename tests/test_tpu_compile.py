@@ -18,6 +18,7 @@ without one.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,3 +111,24 @@ def test_pallas_segment_sum_compiles(one_chip, dtype, lanes_wide):
     compiled = _compile(fn, _shape(PARENT, jnp.int32, one_chip),
                         _shape(PARENT, dtype, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_xla_freq_join_with_64_bit_counts_compiles(one_chip):
+    """The dense freq-join with s64 counts compiles, scatters and gathers
+    32-bit limbs only, and keeps the kernel scopes the device trace's
+    reduction reads."""
+    from repro.core.scopes import scope_table
+    from repro.core.stats import limb_split
+
+    # the graph cell's split: a hub of 160,410 rows, frequencies to 2^40
+    fn = functools.partial(ops.freq_join, mode="sum", backend="xla",
+                           domain=CHILD, config=DEFAULT_CONFIG,
+                           limbs=limb_split(160_410, 2 ** 40))
+    with jax.enable_x64(True):
+        compiled = _compile(fn, *_join_args(one_chip, jnp.int64))
+    text = compiled.as_text()
+    assert "s64[" in text
+    # the scatter-adds write 32-bit limbs, never s64
+    assert not re.findall(r"^.*= s64\[[^\n]*\bscatter\(", text, re.M)
+    paths = set(scope_table(text).values())
+    assert {"freq_join/scatter", "freq_join/gather"} <= paths
