@@ -42,25 +42,29 @@ class JoinTree:
     def __hash__(self):
         return hash(self.cache_key())
 
-    def children(self, alias: str) -> list[str]:
-        return sorted(a for a, p in self.parent.items() if p == alias)
+    def children(self, alias: str, key=None) -> list[str]:
+        """``alias``'s children by name, or by ``key`` (a child → sort
+        key) with ties by name."""
+        kids = sorted(a for a, p in self.parent.items() if p == alias)
+        return kids if key is None else sorted(kids, key=key)
 
-    def postorder(self) -> list[str]:
+    def postorder(self, key=None) -> list[str]:
         out: list[str] = []
 
         def rec(u: str):
-            for c in self.children(u):
+            for c in self.children(u, key):
                 rec(c)
             out.append(u)
 
         rec(self.root)
         return out
 
-    def edges_bottom_up(self) -> list[tuple[str, str]]:
+    def edges_bottom_up(self, key=None) -> list[tuple[str, str]]:
         """(parent, child) pairs in the order semi-joins/FreqJoins run:
-        children fully processed before their parent consumes them."""
+        children fully processed before their parent consumes them, a
+        parent's children in ``children`` order."""
         out: list[tuple[str, str]] = []
-        for u in self.postorder():
+        for u in self.postorder(key):
             p = self.parent[u]
             if p is not None:
                 out.append((p, u))

@@ -10,7 +10,11 @@ sequence of passes over a shared build state:
                             sweep (Opt⁺); unguarded/cyclic → materialising
                             baseline, the paper's fallback).
   2. ``_pass_reroot_guard``— re-root the join tree at the guard (§4.1);
-                            join trees are freely re-rootable.
+                            join trees are freely re-rootable.  Where
+                            several guards qualify and none is FK/PK-safe,
+                            the statistics choose the one whose counts are
+                            narrowest and whose joins need the fewest
+                            32-bit limbs.
   3. ``_pass_lower``      — emit the op graph: one scan node per atom
                             (selections not yet attached), a join node per
                             tree edge (mode-generic sweep), the final
@@ -45,8 +49,9 @@ each considered candidate leaves a machine-readable
 renders and the serving tier uses to detect stale plans (a decision's
 ``depends`` tokens no longer matching the live catalog ⇒ replan).
 
-With ``stats=None`` (the default — library callers, tests) the two
-stats-calibrated passes (5 and 6) record a skip and change nothing: the
+With ``stats=None`` (the default — library callers, tests) the
+stats-calibrated passes (2's choice among guards, 5 and 6) keep
+classify's guard or record a skip and change nothing: the
 planner's output is byte-for-byte what it was before the stats layer
 existed.  Modes can be forced (benchmarks compare ref / opt / opt_plus /
 oma on the same query, mirroring the paper's experimental conditions).
@@ -57,7 +62,12 @@ from __future__ import annotations
 import dataclasses
 
 from repro.core.hypergraph import build_join_tree
-from repro.core.oma import classify, edge_is_fk_pk, subtree_all_fk_pk
+from repro.core.oma import (
+    classify,
+    edge_is_fk_pk,
+    find_guards,
+    subtree_all_fk_pk,
+)
 from repro.core.plan import (
     Decision,
     FinalAggOp,
@@ -76,9 +86,12 @@ from repro.core.plan import (
 )
 from repro.core.query import AggQuery
 from repro.core.stats import (
+    COUNT_LIMITS,
     FK_ELIM_MAX_ORPHANS,
     PREFILTER_MAX_SELECTIVITY,
     PREFILTER_MIN_PARENT_ROWS,
+    count_plan,
+    join_id,
 )
 from repro.tables.table import Schema
 
@@ -110,6 +123,8 @@ class PlanBuild:
     stats: object = None      # StatsCatalog | None — calibration source
     tree: object = None       # JoinTree after _pass_classify
     guard: str | None = None
+    prior: object = None      # the tree classify rooted, where the
+                              # statistics moved the root
     var_cols: dict = dataclasses.field(default_factory=dict)
     root: PlanNode | None = None  # FinalAgg node after _pass_lower
     decisions: list = dataclasses.field(default_factory=list)
@@ -165,10 +180,27 @@ def _pass_classify(st: PlanBuild) -> PlanBuild:
 
 def _pass_reroot_guard(st: PlanBuild) -> PlanBuild:
     # classify() already roots the tree at its preferred guard (it tries
-    # each guard candidate for whole-tree FK/PK safety); this pass is the
-    # explicit seam where an alternative rooting policy would plug in.
+    # each guard candidate for whole-tree FK/PK safety).  Where several
+    # guards qualify and none is FK/PK-safe, the statistics choose the
+    # root whose joins need the fewest 32-bit limbs (``_root_costs``).
     if st.guard is None:
         st.decide("reroot_guard", "", False, "no guard: unguarded query")
+        return st
+    costs = _root_costs(st)
+    if costs is not None:
+        best = min(costs, key=costs.__getitem__)
+        gate = {f"{g}.{k}": v for g, c in costs.items()
+                for k, v in zip(("bits", "limb_rows"), c)}
+        rels = tuple(a.rel for a in st.query.atoms)
+        if costs[best] < costs[st.guard]:
+            st.prior, st.tree, st.guard = st.tree, st.tree.rerooted(best), best
+            st.decide("reroot_guard", best, True,
+                      f"re-rooted at guard {best!r}: its counts and limbs "
+                      "cost the least", gate, rels=rels)
+        else:
+            st.decide("reroot_guard", st.guard, False,
+                      f"no guard costs less than {st.guard!r}", gate,
+                      rels=rels)
     elif st.tree.root != st.guard:
         st.tree = st.tree.rerooted(st.guard)
         st.decide("reroot_guard", st.guard, True,
@@ -177,6 +209,54 @@ def _pass_reroot_guard(st: PlanBuild) -> PlanBuild:
         st.decide("reroot_guard", st.guard, False,
                   f"tree already rooted at guard {st.guard!r}")
     return st
+
+
+def rerooted(plan: PhysicalPlan) -> bool:
+    """True iff the planner moved ``plan``'s root off the guard classify
+    chose (its ``reroot_guard`` decision applied)."""
+    return any(d.pass_name == "reroot_guard" and d.applied
+               for d in plan.decisions)
+
+
+def _root_costs(st: PlanBuild) -> dict[str, tuple[int, int]] | None:
+    """Each guard candidate's cost as the root of a 64-bit-capable sweep:
+    (the width of its counts, Σ over its freq-joins of limbs × (child +
+    parent rows)), from the plan the remaining passes lower there, or
+    None where the choice is not the statistics' to make: no catalog, a
+    mode without freq-joins, one candidate, an FK/PK-safe guard (which
+    classify keeps for set-safety), or a relation without statistics.  A
+    candidate whose counts no width holds costs the most."""
+    guards = find_guards(st.query)
+    if st.stats is None or st.mode != "opt_plus" or len(guards) < 2 \
+            or subtree_all_fk_pk(st.tree, st.schema, st.guard):
+        return None
+    tables = st.stats.tables()
+    if any(a.rel not in tables for a in st.query.atoms):
+        return None
+    rest = PASSES[PASSES.index(_pass_reroot_guard) + 1:]
+    costs = {}
+    for g in guards:
+        trial = dataclasses.replace(
+            st, guard=g, tree=st.tree.rerooted(g), decisions=[],
+            prior=st.tree if g != st.guard else None)
+        for p in rest:
+            trial = p(trial)
+        plan = PhysicalPlan(trial.mode, trial.root, trial.tree,
+                            trial.var_cols)
+        try:
+            counts = count_plan(plan, tables)
+        except PlanningError:
+            costs[g] = (COUNT_LIMITS[-1][0] + 1, 0)
+            continue
+        rows = 0
+        for node in plan.nodes:
+            op = node.op
+            if isinstance(op, FreqJoinOp):
+                _, limbs = counts.limbs.get(join_id(node), (0, 1))
+                rows += limbs * sum(tables[plan.tree.atoms[a].rel].rows
+                                    for a in (op.child, op.parent))
+        costs[g] = (counts.bits, rows)
+    return costs
 
 
 def _pass_lower(st: PlanBuild) -> PlanBuild:
@@ -205,8 +285,15 @@ def _pass_lower(st: PlanBuild) -> PlanBuild:
                   {"mode": mode, "atoms": len(query.atoms)})
         return st
 
-    # bottom-up sweep over join-tree edges (children before parents)
-    for parent, child in tree.edges_bottom_up():
+    # bottom-up sweep over join-tree edges (children before parents).  In
+    # a tree the statistics re-rooted, a parent first joins the children
+    # it already had under classify's root: those joins are nodes of the
+    # plan rooted there, so what that plan shared with other plans (a
+    # fused program's common join) stays shared.
+    prior = st.prior
+    first = None if prior is None else (
+        lambda c: prior.parent[c] != tree.parent[c])
+    for parent, child in tree.edges_bottom_up(first):
         on = tree.shared_vars(parent, child)
         if mode == "oma":
             op = SemiJoinOp(parent, child, on)
@@ -513,4 +600,4 @@ def plan_query(query: AggQuery, schema: Schema, mode: str = "auto",
 
 
 __all__ = ["plan_query", "classify", "build_join_tree", "PASSES",
-           "PlanBuild", "PlanningError"]
+           "PlanBuild", "PlanningError", "rerooted"]

@@ -25,7 +25,11 @@ the env var between calls takes effect even for already-traced shapes)
 and the config, then dispatch to jitted implementations that carry both
 as static arguments.  Under an outer ``jax.jit`` trace the wrappers
 inline like any other Python, so compiled plans pay nothing for the
-indirection.
+indirection.  The jitted implementations inline too (``inline=True``):
+a program that calls a kernel from several sites with one signature
+would otherwise call one shared function, whose instructions lose the
+call sites' scopes on their way through XLA, so a device trace could not
+name the operator behind them.
 
 Each public entry point opens a ``jax.named_scope`` of its own name
 (``freq_join``, ``semi_join``, ``segment_sum``, ``group_by_sum``,
@@ -153,8 +157,9 @@ def _scatter_gather(parent_keys, child_keys, values, domain: int):
                          0)
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "backend", "interpret",
-                                             "domain", "config", "limbs"))
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("mode", "backend", "interpret", "domain",
+                                    "config", "limbs"))
 def _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq, *,
                     mode: str, backend: str, interpret: bool,
                     domain: int | None, config: KernelConfig,
@@ -243,8 +248,8 @@ def segment_sum_sorted(sorted_keys, values, *, backend: str | None = None,
                                  config=config or DEFAULT_CONFIG)
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "interpret",
-                                             "config"))
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("backend", "interpret", "config"))
 def _segment_sum_impl(sorted_keys, values, *, backend: str, interpret: bool,
                       config: KernelConfig):
     n = sorted_keys.shape[0]
@@ -284,7 +289,7 @@ def group_by_sum(keys, values, *, backend: str | None = None,
 # --------------------------------------------------------------------------
 # Weighted percentile (MEDIAN rewrite, paper §4.2)
 # --------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=())
+@functools.partial(jax.jit, inline=True)
 def weighted_percentile(values, weights, q):
     """PERCENTILE(q, A, freq) — lower-interpolation weighted percentile.
 

@@ -152,7 +152,10 @@ counts its program by width (``programs_count32``, ``programs_count64``;
 its ``run`` span notes ``count_bits``) and its freq-join kernel calls'
 rows and the dense path's accumulator slots by width
 (``join_rows<w>``: child plus parent rows; ``join_parent_rows<w>``;
-``join_slots<w>``), tallied once per program like ``joins_*``.
+``join_slots<w>``), tallied once per program like ``joins_*``, and its
+member plans by whether the statistics moved their root
+(``plans_rerooted``, ``plans_default_root``; ``repro.core.rewrite``'s
+``reroot_guard``).
 """
 
 from __future__ import annotations
@@ -174,7 +177,7 @@ from repro.core.executor import (
     shared_subplan_savings,
 )
 from repro.core.plan import MaterializeJoinOp, PhysicalPlan, segment_plan
-from repro.core.rewrite import plan_query
+from repro.core.rewrite import plan_query, rerooted
 from repro.core.scopes import scope_table
 from repro.core.sql import parse_sql
 from repro.core.stats import (FUSION_COST_DISPARITY, Counts, StatsCatalog,
@@ -390,6 +393,9 @@ class QueryService:
             *(f"{c}{w}" for w in COUNT_DTYPES
               for c in ("programs_count", "join_rows", "join_parent_rows",
                         "join_slots")),
+            # executed member plans by whether the statistics moved their
+            # root off classify's guard
+            "plans_rerooted", "plans_default_root",
             "eager_requests",
             "plan_builds",            # plan_query pipeline actually ran
                                       # (0 in a fully warm-started process)
@@ -1289,7 +1295,8 @@ class QueryService:
                 self._inflight.pop(fk, None)
             ev.set()
 
-    def _invoke(self, prog: _Program, sub_db: dict[str, Table], run_span):
+    def _invoke(self, prog: _Program, sub_db: dict[str, Table], run_span,
+                plans: list[PhysicalPlan]):
         """Execute one ready program to completion, noting on the ``run``
         span which program ran (and its scope table, when built).  On a
         mesh, the execution is additionally wrapped in a ``ring_sweep``
@@ -1297,7 +1304,7 @@ class QueryService:
         the mesh path's distinguishing cost and deserves its own timing
         row."""
         run_span.note(program=prog.name, count_bits=prog.counts.bits)
-        self._count_program(prog.counts.bits, prog.joins, prog.sizes)
+        self._count_program(prog.counts.bits, prog.joins, prog.sizes, plans)
         if prog.scopes is not None:
             run_span.note(scopes=prog.scopes)
         fn = prog.fn
@@ -1338,7 +1345,7 @@ class QueryService:
         prog, exec_hit, compile_s = self._executable(
             u.canon, u.plan, bucket, sub_db, self._counts([u], tables), roots)
         with self.obs.span(roots, "run") as rsp:
-            results = self._invoke(prog, sub_db, rsp)
+            results = self._invoke(prog, sub_db, rsp, [u.plan])
         self._finish_unit(u, results, exec_hit=exec_hit, bucket=bucket,
                           compile_s=compile_s, run_s=rsp.duration_s,
                           exec_source="exec_cache" if exec_hit
@@ -1389,7 +1396,7 @@ class QueryService:
             self.cache.fused, key, build,
             valid=lambda p: p.counts == counts)
         with self.obs.span(roots, "run", fused=True) as rsp:
-            outs = self._invoke(prog, sub_db, rsp)
+            outs = self._invoke(prog, sub_db, rsp, plans)
 
         self.obs.inc("fused_batches")
         self.obs.inc("fused_queries", len(units))
@@ -1473,10 +1480,14 @@ class QueryService:
                         scopes)
 
     def _count_program(self, bits: int, joins: dict[str, int],
-                       sizes: dict[str, int]) -> None:
+                       sizes: dict[str, int],
+                       plans: list[PhysicalPlan]) -> None:
         """One execution of a program with ``bits``-wide counts and the
-        join tallies ``joins`` and ``sizes``."""
+        join tallies ``joins`` and ``sizes``, running ``plans``."""
         self.obs.inc(f"programs_count{bits}")
+        moved = sum(map(rerooted, plans))
+        self.obs.inc("plans_rerooted", moved)
+        self.obs.inc("plans_default_root", len(plans) - moved)
         for path in ("dense", "sorted"):
             self.obs.inc(f"joins_{path}", joins.get(path, 0))
         for w in COUNT_DTYPES:
@@ -1511,7 +1522,7 @@ class QueryService:
             # answer column: it travels via ServeStats.exec_stats only
             results.pop("__stats__", None)
             jax.block_until_ready(list(results.values()))
-        self._count_program(counts.bits, ex.joins, ex.join_sizes)
+        self._count_program(counts.bits, ex.joins, ex.join_sizes, [u.plan])
         self._finish_unit(u, results, exec_hit=False, bucket=(),
                           compile_s=0.0, run_s=rsp.duration_s,
                           exec_source="eager")

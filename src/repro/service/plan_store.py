@@ -17,8 +17,10 @@ differently-configured services share a ``cache_dir`` without collisions)::
 Each entry is a JSON document with a header the loader verifies before
 trusting the body:
 
-* ``format_version``     — bumped whenever the payload schema changes; a
-  mismatched entry is skipped (and evicted), never mis-parsed;
+* ``format_version``     — bumped whenever the payload schema changes, or
+  the planner's policy changes the plan it would build; a mismatched
+  entry is skipped (and evicted) and its query re-planned, never
+  mis-parsed;
 * ``schema_fingerprint`` — structural hash of the database schema the plan
   was built against (relations, column metadata, FK edges).  A store warmed
   against one schema can never serve plans into a service with another;
@@ -58,7 +60,10 @@ from repro.core.plan import (
 )
 from repro.tables.table import Schema
 
-FORMAT_VERSION = 1
+# 2: the statistics may move a query's root off classify's guard
+# (``repro.core.rewrite``'s ``reroot_guard``); plans stored under 1 were
+# rooted at the first guard, whatever their joins cost.
+FORMAT_VERSION = 2
 
 
 def schema_fingerprint(schema: Schema) -> str:

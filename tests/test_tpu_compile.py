@@ -132,3 +132,56 @@ def test_xla_freq_join_with_64_bit_counts_compiles(one_chip):
     assert not re.findall(r"^.*= s64\[[^\n]*\bscatter\(", text, re.M)
     paths = set(scope_table(text).values())
     assert {"freq_join/scatter", "freq_join/gather"} <= paths
+
+
+def test_fused_walks_keep_their_scatter_sorts_in_the_freq_join(one_chip):
+    """walk2 fused with walk3 rooted at its middle atom, at the widths of
+    Graph500 SCALE 22 (2^26 edges over 2^22 vertices): the freq-joins
+    call the kernel with one signature, yet every instruction keeps the
+    operator and kernel scopes of its call site.
+    The index sorts the TPU compiler emits before each colliding
+    scatter-add read as ``freq_join/freq_join/scatter``, so the device
+    trace counts them as freq-join time, and the shared join still
+    scatters once."""
+    import numpy as np
+
+    from repro.core import Executor, parse_sql, plan_query
+    from repro.core.rewrite import rerooted
+    from repro.core.scopes import scope_table
+    from repro.core.stats import StatsCatalog, count_plan, widest
+    from repro.tables.table import ColumnMeta, RelSchema, Schema, Table
+
+    VERTICES, EDGES = 1 << 22, 1 << 26
+    schema = Schema(relations={"edge": RelSchema("edge", (
+        ColumnMeta("src", domain=VERTICES),
+        ColumnMeta("dst", domain=VERTICES)))}, foreign_keys=())
+    # statistics from a hub of 2^16 self-loops: walk3's counts need 64 bits
+    rng = np.random.default_rng(17)
+    cols = [np.concatenate([np.zeros(1 << 16, np.int32),
+                            rng.integers(0, VERTICES, 4096, dtype=np.int32)])
+            for _ in range(2)]
+    db = {"edge": Table.from_numpy(dict(zip(("src", "dst"), cols)))}
+    cat = StatsCatalog(schema)
+    cat.refresh("edge", db["edge"], db)
+    plans = [plan_query(parse_sql(sql, schema), schema, mode="opt_plus",
+                        stats=cat) for sql in (
+        "SELECT COUNT(*) FROM edge e0, edge e1 WHERE e0.dst = e1.src",
+        "SELECT COUNT(*) FROM edge e0, edge e1, edge e2 "
+        "WHERE e0.dst = e1.src AND e1.dst = e2.src")]
+    assert [rerooted(p) for p in plans] == [False, True]
+    counts = widest([count_plan(p, cat.tables()) for p in plans])
+    fn = Executor(db, schema).compile_multi(plans, counts=counts)
+    spec = {"edge": jax.tree.map(
+        lambda a: _shape(EDGES, a.dtype, one_chip), db["edge"])}
+    with jax.enable_x64(True):
+        text = fn.lower(spec).compile().as_text()
+    table = scope_table(text)
+    names = {op: re.findall(
+        rf"^\s+(?:ROOT )?%([^\s=]+) = [^\n]*\b{op}\(", text, re.M)
+        for op in ("sort", "scatter")}
+    assert len(names["scatter"]) == 2 and len(names["sort"]) == 2
+    assert {table.get(n) for n in names["sort"]} \
+        == {"freq_join/freq_join/scatter"}
+    # no instruction falls outside the operator that runs it
+    operators = {"scan", "freq_join", "final_agg"}
+    assert {p.split("/")[0] for p in table.values()} <= operators
