@@ -467,60 +467,6 @@ def warm_restart_example():
           f"(plans served from {cache_dir})")
 
 
-def tuning_example():
-    """Tuning the kernels: measured search, persisted beside the plans.
-
-    The three physical kernels (freq_join / semi_join / segment_sum) have
-    tuning knobs — pallas block shapes and the XLA dense-domain dispatch
-    crossover.  ``svc.autotune()`` runs a measured search per (kernel,
-    shape bucket, backend): every candidate is timed on synthetic inputs
-    shaped like the service's buckets and GATED on bitwise equality with
-    the untuned answer, so tuning can change speed but never results.
-    Winners land in ``cache_dir/tune/<topology>/`` with the plan store's
-    discipline (format-versioned, sha256-checksummed, atomic writes,
-    corrupt entries evicted, read-only disks degrade to in-memory):
-    one JSON entry per (kernel, shape bucket, backend) holding the
-    winning ``KernelConfig`` and its measurements.  Entries key off the
-    SAME power-of-two buckets as the plan cache — growth inside a bucket
-    retunes nothing; a ``format_version`` bump or topology change orphans
-    old entries rather than mis-reading them.  A restarted service loads
-    the winners from disk: ``tune_searches == 0``, the tuning twin of
-    ``plan_builds == 0``.  ``export_cache``/``import_cache`` ship them
-    with the plans.
-    """
-    import tempfile
-
-    from repro.service import QueryService
-
-    db, schema = make_tpch_db(scale=500, seed=0)
-    cache_dir = tempfile.mkdtemp(prefix="repro-tune-cache-")
-    sql = """
-        SELECT SUM(ps.ps_supplycost), COUNT(*)
-        FROM partsupp ps, part p
-        WHERE ps.ps_partkey = p.p_partkey AND p.p_price > 1500.0
-    """
-
-    svc = QueryService(db, schema, cache_dir=cache_dir)
-    before = svc.submit(sql)
-    report = svc.autotune()               # offline: seconds, not request-path
-    print(f"\n[tuning] cold search: buckets={report['buckets']} "
-          f"searches={report['searches']} installed={report['installed']} "
-          f"gate_rejects={report['gate_rejects']}")
-    after = svc.submit(sql)               # re-traced with tuned configs
-    same = all(float(after.values[k]) == float(before.values[k])
-               for k in before.values)
-    print(f"[tuning] answers identical post-tune: {same}")
-
-    # restart: winners come back from disk, nothing is re-measured
-    svc2 = QueryService(db, schema, cache_dir=cache_dir)
-    report2 = svc2.autotune()
-    m = svc2.metrics()
-    print(f"[tuning] warm restart: searches={report2['searches']} "
-          f"tune_searches={m['tune_searches']} "
-          f"tune_store_hits={m['tune_store_hits']} "
-          f"(configs served from {cache_dir}/tune)")
-
-
 def mesh_serving_example():
     """Serving beyond one device: the same service, sharded over a mesh.
 
@@ -602,5 +548,4 @@ if __name__ == "__main__":
     multi_tenant_example()
     observability_example()
     warm_restart_example()
-    tuning_example()
     mesh_serving_example()

@@ -130,35 +130,6 @@ def _shard_map_discipline(paths: list[str]) -> int:
     return 1 if failures else 0
 
 
-def _block_shape_discipline(paths: list[str]) -> int:
-    """Forbid kernel block-shape constants (``PARENT_BLOCK_ROWS``,
-    ``CHILD_BLOCK_ROWS``, ``LANES_WIDE``, ``LANES``) outside
-    ``src/repro/kernels/``.  Block shapes are tuning parameters owned by
-    the autotuner (``kernels/autotune.py``): a caller that hard-codes one
-    silently pins a shape the measured search would otherwise pick, and
-    within-bucket zero-recompile guarantees break when two layers disagree
-    about padding granularity.  Callers pass a ``KernelConfig`` (or None
-    for the tuned/default dispatch) instead.  Tests are exempt (they pin
-    configs on purpose to exercise the parametrisation).  Always runs,
-    even when ruff/pyflakes handle the general lint."""
-    failures = 0
-    pat = re.compile(r"\b(PARENT_BLOCK_ROWS|CHILD_BLOCK_ROWS|"
-                     r"LANES_WIDE|LANES)\b")
-    for f in _py_files(paths):
-        parts = f.parts
-        if "tests" in parts or f.name == "lint.py":
-            continue
-        if "kernels" in parts and "repro" in parts:
-            continue
-        for ln, line in enumerate(f.read_text().splitlines(), start=1):
-            if pat.search(line.split("#")[0]):
-                print(f"{f}:{ln}: kernel block-shape constant outside "
-                      "src/repro/kernels/ — block shapes belong to the "
-                      "autotuner; pass a KernelConfig instead")
-                failures += 1
-    return 1 if failures else 0
-
-
 def _stats_threshold_discipline(paths: list[str]) -> int:
     """Forbid cardinality/selectivity policy constants outside
     ``src/repro/core/stats.py``.  Cost-calibrated planning has ONE home
@@ -257,7 +228,6 @@ def main(argv: list[str]) -> int:
     paths = argv or [p for p in DEFAULT_PATHS if pathlib.Path(p).exists()]
     clock_rc = _clock_discipline(paths)
     shard_rc = _shard_map_discipline(paths)
-    block_rc = _block_shape_discipline(paths)
     stats_rc = _stats_threshold_discipline(paths)
     future_rc = _future_resolution_discipline(paths)
     rc = _external(["ruff", "check"], paths)
@@ -265,7 +235,7 @@ def main(argv: list[str]) -> int:
         rc = _external(["pyflakes"], paths)
     if rc is None:
         rc = _builtin_lint(paths)
-    rc = rc or clock_rc or shard_rc or block_rc or stats_rc or future_rc
+    rc = rc or clock_rc or shard_rc or stats_rc or future_rc
     print("lint: OK" if rc == 0 else "lint: FAIL")
     return rc
 

@@ -52,20 +52,16 @@ python scripts/lint.py
 
 if [[ "${1:-}" == "--smoke" ]]; then
   # keep the previous trajectory around for the advisory perf diff
-  for f in BENCH_serving.json BENCH_tuning.json; do
+  for f in BENCH_serving.json; do
     [[ -f "$f" ]] && cp "$f" "$f.prev"
   done
   echo "== smoke: fused + mixed + async + restart + tracing + mesh + tenant gates =="
   python benchmarks/serving_queries.py --smoke --record BENCH_serving.json
   echo "== smoke: BENCH_serving.json schema check =="
   python -m benchmarks.recorder BENCH_serving.json
-  echo "== smoke: kernel autotuning gates (bitwise + warm restart) =="
-  python benchmarks/kernel_tuning.py --smoke --record BENCH_tuning.json
-  echo "== smoke: BENCH_tuning.json schema check =="
-  python -m benchmarks.recorder BENCH_tuning.json
   # advisory perf diff vs the previous run: printed, never fails the
   # build (single-run timings on a shared box are noisy)
-  for f in BENCH_serving.json BENCH_tuning.json; do
+  for f in BENCH_serving.json; do
     if [[ -f "$f.prev" ]]; then
       echo "== smoke: advisory perf diff $f.prev -> $f =="
       python benchmarks/report.py --compare "$f.prev" "$f" || true

@@ -7,7 +7,8 @@ operator) as the analytics layer of a multi-pod LM training/serving stack.
 Layers:
   repro.core        — the paper: query IR, join trees, 0MA, rewrites, executor
   repro.tables      — fixed-shape columnar substrate
-  repro.kernels     — Pallas TPU kernels (+ XLA twins + jnp oracles)
+  repro.kernels     — the freq-join, semi-join and segment-sum kernels (XLA)
+                      and their O(N·M) oracles
   repro.models      — LM zoo for the 10 assigned architectures
   repro.training    — optimizer / microbatching / remat / losses
   repro.service     — SQL serving tier: fingerprints, plan cache, QueryService

@@ -67,7 +67,6 @@ from repro.core.plan import (
     MaterializeJoinOp,
     PhysicalPlan,
     PlanNode,
-    PlanningError,
     ScanOp,
     SemiJoinOp,
 )
@@ -129,31 +128,23 @@ class _State:
 
 class Executor:
     def __init__(self, db: dict[str, Table], schema: Schema,
-                 backend: str = "xla", oom_guard: int | None = None,
-                 tuning=None):
+                 oom_guard: int | None = None):
         self.db = db
         self.schema = schema
-        self.backend = backend
         # the frequencies' dtype while tracing or executing (count_bits)
         self.count_dtype = jnp.int32
         self.oom_guard = oom_guard
-        # join kernel calls by path ("dense", "sorted", "pallas")
+        # join kernel calls by path ("dense", "sorted")
         self.joins: collections.Counter = collections.Counter()
         # freq-join kernel calls' rows and dense slots, by the counts'
         # width: "rows<w>" (child + parent), "parent_rows<w>", "slots<w>"
         self.join_sizes: collections.Counter = collections.Counter()
-        # tuned kernel configs (repro.kernels.autotune.TuneTable, or None
-        # for untuned defaults): looked up at trace time by the concrete
-        # kernel input sizes — already bucket-padded on the serving path,
-        # so the lookup lands on the bucket the entry was tuned at
-        self.tuning = tuning
 
     def jittable(self) -> "Executor":
         """Copy with eager-only options stripped — the configuration
         ``compile()`` accepts.  Use when one benchmark harness drives both
         guarded eager baselines and jitted plans."""
-        return Executor(self.db, self.schema, self.backend,
-                        oom_guard=None, tuning=self.tuning)
+        return Executor(self.db, self.schema, oom_guard=None)
 
     # ------------------------------------------------------------------
     def _domains(self, plan: PhysicalPlan, alias: str) -> dict[str, int | None]:
@@ -188,32 +179,18 @@ class Executor:
                 domain *= d
         return key, domain
 
-    def _tune_cfg(self, kernel: str, *sizes: int):
-        """Tuned config for one kernel call (None → untuned defaults).
-        Sizes are the concrete trace-time array lengths, which on the
-        serving path are already padded to their shape bucket — so the
-        table lookup hits exactly the bucket ``autotune()`` measured."""
-        if self.tuning is None:
-            return None
-        return self.tuning.lookup(kernel, sizes, self.backend)
-
-    def _join_path(self, kernel: str, n_parent: int, n_child: int,
-                   domain: int | None):
-        """(tuned config, path) of one join kernel call, the path tallied."""
-        cfg = self._tune_cfg(kernel, n_parent, n_child)
-        path = kops.join_path(domain, n_child, backend=self.backend,
-                              config=cfg)
+    def _join_path(self, n_child: int, domain: int | None) -> str:
+        """The path of one join kernel call, tallied."""
+        path = kops.join_path(domain, n_child)
         self.joins[path] += 1
-        return cfg, path
+        return path
 
     def _semi_join(self, plan: PhysicalPlan, op: SemiJoinOp,
                    p: _State, c: _State) -> _State:
         pk, _pd = self._key(plan, op.parent, p, op.on_vars)
         ck, cdom = self._key(plan, op.child, c, op.on_vars)
-        cfg, _path = self._join_path("semi_join", pk.shape[0], ck.shape[0],
-                                     cdom)
-        freq = kops.semi_join(pk, p.freq, ck, c.freq, backend=self.backend,
-                              domain=cdom, config=cfg)
+        self._join_path(ck.shape[0], cdom)
+        freq = kops.semi_join(pk, p.freq, ck, c.freq, domain=cdom)
         return _State(p.cols, freq)
 
     def _freq_join(self, plan: PhysicalPlan, op: FreqJoinOp,
@@ -221,8 +198,7 @@ class Executor:
                    limbs: tuple[int, int] | None = None) -> _State:
         pk, _pd = self._key(plan, op.parent, p, op.on_vars)
         ck, cdom = self._key(plan, op.child, c, op.on_vars)
-        cfg, path = self._join_path("freq_join", pk.shape[0], ck.shape[0],
-                                    cdom)
+        path = self._join_path(ck.shape[0], cdom)
         # bytes the call must move, by the counts' width: both key
         # columns, the child's and the parent's frequencies, and the dense
         # path's accumulator
@@ -234,25 +210,17 @@ class Executor:
         cf = c.freq
         if op.pregroup and path != "dense":
             with jax.named_scope("pregroup"):
-                ck, cf, _valid = kops.group_by_sum(
-                    ck, cf, backend=self.backend,
-                    config=self._tune_cfg("segment_sum", ck.shape[0]))
-        freq = kops.freq_join(pk, p.freq, ck, cf, backend=self.backend,
-                              domain=cdom, config=cfg, limbs=limbs)
+                ck, cf, _valid = kops.group_by_sum(ck, cf)
+        freq = kops.freq_join(pk, p.freq, ck, cf, domain=cdom, limbs=limbs)
         return _State(p.cols, freq)
 
     # ------------------------------------------------------------------
     def _counts(self, plans: list[PhysicalPlan],
                 counts: Counts | None) -> Counts:
         """``counts``, or where the caller gives none the narrowest its
-        own tables allow (``db_counts``); refused where the join kernels
-        cannot hold them."""
+        own tables allow (``db_counts``)."""
         if counts is None:
             counts = db_counts(plans, self.db, self.schema)
-        if counts.bits != 32 and self.backend != "xla":
-            raise PlanningError(
-                f"the {self.backend} join kernels hold 32-bit counts; this "
-                f"query needs {counts.bits}-bit counts")
         return counts
 
     def execute(self, plan: PhysicalPlan, stats: ExecStats | None = None,
@@ -418,7 +386,7 @@ class Executor:
         semi/freq joins are ring sweeps over the mesh); the traversal
         itself — content-key memoisation, sub-DAG dedup, multi-plan fusion
         — is shared and lives only in ``_trace_plan``."""
-        ex = Executor(db, self.schema, self.backend, tuning=self.tuning)
+        ex = Executor(db, self.schema)
         ex.count_dtype = COUNT_DTYPES[count_bits]
         return ex
 

@@ -1,19 +1,12 @@
-"""Pallas TPU kernels for the paper's physical operators.
+"""The paper's physical operators as XLA kernels.
 
-  freq_join.py   — FreqJoin (paper §5): blocked broadcast-compare sum-product
-  semi_join.py   — Boolean-semiring specialisation (0MA sweep, §4.1)
-  segment_sum.py — sorted group-by-SUM (frequency pre-grouping, §4.2/§4.3)
-  ops.py         — public wrappers, padding, XLA twins, config dispatch
-  autotune.py    — measured block/dispatch search per shape bucket
-  ref.py         — pure-jnp O(N·M) oracles (ground truth for tests)
+  ops.py — freq-join (paper §5) with its dense or sorted path chosen by
+           ``join_path``, semi-join (0MA sweep, §4.1), sorted
+           group-by-SUM (frequency pre-grouping, §4.2/§4.3), weighted
+           percentile
+  ref.py — pure-jnp O(N·M) oracles (ground truth for tests)
 """
 
-from repro.kernels.autotune import (
-    DEFAULT_CONFIG,
-    KernelConfig,
-    KernelTuner,
-    TuneTable,
-)
 from repro.kernels.ops import (
     freq_join,
     group_by_sum,
@@ -23,10 +16,6 @@ from repro.kernels.ops import (
 )
 
 __all__ = [
-    "DEFAULT_CONFIG",
-    "KernelConfig",
-    "KernelTuner",
-    "TuneTable",
     "freq_join",
     "group_by_sum",
     "segment_sum_sorted",

@@ -1,35 +1,26 @@
-"""Public kernel ops: padding, backend dispatch, XLA twins.
+"""The kernel ops: freq-join, semi-join, segment sum, weighted percentile.
 
-Every physical operator has two interchangeable backends:
+Each op is an XLA formulation (scatter-add/gather, sort/searchsorted,
+segment-sum), the same on every platform, and what the distributed
+executor traces through `shard_map` (collectives compose with XLA ops).
+All are tested against the O(N·M) oracles in ref.py.
 
-  * ``"pallas"`` — the TPU kernels in freq_join.py / semi_join.py /
-    segment_sum.py — compiled by Mosaic on a TPU, and run through the
-    Pallas interpreter on any other platform (``interpret_mode``), which
-    is how the CPU tests validate them;
-  * ``"xla"``    — algorithmically equivalent sort/searchsorted/segment-sum
-    formulations lowered by XLA; these are what the CPU benchmarks time and
-    what the distributed executor traces through `shard_map` (collectives
-    compose with XLA ops on every backend).
+A join takes one of two paths, and ``join_path(domain, n_child)`` alone
+decides which: the dense path (one scatter-add into a domain-sized
+accumulator, one gather) where the keys' declared domain is small enough
+for the child, else the sorted path (argsort and two binary searches).
+The kernel's dispatch asks it, and so does the executor's tally, so a
+tally counts what runs.
 
-Both are tested against the O(N·M) oracles in ref.py.
-
-Dispatch parameters — pallas block shapes and the XLA dense-domain
-crossover — come from a ``KernelConfig`` (``kernels/autotune.py``);
-``config=None`` means the untuned ``DEFAULT_CONFIG``.  The serving tier
-threads tuned configs per shape bucket through ``Executor``; standalone
-callers can pass one explicitly.
-
-The public entry points are deliberately NOT jitted: they resolve the
-backend (``REPRO_KERNEL_BACKEND`` is re-read on EVERY call, so flipping
-the env var between calls takes effect even for already-traced shapes)
-and the config, then dispatch to jitted implementations that carry both
-as static arguments.  Under an outer ``jax.jit`` trace the wrappers
-inline like any other Python, so compiled plans pay nothing for the
-indirection.  The jitted implementations inline too (``inline=True``):
-a program that calls a kernel from several sites with one signature
-would otherwise call one shared function, whose instructions lose the
-call sites' scopes on their way through XLA, so a device trace could not
-name the operator behind them.
+The public entry points are NOT jitted: they call jitted
+implementations that carry the join's mode, domain and limbs as static
+arguments.  Under an outer ``jax.jit`` trace the wrappers inline like any
+other Python, so compiled plans pay nothing for the indirection.  The
+jitted implementations inline too (``inline=True``): a program that calls
+a kernel from several sites with one signature would otherwise call one
+shared function, whose instructions lose the call sites' scopes on their
+way through XLA, so a device trace could not name the operator behind
+them.
 
 Each public entry point opens a ``jax.named_scope`` of its own name
 (``freq_join``, ``semi_join``, ``segment_sum``, ``group_by_sum``,
@@ -44,74 +35,51 @@ operation (``repro.core.scopes``).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import freq_join as _fj
-from repro.kernels import segment_sum as _ss
-from repro.kernels import semi_join as _sj
-from repro.kernels.autotune import DEFAULT_CONFIG, KernelConfig
-
-
-def default_backend() -> str:
-    return os.environ.get("REPRO_KERNEL_BACKEND", "xla")
-
-
-def interpret_mode() -> bool:
-    """Whether Pallas kernels run through the interpreter: never on a TPU,
-    always elsewhere (the interpreter is how the CPU tests run them).
-    Derived from the platform here and nowhere else, so no caller can run
-    kernel bodies through the interpreter on the chip."""
-    return jax.default_backend() != "tpu"
-
-
-def _round_up(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
-
-
-def _pad1(a: jax.Array, n: int, fill) -> jax.Array:
-    if a.shape[0] == n:
-        return a
-    return jnp.concatenate([a, jnp.full((n - a.shape[0],), fill, a.dtype)])
+# the dense path's crossover: a declared domain of at most
+# max(DENSE_RATIO · child rows, DENSE_FLOOR) slots goes dense (on a TPU
+# v5e, TPC-H SF10's 2^23 partsupp rows into 100,001 supplier slots take
+# 56.4 ms dense against ~1.1 s sorted; PERF.md) ...
+DENSE_RATIO = 4
+DENSE_FLOOR = 1 << 20
+# ... and never past what int32 packed keys can index
+DENSE_DOMAIN_CAP = 1 << 31
 
 
 # --------------------------------------------------------------------------
 # FreqJoin
 # --------------------------------------------------------------------------
-def join_path(domain: int | None, n_child: int, *, backend: str | None = None,
-              config: KernelConfig | None = None) -> str:
+def join_path(domain: int | None, n_child: int) -> str:
     """The path ``freq_join``/``semi_join`` take for a child of ``n_child``
     rows whose packed keys lie in ``[0, domain)`` (None: unknown):
-    ``"dense"`` (one scatter-add, one gather), ``"sorted"`` (argsort and
-    two binary searches) or ``"pallas"``.  The dispatch itself asks this,
-    so a caller that tallies paths counts what runs."""
-    if (backend or default_backend()) != "xla":
-        return "pallas"
-    if (config or DEFAULT_CONFIG).dense_ok(domain, n_child):
+    ``"dense"`` (one scatter-add, one gather) where the domain is declared,
+    at most ``max(DENSE_RATIO · n_child, DENSE_FLOOR)`` and below
+    ``DENSE_DOMAIN_CAP``; ``"sorted"`` (argsort and two binary searches)
+    otherwise."""
+    if (domain is not None
+            and domain <= max(DENSE_RATIO * n_child, DENSE_FLOOR)
+            and domain < DENSE_DOMAIN_CAP):
         return "dense"
     return "sorted"
 
 
 def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
-              mode: str = "sum", backend: str | None = None,
-              domain: int | None = None,
-              config: KernelConfig | None = None,
+              mode: str = "sum", domain: int | None = None,
               limbs: tuple[int, int] | None = None):
     """R ⋉^freq S — returns updated parent frequencies (paper §5).
 
     mode="sum": ℕ-semiring (COUNT/SUM propagation);
     mode="any": Boolean semiring (semi-join).
 
-    `domain` (beyond-paper, EXPERIMENTS §Perf): the packed join keys'
-    declared domain, None when unknown.  Where it is dense enough for the
-    child (``join_path``), the sort+searchsorted pipeline collapses to one
-    scatter-add into a domain-sized accumulator plus one gather — O(N)
-    instead of O(N log N), and on TPU the exact memory pattern of an
-    embedding-gradient update.  Keys outside the domain contribute
-    nothing.  Otherwise it sorts; the crossover comes from ``config``
-    (``dense_ratio``/``dense_floor``).
+    `domain`: the packed join keys' declared domain, None when unknown.
+    Where ``join_path`` says dense, the sort+searchsorted pipeline
+    collapses to one scatter-add into a domain-sized accumulator plus one
+    gather — O(N) instead of O(N log N), and on TPU the exact memory
+    pattern of an embedding-gradient update.  Keys outside the domain
+    contribute nothing.  Otherwise it sorts.
 
     64-bit frequencies: XLA's s64 scatter-add and gather cost 11.9× and
     2.7× their s32 twins on a v5e (PERF.md), so the dense path
@@ -123,18 +91,9 @@ def freq_join(parent_keys, parent_freq, child_keys, child_freq, *,
     ``limbs`` are refused.
     """
     with jax.named_scope("freq_join"):
-        return _freq_join_call(parent_keys, parent_freq, child_keys,
-                               child_freq, mode=mode, backend=backend,
-                               domain=domain, config=config, limbs=limbs)
-
-
-def _freq_join_call(parent_keys, parent_freq, child_keys, child_freq, *,
-                    mode, backend, domain, config, limbs=None):
-    return _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq,
-                           mode=mode, backend=backend or default_backend(),
-                           interpret=interpret_mode(),
-                           domain=domain, config=config or DEFAULT_CONFIG,
-                           limbs=limbs)
+        return _freq_join_impl(parent_keys, parent_freq, child_keys,
+                               child_freq, mode=mode, domain=domain,
+                               limbs=limbs)
 
 
 def _scatter_gather(parent_keys, child_keys, values, domain: int):
@@ -158,131 +117,92 @@ def _scatter_gather(parent_keys, child_keys, values, domain: int):
 
 
 @functools.partial(jax.jit, inline=True,
-                   static_argnames=("mode", "backend", "interpret", "domain",
-                                    "config", "limbs"))
+                   static_argnames=("mode", "domain", "limbs"))
 def _freq_join_impl(parent_keys, parent_freq, child_keys, child_freq, *,
-                    mode: str, backend: str, interpret: bool,
-                    domain: int | None, config: KernelConfig,
+                    mode: str, domain: int | None,
                     limbs: tuple[int, int] | None = None):
-    if backend == "xla":
-        nc = child_keys.shape[0]
-        if join_path(domain, nc, backend=backend, config=config) == "dense":
-            dt = parent_freq.dtype
-            if mode == "any":
-                # a count of live child rows, below 2^31 at any width
-                mult = _scatter_gather(parent_keys, child_keys,
-                                       (child_freq > 0).astype(jnp.int32),
-                                       domain)
-                return parent_freq * (mult > 0).astype(dt)
-            if dt.itemsize <= 4:
-                mult = _scatter_gather(parent_keys, child_keys, child_freq,
-                                       domain)
-                return parent_freq * mult.astype(dt)
-            if limbs is None:
-                raise ValueError(
-                    "64-bit frequencies on the dense path need their limb "
-                    "split (repro.core.stats.limb_split)")
-            bits, n = limbs
-            mask = (1 << bits) - 1
-            mult = jnp.zeros(parent_freq.shape, dt)
-            for i in range(n):
-                limb = ((child_freq >> (bits * i)) & mask).astype(jnp.uint32)
-                part = _scatter_gather(parent_keys, child_keys, limb, domain)
-                mult = mult + (part.astype(dt) << (bits * i))
-            return parent_freq * mult
-        with jax.named_scope("sort"):
-            order = jnp.argsort(child_keys)
-            ck = child_keys[order]
-            cf = child_freq[order]
+    if join_path(domain, child_keys.shape[0]) == "dense":
+        dt = parent_freq.dtype
         if mode == "any":
-            cf = (cf > 0).astype(parent_freq.dtype)
-        zero = jnp.zeros((1,), cf.dtype)
-        prefix = jnp.concatenate([zero, jnp.cumsum(cf)])
-        with jax.named_scope("search"):
-            lo = jnp.searchsorted(ck, parent_keys, side="left")
-            hi = jnp.searchsorted(ck, parent_keys, side="right")
-        mult = (prefix[hi] - prefix[lo]).astype(parent_freq.dtype)
-        if mode == "any":
-            mult = (mult > 0).astype(parent_freq.dtype)
+            # a count of live child rows, below 2^31 at any width
+            mult = _scatter_gather(parent_keys, child_keys,
+                                   (child_freq > 0).astype(jnp.int32),
+                                   domain)
+            return parent_freq * (mult > 0).astype(dt)
+        if dt.itemsize <= 4:
+            mult = _scatter_gather(parent_keys, child_keys, child_freq,
+                                   domain)
+            return parent_freq * mult.astype(dt)
+        if limbs is None:
+            raise ValueError(
+                "64-bit frequencies on the dense path need their limb "
+                "split (repro.core.stats.limb_split)")
+        bits, n = limbs
+        mask = (1 << bits) - 1
+        mult = jnp.zeros(parent_freq.shape, dt)
+        for i in range(n):
+            limb = ((child_freq >> (bits * i)) & mask).astype(jnp.uint32)
+            part = _scatter_gather(parent_keys, child_keys, limb, domain)
+            mult = mult + (part.astype(dt) << (bits * i))
         return parent_freq * mult
-
-    np_, nc = parent_keys.shape[0], child_keys.shape[0]
-    ppad = config.parent_block_rows * _fj.LANES
-    cpad = config.child_block_rows * _fj.LANES
-    npp, ncp = _round_up(np_, ppad), _round_up(nc, cpad)
-    pk = _pad1(parent_keys, npp, 0)
-    pf = _pad1(parent_freq, npp, 0)
-    ck = _pad1(child_keys, ncp, 0)
-    cf = _pad1(child_freq, ncp, 0)  # freq-0 padding contributes nothing
-    fn = _sj.semi_join_pallas if mode == "any" else functools.partial(
-        _fj.freq_join_pallas, mode=mode)
-    out = fn(pk, pf, ck, cf, interpret=interpret,
-             parent_block_rows=config.parent_block_rows,
-             child_block_rows=config.child_block_rows)
-    return out[:np_]
+    with jax.named_scope("sort"):
+        order = jnp.argsort(child_keys)
+        ck = child_keys[order]
+        cf = child_freq[order]
+    if mode == "any":
+        cf = (cf > 0).astype(parent_freq.dtype)
+    zero = jnp.zeros((1,), cf.dtype)
+    prefix = jnp.concatenate([zero, jnp.cumsum(cf)])
+    with jax.named_scope("search"):
+        lo = jnp.searchsorted(ck, parent_keys, side="left")
+        hi = jnp.searchsorted(ck, parent_keys, side="right")
+    mult = (prefix[hi] - prefix[lo]).astype(parent_freq.dtype)
+    if mode == "any":
+        mult = (mult > 0).astype(parent_freq.dtype)
+    return parent_freq * mult
 
 
 def semi_join(parent_keys, parent_freq, child_keys, child_freq, *,
-              backend: str | None = None, domain: int | None = None,
-              config: KernelConfig | None = None):
+              domain: int | None = None):
     """R ⋉ S over live tuples (0MA sweep step, paper §4.1)."""
     with jax.named_scope("semi_join"):
-        return _freq_join_call(parent_keys, parent_freq, child_keys,
-                               child_freq, mode="any", backend=backend,
-                               domain=domain, config=config)
+        return _freq_join_impl(parent_keys, parent_freq, child_keys,
+                               child_freq, mode="any", domain=domain)
 
 
 # --------------------------------------------------------------------------
 # Segment sum (sorted group-by-SUM)
 # --------------------------------------------------------------------------
-def segment_sum_sorted(sorted_keys, values, *, backend: str | None = None,
-                       config: KernelConfig | None = None):
+def segment_sum_sorted(sorted_keys, values):
     """GROUP BY key, SUM(value) over key-sorted input.
 
     Returns (sums, valid): run total at the LAST row of each run.
     """
     with jax.named_scope("segment_sum"):
-        return _segment_sum_impl(sorted_keys, values,
-                                 backend=backend or default_backend(),
-                                 interpret=interpret_mode(),
-                                 config=config or DEFAULT_CONFIG)
+        return _segment_sum_impl(sorted_keys, values)
 
 
-@functools.partial(jax.jit, inline=True,
-                   static_argnames=("backend", "interpret", "config"))
-def _segment_sum_impl(sorted_keys, values, *, backend: str, interpret: bool,
-                      config: KernelConfig):
+@functools.partial(jax.jit, inline=True)
+def _segment_sum_impl(sorted_keys, values):
     n = sorted_keys.shape[0]
-    if backend == "xla":
-        is_first = jnp.concatenate(
-            [jnp.ones((1,), bool), sorted_keys[1:] != sorted_keys[:-1]])
-        is_last = jnp.concatenate(
-            [sorted_keys[1:] != sorted_keys[:-1], jnp.ones((1,), bool)])
-        run_id = jnp.cumsum(is_first.astype(jnp.int32)) - 1
-        sums = jax.ops.segment_sum(values, run_id, num_segments=n)
-        out = jnp.where(is_last, jnp.take(sums, run_id), jnp.zeros((), values.dtype))
-        return out, is_last
-
-    npad = _round_up(n, config.lanes_wide)
-    # padded keys must sort last: use max-representable key
-    maxk = jnp.asarray(jnp.iinfo(sorted_keys.dtype).max, sorted_keys.dtype)
-    ks = _pad1(sorted_keys, npad, maxk)
-    vs = _pad1(values, npad, 0)
-    out, valid = _ss.segment_sum_pallas(ks, vs, interpret=interpret,
-                                        lanes_wide=config.lanes_wide)
-    return out[:n], valid[:n]
+    is_first = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_keys[1:] != sorted_keys[:-1]])
+    is_last = jnp.concatenate(
+        [sorted_keys[1:] != sorted_keys[:-1], jnp.ones((1,), bool)])
+    run_id = jnp.cumsum(is_first.astype(jnp.int32)) - 1
+    sums = jax.ops.segment_sum(values, run_id, num_segments=n)
+    out = jnp.where(is_last, jnp.take(sums, run_id), jnp.zeros((), values.dtype))
+    return out, is_last
 
 
-def group_by_sum(keys, values, *, backend: str | None = None,
-                 config: KernelConfig | None = None):
+def group_by_sum(keys, values):
     """Unsorted group-by: sort once, then segment-sum.  Returns
     (sorted_keys, sums, valid) so downstream FreqJoins can reuse the sort."""
     with jax.named_scope("group_by_sum"):
         order = jnp.argsort(keys)
         ks = keys[order]
         vs = values[order]
-        sums, valid = segment_sum_sorted(ks, vs, backend=backend,
-                                         config=config)
+        sums, valid = segment_sum_sorted(ks, vs)
         return ks, sums, valid
 
 

@@ -1,8 +1,7 @@
 """Pure-jnp oracles for every kernel in repro.kernels.
 
 These are the semantic ground truth: O(N·M) / unvectorised-but-obvious
-implementations that the Pallas kernels (interpret mode) and the XLA twins
-in ops.py are tested against.
+implementations that the kernels in ops.py are tested against.
 """
 
 from __future__ import annotations

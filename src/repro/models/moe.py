@@ -146,14 +146,13 @@ def moe_apply(p, cfg: ModelConfig, x, dtype):
     return out.reshape(b, s, d), aux
 
 
-def load_stats(expert_idx: jax.Array, n_experts: int, backend: str = "xla"):
+def load_stats(expert_idx: jax.Array, n_experts: int):
     """Expert load = `SELECT expert, COUNT(*) GROUP BY expert` over the
     (token→expert) assignment relation — computed with the paper engine's
     segmented-sum machinery (see DESIGN.md §4)."""
     from repro.kernels import ops as kops
     flat = expert_idx.reshape(-1).astype(jnp.int32)
-    keys, sums, valid = kops.group_by_sum(
-        flat, jnp.ones_like(flat), backend=backend)
+    keys, sums, valid = kops.group_by_sum(flat, jnp.ones_like(flat))
     loads = jnp.zeros((n_experts,), jnp.int32)
     loads = loads.at[jnp.where(valid, keys, n_experts)].add(
         jnp.where(valid, sums, 0), mode="drop")

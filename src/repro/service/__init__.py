@@ -6,9 +6,9 @@ program runs": query fingerprinting (``fingerprint``), the multi-level
 plan cache (``plan_cache``), the persistent cross-process plan store
 (``plan_store``), the concurrent micro-batching engine (``engine``), the
 async cross-caller batch former (``scheduler``), the persistent
-tuned-kernel-config store (``tune_store``), the persistent statistics
-store behind cost-calibrated planning (``stats_store``), and the tracing
-+ metrics registry every request reports into (``observability``).
+statistics store behind cost-calibrated planning (``stats_store``), and
+the tracing + metrics registry every request reports into
+(``observability``).
 """
 
 from repro.service.engine import (
@@ -40,7 +40,6 @@ from repro.service.plan_store import (
 )
 from repro.service.scheduler import AsyncScheduler, TenantPolicy
 from repro.service.stats_store import StatsStore
-from repro.service.tune_store import TuneStore
 
 __all__ = [
     "AdmissionError",
@@ -64,7 +63,6 @@ __all__ = [
     "StatsStore",
     "TenantAdmissionError",
     "TenantPolicy",
-    "TuneStore",
     "schema_fingerprint",
     "store_fingerprint",
 ]

@@ -81,18 +81,10 @@ caching and never attach an error to a request.  ``export_cache`` /
 ``import_cache`` move a warm cache between directories (e.g. to seed a
 fleet from one warmed pod).
 
-Kernel autotuning: ``autotune()`` runs a measured config search
-(``repro.kernels.autotune``) per (kernel, shape bucket, backend) over the
-loaded tables' buckets — pallas block shapes, the XLA dense-domain
-dispatch crossover — gating every candidate on bitwise equality with the
-untuned answer, then drops compiled executables so the next serve
-re-traces with the winners.  Tuned configs key off the SAME shape buckets
-as the executable cache, so within-bucket growth never retunes.  With a
-``cache_dir`` the winners persist in a ``TuneStore`` beside the plans
-(same versioned/checksummed/corruption-tolerant discipline) and load at
-construction: a warm-started process reports ``tune_searches == 0`` —
-the tuning analogue of ``plan_builds == 0`` — and ``export_cache`` /
-``import_cache`` ship tuned configs along with the plans.
+Kernels: every program runs the XLA kernels of ``repro.kernels.ops``;
+each join takes the dense or the sorted path that
+``kops.join_path(domain, n_child)`` gives for its declared key domain
+and its child's padded length.  No option of the service changes it.
 
 Serving beyond one device: ``QueryService(db, schema, mesh=...)`` puts
 the whole front door on a device mesh.  The jit executor becomes
@@ -187,7 +179,6 @@ from repro.service.fingerprint import CanonicalQuery, canonicalize
 from repro.service.observability import (DEFAULT_TENANT, NULL_SPAN,
                                          Observability, TraceSpan)
 from repro.service.plan_cache import LRUCache, PlanCache, ShapeBucket
-from repro.kernels.autotune import KernelTuner
 from repro.service.plan_store import (
     PlanStore,
     enable_executable_cache,
@@ -195,7 +186,6 @@ from repro.service.plan_store import (
     store_fingerprint,
 )
 from repro.service.stats_store import STATS_PERSIST_ZEROS, StatsStore
-from repro.service.tune_store import TUNE_PERSIST_ZEROS, TuneStore
 from repro.tables.table import Schema, Table, bucket_capacity
 
 
@@ -335,7 +325,6 @@ class QueryService:
 
     def __init__(self, db: dict[str, Table], schema: Schema, *,
                  mode: str = "auto", use_fkpk: bool = False,
-                 backend: str = "xla",
                  plan_capacity: int = 256, exec_capacity: int = 512,
                  fused_capacity: int = 128, padded_capacity: int = 64,
                  min_bucket: int = 8, async_max_batch: int = 64,
@@ -441,11 +430,10 @@ class QueryService:
             for a, n in zip(*self._topo):
                 self.obs.set_gauge(f"mesh_shard_count_{a}", n)
         else:
-            self._jit_executor = Executor(self._db, schema, backend)
+            self._jit_executor = Executor(self._db, schema)
             self._topo = ()
             self._row_sharding = None
         store = None
-        tune_store = None
         if cache_dir is not None:
             # the store identity covers schema AND planner configuration
             # AND shard topology: plans are planner output, so a store
@@ -458,23 +446,12 @@ class QueryService:
             # executables warm-start through JAX's own persistent
             # compilation cache (process-global; see plan_store docs)
             enable_executable_cache()
-            # tuned kernel configs persist beside the plans, scoped by the
-            # same topology (per-shard buckets tune differently)
-            tune_store = TuneStore(cache_dir, topology=self._topo)
         self.cache = PlanCache(plan_capacity, exec_capacity, fused_capacity,
                                padded_capacity, store=store)
-        # kernel autotuning: the tuner resolves configs table → store →
-        # measured search; a warm start installs every persisted entry NOW
-        # so serving (and ``autotune()``) re-measures nothing
-        # (``tune_searches == 0``).  The executor reads the table at trace
-        # time, so installed configs take effect on the next compile.
-        self.tuner = KernelTuner(tune_store, backend=backend)
-        self.tuner.load_persisted()
-        self._jit_executor.tuning = self.tuner.table
         # cost-calibrated planning: one statistics catalog feeds the gated
         # rewrite passes, the fusion-admission cost gate, and the serve-time
         # feedback loop.  Stats are derived state, so they persist under the
-        # same cache_dir discipline as plans/tunings — scoped by SCHEMA only
+        # same cache_dir discipline as plans — scoped by SCHEMA only
         # (statistics describe the data, not the planner configuration, so
         # every mode/use_fkpk/topology variant shares them).  A warm restart
         # over identical data loads every table from disk and reports
@@ -807,67 +784,6 @@ class QueryService:
         if sch is not None:
             sch.close(timeout=timeout)
 
-    # ---- kernel autotuning ----------------------------------------------
-    @property
-    def tune_store(self) -> TuneStore | None:
-        """The persistent tuned-config store (None without
-        ``cache_dir``)."""
-        return self.tuner.store
-
-    def autotune(self, kernels=("freq_join", "semi_join", "segment_sum"),
-                 *, row: Callable[..., Any] | None = None) -> dict[str, Any]:
-        """Tune the kernels for this service's loaded tables.
-
-        Runs the measured config search for every (kernel, shape-bucket)
-        combination the current tables can produce — join kernels over
-        (parent bucket × child bucket) pairs, the segmented sum per
-        bucket — skipping any combination already resolved by the
-        in-memory table or the persistent store (so a warm-started
-        service measures nothing and this call is cheap to repeat).
-        Every candidate is gated on bitwise equality with the untuned
-        answer inside the search itself; a fresh install then drops the
-        compiled executables so the next serve re-traces with the tuned
-        configs.  ``row`` (a ``Recorder.row``-shaped sink) receives the
-        per-candidate timing trajectory.  Returns a summary dict."""
-        with self._lock:
-            caps = sorted({self._bucket_cap(t.capacity)
-                           for t in self._db.values()})
-        before = self.tuner.metrics()
-        prev_row = self.tuner.row
-        if row is not None:
-            self.tuner.row = row
-        try:
-            for kernel in kernels:
-                if kernel == "segment_sum":
-                    for b in caps:
-                        self.tuner.ensure(kernel, (b,))
-                else:
-                    for bp in caps:
-                        for bc in caps:
-                            self.tuner.ensure(kernel, (bp, bc))
-        finally:
-            self.tuner.row = prev_row
-        after = self.tuner.metrics()
-        installed = after["tune_installs"] - before["tune_installs"]
-        invalidated = 0
-        if installed:
-            # tuned configs are trace-time constants: compiled programs
-            # predate them, so drop the executable levels (plans are
-            # config-free and survive)
-            with self._lock:
-                invalidated = (
-                    self.cache.execs.invalidate_if(lambda k: True)
-                    + self.cache.fused.invalidate_if(lambda k: True))
-        return {
-            "buckets": caps,
-            "searches": after["tune_searches"] - before["tune_searches"],
-            "installed": installed,
-            "gate_rejects": (after["tune_gate_rejects"]
-                             - before["tune_gate_rejects"]),
-            "entries": after["tune_entries"],
-            "invalidated_executables": invalidated,
-        }
-
     # ---- cache persistence ----------------------------------------------
     @property
     def plan_store(self) -> PlanStore | None:
@@ -895,19 +811,6 @@ class QueryService:
             for fp, plan in own.load_all():
                 if fp not in exported and dest.save(fp, plan):
                     exported.add(fp)
-        # tuned kernel configs ship with the plans: everything in the
-        # in-memory table, plus store entries memory never loaded
-        tdest = TuneStore(path, topology=self._topo)
-        tuned = set()
-        for (kernel, shape, backend), cfg in self.tuner.table.entries():
-            if tdest.save(kernel, shape, backend, cfg):
-                tuned.add((kernel, shape, backend))
-        town = self.tuner.store
-        if town is not None \
-                and town.root.resolve() != tdest.root.resolve():
-            for key, cfg in town.load_all():
-                if key not in tuned:
-                    tdest.save(*key, cfg)
         return len(exported)
 
     def import_cache(self, path) -> int:
@@ -928,17 +831,6 @@ class QueryService:
             if write_through:
                 own.save(fp, plan)
             n += 1
-        # tuned kernel configs ride along: install into the live table
-        # (they take effect on the next compile) and write through to our
-        # own store when we have one
-        tsrc = TuneStore(path, topology=self._topo)
-        town = self.tuner.store
-        t_through = town is not None \
-            and town.root.resolve() != tsrc.root.resolve()
-        for (kernel, shape, backend), cfg in tsrc.load_all():
-            self.tuner.table.install(kernel, shape, backend, cfg)
-            if t_through:
-                town.save(kernel, shape, backend, cfg)
         return n
 
     def _serve_batch(self, reqs: list[_Request]) -> dict[int, QueryResult]:
@@ -1502,7 +1394,6 @@ class QueryService:
     def _serve_eager(self, u: _Unit) -> None:
         """Fallback for non-jittable (materialising) plans: serve eagerly
         with the paper's per-step ExecStats attached."""
-        base = self._jit_executor
         roots = [r.trace for r in u.group]
         self.obs.inc("eager_requests", len(u.group))
         with self._lock:
@@ -1513,7 +1404,7 @@ class QueryService:
             sub_db = {rel: self._db[rel] for rel in u.plan.scanned_rels()}
             tables = self.stats.tables()
         counts = self._counts([u], tables)
-        ex = Executor(sub_db, self.schema, base.backend, tuning=base.tuning)
+        ex = Executor(sub_db, self.schema)
         stats = ExecStats()
         with self.obs.span(roots, "run", eager=True,
                            count_bits=counts.bits) as rsp:
@@ -1553,10 +1444,6 @@ class QueryService:
             snap["counters"].update(self.cache.metrics())
             snap["gauges"]["padded_relations"] = len(self.cache.padded)
         snap["counters"].update(self.cache.persist_metrics())
-        snap["counters"].update(self.tuner.metrics())
-        snap["counters"].update(
-            self.tuner.store.metrics() if self.tuner.store is not None
-            else dict(TUNE_PERSIST_ZEROS))
         snap["counters"].update(
             self.stats_store.metrics() if self.stats_store is not None
             else dict(STATS_PERSIST_ZEROS))

@@ -1,9 +1,8 @@
 """Persistent statistics store: warm restarts skip stats recomputation.
 
 Table statistics are *derived* state — a pure function of one table's data
-version — so they persist under the same ``cache_dir`` discipline as plans
-(PR 5) and tuned kernel configs (PR 8): versioned, checksummed, atomic,
-corruption-tolerant.  Entries are keyed by (relation, content token): the
+version — so they persist under the same ``cache_dir`` discipline as
+plans: versioned, checksummed, atomic, corruption-tolerant.  Entries are keyed by (relation, content token): the
 engine passes a composite token covering the table's own
 ``Table.content_token()`` PLUS those of its FK-destination tables (orphan
 counts read both sides of each declared FK), so a warm restart over

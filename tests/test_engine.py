@@ -28,7 +28,8 @@ from repro.data import (
 )
 from repro.core.plan import FreqJoinOp, SemiJoinOp
 from repro.data.relational import stats_count_query, tpch_v1_query
-from repro.kernels.autotune import KernelConfig
+from repro.kernels.ops import DENSE_DOMAIN_CAP, DENSE_RATIO
+from repro.service import QueryService
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -163,15 +164,6 @@ def test_stats_count_modes_agree(use_fkpk):
         plan = plan_query(q, schema, mode=mode, use_fkpk=use_fkpk)
         got = ex.execute(plan)["count(*)"]
         assert int(got) == int(ref)
-
-
-def test_pallas_backend_engine_agrees():
-    db, schema = make_graph_db(n_nodes=10, n_edges=30, seed=5)
-    q = path_query(2)
-    want = brute_force_count(db, schema, q)
-    ex = Executor(db, schema, backend="pallas")
-    got = ex.execute(plan_query(q, schema, mode="opt_plus"))["count(*)"]
-    assert int(got) == want
 
 
 # ---------------------------------------------------------------------------
@@ -392,19 +384,35 @@ def test_declared_domain_chooses_the_join_path(domain, path):
         [op for op in plan.ops if isinstance(op, (FreqJoinOp, SemiJoinOp))])
 
 
-class _AnyDomain:
-    """A tuning whose crossover admits every domain."""
-
-    def lookup(self, kernel, sizes, backend):
-        return KernelConfig(dense_floor=1 << 40)
-
-
-@pytest.mark.parametrize("domain, path", [((1 << 31) - 1, "dense"),
-                                          (1 << 31, "sorted")])
+@pytest.mark.parametrize("domain, path", [(DENSE_DOMAIN_CAP - 1, "dense"),
+                                          (DENSE_DOMAIN_CAP, "sorted")])
 def test_dense_domain_cap_holds_whatever_the_tuning(domain, path):
+    """A child long enough for ``DENSE_RATIO`` to admit a 2^31-slot domain
+    still sorts: the int32 cap binds.  The program is traced and lowered
+    at that length, never run."""
     db, schema = make_graph_db(n_nodes=14, n_edges=45, seed=21)
     plan = plan_query(path_query(3), schema, mode="opt_plus")
-    ex = Executor(db, _with_domain(schema, domain), tuning=_AnyDomain())
+    ex = Executor(db, _with_domain(schema, domain))
+    rows = DENSE_DOMAIN_CAP // DENSE_RATIO
+    spec = {rel: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((rows,), a.dtype), t)
+        for rel, t in db.items()}
     joins = collections.Counter()
-    ex.compile(plan, joins=joins).lower(db)   # traced, never run
+    ex.compile(plan, joins=joins).lower(spec)
     assert set(joins) == {path}
+
+
+def test_graph_query_served_declared_and_stripped():
+    """A tree query through the service over a declared schema and over
+    the same schema with its domains stripped: the dense path and the
+    sorted path both equal brute force."""
+    db, schema = make_graph_db(n_nodes=12, n_edges=24, seed=3)
+    q = tree_query(1)
+    want = brute_force_count(db, schema, q)
+    for sch, path in ((schema, "dense"), (_with_domain(schema, None),
+                                          "sorted")):
+        svc = QueryService(db, sch, mode="opt_plus")
+        assert int(svc.submit(q).values["count(*)"]) == want
+        m = svc.metrics()
+        assert m[f"joins_{path}"] > 0
+        assert m["joins_dense" if path == "sorted" else "joins_sorted"] == 0

@@ -107,7 +107,7 @@ class LinearReference:
         outer = self.ex
 
         def run(db):
-            inner = Executor(db, outer.schema, outer.backend)
+            inner = Executor(db, outer.schema)
             return self._sweep(inner, plan)
 
         return jax.jit(run)

@@ -2,10 +2,9 @@
 
 No chip is needed: the TPU compiler compiles for a *described* v5e
 (``jax.experimental.topologies``) and raises what the chip's compiler
-would raise — unaligned blocks, unsupported Mosaic lowerings, programs
-that do not fit.  Nothing runs, so this says nothing about results or
-times; the kernels' answers are checked in interpret mode by
-``test_kernels.py``.
+would raise — unsupported lowerings, programs that do not fit.  Nothing
+runs, so this says nothing about results or times; the kernels' answers
+are checked by ``test_kernels.py``.
 
 Widths are those of TPC-H SF10's partsupp (8M rows → 2^23) against
 part (2M → 2^21).  The topology is described inside a module fixture —
@@ -26,10 +25,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.autotune import DEFAULT_CONFIG
-from repro.kernels.freq_join import freq_join_pallas
-from repro.kernels.segment_sum import segment_sum_pallas
-from repro.kernels.semi_join import semi_join_pallas
 
 PARENT = 1 << 23
 CHILD = 1 << 21
@@ -72,16 +67,13 @@ def _compile(fn, *args):
 @pytest.mark.parametrize("mode", ["sum", "any"])
 @pytest.mark.parametrize("domain", [None, CHILD], ids=["sort", "dense"])
 def test_xla_freq_join_compiles(one_chip, mode, domain):
-    fn = functools.partial(ops._freq_join_impl, mode=mode, backend="xla",
-                           interpret=False, domain=domain,
-                           config=DEFAULT_CONFIG)
-    assert DEFAULT_CONFIG.dense_ok(domain, CHILD) == (domain is not None)
+    fn = functools.partial(ops._freq_join_impl, mode=mode, domain=domain)
+    assert (ops.join_path(domain, CHILD) == "dense") == (domain is not None)
     _compile(fn, *_join_args(one_chip))
 
 
 def test_xla_group_by_sum_compiles(one_chip):
-    fn = functools.partial(ops.group_by_sum, backend="xla")
-    _compile(fn, _shape(PARENT, jnp.int32, one_chip),
+    _compile(ops.group_by_sum, _shape(PARENT, jnp.int32, one_chip),
              _shape(PARENT, jnp.int32, one_chip))
 
 
@@ -91,26 +83,36 @@ def test_weighted_percentile_compiles(one_chip):
              _shape(PARENT, jnp.int32, one_chip))
 
 
-@pytest.mark.parametrize("block_rows", [8, 64])
-@pytest.mark.parametrize("kernel", ["freq_join", "semi_join"])
-def test_pallas_join_compiles(one_chip, kernel, block_rows):
-    fn = freq_join_pallas if kernel == "freq_join" else semi_join_pallas
-    fn = functools.partial(fn, interpret=False,
-                           parent_block_rows=block_rows,
-                           child_block_rows=block_rows)
-    compiled = _compile(fn, *_join_args(one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
+SMALL = 1 << 16
 
 
-@pytest.mark.parametrize("lanes_wide", [1024, 8192])
-@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32],
-                         ids=["int32", "float32"])
-def test_pallas_segment_sum_compiles(one_chip, dtype, lanes_wide):
-    fn = functools.partial(segment_sum_pallas, interpret=False,
-                           lanes_wide=lanes_wide)
-    compiled = _compile(fn, _shape(PARENT, jnp.int32, one_chip),
-                        _shape(PARENT, dtype, one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
+@pytest.mark.parametrize("mode, domain", [("sum", None), ("any", None),
+                                          ("any", SMALL)],
+                         ids=["sorted-sum", "sorted-any", "dense-any"])
+def test_64_bit_joins_compile(one_chip, mode, domain):
+    """The 64-bit joins no limb split covers: the sorted path's s64
+    prefix sums, and the dense semi-join, which scatters an int32 count
+    of live rows whatever the frequencies' width."""
+    fn = functools.partial(ops._freq_join_impl, mode=mode, domain=domain)
+    assert ops.join_path(domain, SMALL) == ("sorted" if domain is None
+                                           else "dense")
+    args = (_shape(SMALL, jnp.int32, one_chip),
+            _shape(SMALL, jnp.int64, one_chip),
+            _shape(SMALL, jnp.int32, one_chip),
+            _shape(SMALL, jnp.int64, one_chip))
+    with jax.enable_x64(True):
+        text = _compile(fn, *args).as_text()
+    assert "s64[" in text
+    if domain is not None:
+        assert not re.findall(r"^.*= s64\[[^\n]*\bscatter\(", text, re.M)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int64],
+                         ids=["float32", "int64"])
+def test_group_by_sum_compiles_over_values(one_chip, dtype):
+    with jax.enable_x64(dtype == jnp.int64):
+        _compile(ops.group_by_sum, _shape(SMALL, jnp.int32, one_chip),
+                 _shape(SMALL, dtype, one_chip))
 
 
 def test_xla_freq_join_with_64_bit_counts_compiles(one_chip):
@@ -121,8 +123,7 @@ def test_xla_freq_join_with_64_bit_counts_compiles(one_chip):
     from repro.core.stats import limb_split
 
     # the graph cell's split: a hub of 160,410 rows, frequencies to 2^40
-    fn = functools.partial(ops.freq_join, mode="sum", backend="xla",
-                           domain=CHILD, config=DEFAULT_CONFIG,
+    fn = functools.partial(ops.freq_join, mode="sum", domain=CHILD,
                            limbs=limb_split(160_410, 2 ** 40))
     with jax.enable_x64(True):
         compiled = _compile(fn, *_join_args(one_chip, jnp.int64))
